@@ -236,12 +236,27 @@ def collect_status() -> dict:
     except Exception:  # noqa: BLE001
         pass
     try:
+        # where this process computes (platform, device_kind, versions —
+        # named once the agent brought the backend up), the plane's
+        # utilization once it has dispatched, and the Tier-1 routing
+        # decision (probe numbers, rows kept on the host per tier, counted
+        # fallbacks)
+        import sys as _sys
+        from ..ops import device_info as _di
         from ..ops.device_plane import DevicePlane
+        dev: dict = dict(_di.status() or {})
         plane = DevicePlane._instance    # observe-only: never construct
         if plane is not None:
             u = plane.utilization()
-            doc["device"] = {k: (round(v, 6) if isinstance(v, float) else v)
-                             for k, v in u.items()}
+            dev.update({k: (round(v, 6) if isinstance(v, float) else v)
+                        for k, v in u.items()})
+        # observe-only, like the sections below: importing the engine
+        # would import jax in a process that never parsed a row
+        _eng = _sys.modules.get("loongcollector_tpu.ops.regex.engine")
+        if _eng is not None:
+            dev["routing"] = _eng.routing_status()
+        if dev:
+            doc["device"] = dev
     except Exception:  # noqa: BLE001
         pass
     try:

@@ -18,6 +18,7 @@ import time
 from typing import Callable, Optional
 
 from .. import prof
+from ..ops import device_info
 from ..prof import flight
 from ..utils import flags
 from ..utils.logger import get_logger
@@ -27,7 +28,10 @@ from .metrics import MetricsRecord
 log = get_logger("watchdog")
 
 flags.DEFINE_FLAG_DOUBLE("cpu_usage_limit", "agent CPU cores limit", 2.0)
-flags.DEFINE_FLAG_INT32("memory_usage_limit_mb", "agent RSS limit (MB)", 2048)
+flags.DEFINE_FLAG_INT32(
+    "memory_usage_limit_mb",
+    "agent RSS limit (MB), above what the device runtime holds resident",
+    2048)
 
 
 def _read_self_stat() -> tuple:
@@ -125,8 +129,16 @@ class LoongCollectorMonitor:
             AlarmManager.instance().send_alarm(
                 AlarmType.CPU_LIMIT, "agent cpu over limit",
                 AlarmLevel.ERROR, details=self._breach_details(breach))
-        if rss > mem_limit > 0:
-            breach = f"rss {rss>>20} MB > limit {mem_limit>>20} MB"
+        # the limit guards the agent's own growth: the host memory the
+        # device runtime made resident at backend start (device_info) is
+        # the machine's fixed cost — on a v5e host it alone is several
+        # times the limit, and counting it restarts a healthy agent
+        runtime = device_info.runtime_rss_bytes()
+        if rss - runtime > mem_limit > 0:
+            breach = (f"rss {(rss - runtime)>>20} MB > limit "
+                      f"{mem_limit>>20} MB")
+            if runtime:
+                breach += f" (device runtime's {runtime>>20} MB excluded)"
             log.warning("watchdog: %s", breach)
             AlarmManager.instance().send_alarm(
                 AlarmType.MEM_LIMIT, "agent memory over limit",

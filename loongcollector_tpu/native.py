@@ -1,8 +1,11 @@
 """ctypes bridge to the C++ native data plane (native/).
 
-Loads libloongcollector_native.so if present (building it once with the
-repo's Makefile when a toolchain is available); every entry point has a
-pure-numpy/Python fallback so the framework runs without the library.
+Loads libloongcollector_native.so (building it once with the repo's
+Makefile when it is absent).  A library that cannot be built or loaded is
+an error: every entry point does have a pure-numpy/Python twin, but those
+are the references the equivalence gates compare against, reached only by
+the explicit ``LOONG_DISABLE_NATIVE`` switch — never by a build that
+failed quietly.
 
 Reference parity: the reference's equivalents are C++ (LogFileReader line
 alignment, the batch staging copy, core/protobuf/sls/LogGroupSerializer).
@@ -25,6 +28,8 @@ log = get_logger("native")
 _lib = None
 _load_lock = threading.Lock()
 _load_attempted = False
+#: the failure of this process's one load attempt, re-raised on later calls
+_load_error: Optional["NativeLibraryError"] = None
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "native")
@@ -38,129 +43,150 @@ def _so_path() -> str:
     return os.environ.get("LOONG_NATIVE_LIB") or _SO_PATH
 
 
-def _try_build() -> bool:
-    makefile = os.path.join(_NATIVE_DIR, "Makefile")
-    if not os.path.exists(makefile):
-        return False
+class NativeLibraryError(RuntimeError):
+    """The native library could not be built or loaded."""
+
+
+def _build() -> None:
+    """`make -C native`; raises NativeLibraryError with the compiler's
+    own words when it fails."""
     try:
         subprocess.run(["make", "-C", _NATIVE_DIR, "-s"], check=True,
                        timeout=120, capture_output=True)
-        return os.path.exists(_SO_PATH)
-    except (OSError, subprocess.SubprocessError):
-        return False
+    except subprocess.CalledProcessError as e:
+        raise NativeLibraryError(
+            "native build failed:\n"
+            + e.stderr.decode(errors="replace")[-2000:]) from e
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise NativeLibraryError(f"native build failed: {e!r}") from e
+    if not os.path.exists(_SO_PATH):
+        raise NativeLibraryError(f"native build left no {_SO_PATH}")
+
+
+def _cdll(so_path: str) -> ctypes.CDLL:
+    try:
+        return ctypes.CDLL(so_path)
+    except OSError as e:
+        raise NativeLibraryError(
+            f"failed to load native library {so_path}: {e}") from e
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    global _lib, _load_attempted
-    if _lib is not None or _load_attempted:
-        return _lib
-    with _load_lock:
-        if _lib is not None or _load_attempted:
-            return _lib
-        _load_attempted = True
-        if os.environ.get("LOONG_DISABLE_NATIVE"):
-            return None
-        so_path = _so_path()
-        overridden = so_path != _SO_PATH
+    """The loaded library, or None under LOONG_DISABLE_NATIVE.  One load
+    (and at most one build) attempt per process: a failure is kept and
+    re-raised by every later call."""
+    global _lib, _load_attempted, _load_error
+    if not _load_attempted:
+        with _load_lock:
+            if not _load_attempted:
+                try:
+                    _lib = _load()
+                except NativeLibraryError as e:
+                    _load_error = e
+                _load_attempted = True
+    if _load_error is not None:
+        raise _load_error
+    return _lib
+
+
+def _load() -> Optional[ctypes.CDLL]:
+    if os.environ.get("LOONG_DISABLE_NATIVE"):
+        return None
+    so_path = _so_path()
+    overridden = so_path != _SO_PATH
+    if not os.path.exists(so_path):
         # an explicit override must load exactly what it names — never
         # fall back to (or rebuild over) the release artifact
-        if not os.path.exists(so_path) and (overridden or not _try_build()):
-            log.info("native library unavailable; using python fallbacks")
-            return None
-        try:
-            lib = ctypes.CDLL(so_path)
-        except OSError as e:
-            log.warning("failed to load native library: %s", e)
-            return None
-        if not overridden and (
-                not hasattr(lib, "lct_t1_exec")
-                or not hasattr(lib, "lct_ndjson_serialize")
-                or not hasattr(lib, "lct_struct_index")
-                or not hasattr(lib, "lct_group_reduce")):
-            # stale build predating the newest entry point: rebuild + reload
-            if _try_build():
-                try:
-                    lib = ctypes.CDLL(so_path)
-                except OSError:
-                    pass
-        # pointer params bind as c_void_p and calls pass raw addresses
-        # (arr.ctypes.data): ctypes POINTER casts cost ~2 us each and the
-        # hot wrappers pass ~20 pointers per group
-        u8p = ctypes.c_void_p
-        i32p = ctypes.c_void_p
-        i64p = ctypes.c_void_p
-        lib.lct_split_lines.restype = ctypes.c_int64
-        lib.lct_split_lines.argtypes = [u8p, ctypes.c_int64, ctypes.c_uint8,
-                                        ctypes.c_int64, i32p, i32p]
-        lib.lct_pack_rows.restype = None
-        lib.lct_pack_rows.argtypes = [u8p, ctypes.c_int64, i64p, i32p,
-                                      ctypes.c_int64, ctypes.c_int64, u8p]
-        lib.lct_json_extract.restype = None
-        lib.lct_json_extract.argtypes = [u8p, ctypes.c_int64, i64p, i32p,
-                                         ctypes.c_int64, u8p, i32p,
-                                         ctypes.c_int64, i32p, i32p,
-                                         u8p, u8p]
-        lib.lct_sls_serialize.restype = ctypes.c_int64
-        lib.lct_sls_serialize.argtypes = [u8p, ctypes.c_int64, i64p,
-                                          ctypes.c_int64, ctypes.c_int64,
-                                          u8p, i32p, i32p, i32p,
-                                          u8p, ctypes.c_int64]
-        if hasattr(lib, "lct_sls_serialize_strided"):
-            lib.lct_sls_serialize_strided.restype = ctypes.c_int64
-            lib.lct_sls_serialize_strided.argtypes = [
-                u8p, ctypes.c_int64, i64p, ctypes.c_int64, ctypes.c_int64,
-                u8p, i32p, i32p, i32p, ctypes.c_int64, ctypes.c_int64,
-                u8p, ctypes.c_int64]
-        if hasattr(lib, "lct_ndjson_serialize"):
-            lib.lct_ndjson_serialize.restype = ctypes.c_int64
-            lib.lct_ndjson_serialize.argtypes = [
-                u8p, ctypes.c_int64, i64p, ctypes.c_int64, ctypes.c_int64,
-                u8p, i32p, i32p, i32p, ctypes.c_int64, ctypes.c_int64,
-                u8p, ctypes.c_int64, ctypes.c_int32,
-                u8p, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,
-                u8p, ctypes.c_int64, u8p, ctypes.c_int64]
-        if hasattr(lib, "lct_struct_index"):
-            lib.lct_struct_index.restype = None
-            lib.lct_struct_index.argtypes = [
-                u8p, ctypes.c_int64, i64p, i32p, ctypes.c_int64,
-                ctypes.c_int32, ctypes.c_uint8, ctypes.c_uint8,
-                ctypes.c_int64, u8p, u8p, u8p, u8p]
-        if hasattr(lib, "lct_json_struct_parse"):
-            lib.lct_json_struct_parse.restype = ctypes.c_int64
-            lib.lct_json_struct_parse.argtypes = [
-                u8p, ctypes.c_int64, i64p, i32p, ctypes.c_int64,
-                u8p, i32p, ctypes.c_int64, i32p, i32p, u8p,
-                u8p, ctypes.c_int64,
-                i32p, i32p, i32p, i32p, i32p, ctypes.c_int64, i64p]
-        if hasattr(lib, "lct_group_reduce"):
-            lib.lct_group_reduce.restype = ctypes.c_int64
-            lib.lct_group_reduce.argtypes = [
-                u8p, ctypes.c_int64,
-                i64p, i64p, i32p, i64p, i32p,
-                ctypes.c_int64, ctypes.c_int64,
-                ctypes.c_double, ctypes.c_int64,
-                i32p, i32p, u8p, i64p, u8p, u8p, u8p,
-                i64p, ctypes.c_int64]
-        if hasattr(lib, "lct_delim_struct_parse"):
-            lib.lct_delim_struct_parse.restype = ctypes.c_int64
-            lib.lct_delim_struct_parse.argtypes = [
-                u8p, ctypes.c_int64, i64p, i32p, ctypes.c_int64,
-                ctypes.c_uint8, ctypes.c_uint8, ctypes.c_int64,
-                i32p, i32p, i32p, u8p, ctypes.c_int64, i64p]
-        for fn in ("lct_lz4_bound", "lct_lz4_compress", "lct_lz4_decompress",
-                   "lct_snappy_bound", "lct_snappy_compress",
-                   "lct_snappy_uncompressed_len", "lct_snappy_decompress"):
-            f = getattr(lib, fn, None)
-            if f is None:      # stale .so predating the codecs: rebuild once
-                continue
-            f.restype = ctypes.c_int64
-            f.argtypes = ([ctypes.c_int64] if fn.endswith("bound")
-                          else [u8p, ctypes.c_int64, u8p, ctypes.c_int64]
-                          if not fn.endswith("uncompressed_len")
-                          else [u8p, ctypes.c_int64])
-        _lib = lib
-        log.info("native library loaded: %s", so_path)
-        return _lib
+        if overridden:
+            raise NativeLibraryError(
+                f"LOONG_NATIVE_LIB={so_path} does not exist")
+        _build()
+    lib = _cdll(so_path)
+    if not overridden and (
+            not hasattr(lib, "lct_t1_exec")
+            or not hasattr(lib, "lct_ndjson_serialize")
+            or not hasattr(lib, "lct_struct_index")
+            or not hasattr(lib, "lct_group_reduce")):
+        # stale build predating the newest entry point: rebuild + reload
+        _build()
+        lib = _cdll(so_path)
+    # pointer params bind as c_void_p and calls pass raw addresses
+    # (arr.ctypes.data): ctypes POINTER casts cost ~2 us each and the
+    # hot wrappers pass ~20 pointers per group
+    u8p = ctypes.c_void_p
+    i32p = ctypes.c_void_p
+    i64p = ctypes.c_void_p
+    lib.lct_split_lines.restype = ctypes.c_int64
+    lib.lct_split_lines.argtypes = [u8p, ctypes.c_int64, ctypes.c_uint8,
+                                    ctypes.c_int64, i32p, i32p]
+    lib.lct_pack_rows.restype = None
+    lib.lct_pack_rows.argtypes = [u8p, ctypes.c_int64, i64p, i32p,
+                                  ctypes.c_int64, ctypes.c_int64, u8p]
+    lib.lct_json_extract.restype = None
+    lib.lct_json_extract.argtypes = [u8p, ctypes.c_int64, i64p, i32p,
+                                     ctypes.c_int64, u8p, i32p,
+                                     ctypes.c_int64, i32p, i32p,
+                                     u8p, u8p]
+    lib.lct_sls_serialize.restype = ctypes.c_int64
+    lib.lct_sls_serialize.argtypes = [u8p, ctypes.c_int64, i64p,
+                                      ctypes.c_int64, ctypes.c_int64,
+                                      u8p, i32p, i32p, i32p,
+                                      u8p, ctypes.c_int64]
+    if hasattr(lib, "lct_sls_serialize_strided"):
+        lib.lct_sls_serialize_strided.restype = ctypes.c_int64
+        lib.lct_sls_serialize_strided.argtypes = [
+            u8p, ctypes.c_int64, i64p, ctypes.c_int64, ctypes.c_int64,
+            u8p, i32p, i32p, i32p, ctypes.c_int64, ctypes.c_int64,
+            u8p, ctypes.c_int64]
+    if hasattr(lib, "lct_ndjson_serialize"):
+        lib.lct_ndjson_serialize.restype = ctypes.c_int64
+        lib.lct_ndjson_serialize.argtypes = [
+            u8p, ctypes.c_int64, i64p, ctypes.c_int64, ctypes.c_int64,
+            u8p, i32p, i32p, i32p, ctypes.c_int64, ctypes.c_int64,
+            u8p, ctypes.c_int64, ctypes.c_int32,
+            u8p, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,
+            u8p, ctypes.c_int64, u8p, ctypes.c_int64]
+    if hasattr(lib, "lct_struct_index"):
+        lib.lct_struct_index.restype = None
+        lib.lct_struct_index.argtypes = [
+            u8p, ctypes.c_int64, i64p, i32p, ctypes.c_int64,
+            ctypes.c_int32, ctypes.c_uint8, ctypes.c_uint8,
+            ctypes.c_int64, u8p, u8p, u8p, u8p]
+    if hasattr(lib, "lct_json_struct_parse"):
+        lib.lct_json_struct_parse.restype = ctypes.c_int64
+        lib.lct_json_struct_parse.argtypes = [
+            u8p, ctypes.c_int64, i64p, i32p, ctypes.c_int64,
+            u8p, i32p, ctypes.c_int64, i32p, i32p, u8p,
+            u8p, ctypes.c_int64,
+            i32p, i32p, i32p, i32p, i32p, ctypes.c_int64, i64p]
+    if hasattr(lib, "lct_group_reduce"):
+        lib.lct_group_reduce.restype = ctypes.c_int64
+        lib.lct_group_reduce.argtypes = [
+            u8p, ctypes.c_int64,
+            i64p, i64p, i32p, i64p, i32p,
+            ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_double, ctypes.c_int64,
+            i32p, i32p, u8p, i64p, u8p, u8p, u8p,
+            i64p, ctypes.c_int64]
+    if hasattr(lib, "lct_delim_struct_parse"):
+        lib.lct_delim_struct_parse.restype = ctypes.c_int64
+        lib.lct_delim_struct_parse.argtypes = [
+            u8p, ctypes.c_int64, i64p, i32p, ctypes.c_int64,
+            ctypes.c_uint8, ctypes.c_uint8, ctypes.c_int64,
+            i32p, i32p, i32p, u8p, ctypes.c_int64, i64p]
+    for fn in ("lct_lz4_bound", "lct_lz4_compress", "lct_lz4_decompress",
+               "lct_snappy_bound", "lct_snappy_compress",
+               "lct_snappy_uncompressed_len", "lct_snappy_decompress"):
+        f = getattr(lib, fn, None)
+        if f is None:      # stale .so predating the codecs: rebuild once
+            continue
+        f.restype = ctypes.c_int64
+        f.argtypes = ([ctypes.c_int64] if fn.endswith("bound")
+                      else [u8p, ctypes.c_int64, u8p, ctypes.c_int64]
+                      if not fn.endswith("uncompressed_len")
+                      else [u8p, ctypes.c_int64])
+    log.info("native library loaded: %s", so_path)
+    return lib
 
 
 def _u8(a: np.ndarray) -> int:

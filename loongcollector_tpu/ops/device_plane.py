@@ -10,9 +10,8 @@ background), so the host packs and dispatches chunk N+1 while the device
 executes chunk N, and materialises results strictly as needed.
 
 Back-pressure contract: every dispatch acquires from a process-wide in-flight
-byte budget and releases it on materialisation.  When the device stalls (or a
-tunnel wedges), the budget fills, `submit` blocks, the runner thread stops
-popping, the bounded process queues hit their high watermark, and the file
+byte budget and releases it on materialisation.  When the device stalls, the
+budget fills, `submit` blocks, the runner thread stops popping, the bounded process queues hit their high watermark, and the file
 inputs get feedback-blocked — the exact chain the reference builds between
 FlusherRunner, the sender queues and the process queues, extended one hop
 further onto the device.
@@ -727,7 +726,8 @@ class DispatchAborted(RuntimeError):
 
 
 # ---------------------------------------------------------------------------
-# Latency-injection kernel: the CPU-testable stand-in for a remote device.
+# Latency-injection kernel: the CPU-testable stand-in for a slow device
+# (a test fake — nothing on the served path constructs one).
 
 
 class LatencyInjectedArray:
@@ -755,13 +755,13 @@ class LatencyInjectedArray:
 
 class LatencyInjectedKernel:
     """Wraps a synchronous kernel so that dispatch returns instantly and
-    materialisation blocks for `rtt_s` — an honest model of a (possibly
-    tunneled) accelerator.  `serialize=True` (concurrency 1) models a
+    materialisation blocks for `rtt_s` — a model of a slow
+    accelerator.  `serialize=True` (concurrency 1) models a
     device that executes one dispatch at a time: each call's execution
     starts after the previous call's, exactly like a device execution
     stream.
 
-    ``wire_s`` splits a tunneled round trip into its pipelinable part:
+    ``wire_s`` splits a slow round trip into its pipelinable part:
     each dispatch pays one-way wire latency BEFORE execution can start
     (H2D) and the host pays it again before results are visible (D2H), so
     a synchronous round trip costs ``2*wire_s + rtt_s`` while a pipelined

@@ -1,10 +1,9 @@
 """loongstream: the streaming device pipeline (batch rings + auto-tuner).
 
-`BENCH_TPU_LAST_GOOD.json` shows the kernel parsing at 128 GB/s while the
-pipeline moves 2 MB/s end-to-end: the device sits idle on batch assembly,
-H2D/D2H transfer and synchronous round-trips (exactly what loongprof's
-``device_idle_while_backlogged_ms`` measures).  This module closes that gap
-on the host side of the dispatch:
+A synchronous submit→materialise loop leaves the device idle during batch
+assembly, H2D/D2H transfer and the round trip itself (exactly what
+loongprof's ``device_idle_while_backlogged_ms`` measures).  This module
+closes that gap on the host side of the dispatch:
 
 * **BatchRing / BatchSlot** — a persistent ring of pre-allocated
   fixed-geometry batch buffers per ``(B, L)`` geometry.  Packing reuses the

@@ -1,9 +1,8 @@
 """loongresident: single-dispatch pipeline fusion — the AOT stage compiler.
 
-`BENCH_TPU_LAST_GOOD.json` shows the kernel at 128 GB/s while
-`pipeline_e2e_MBps` sits at 2.0: with every device-capable stage running
-its own pack → H2D → dispatch → materialise cycle, an N-stage pipeline
-pays N synchronous device round trips per batch.  ParPaRaw's whole
+With every device-capable stage running its own pack → H2D → dispatch →
+materialise cycle, an N-stage pipeline pays N synchronous device round
+trips per batch.  ParPaRaw's whole
 contribution is never leaving the device between phases; the DFA
 processing literature composes automata passes into one resident
 execution.  This module does the same for a pipeline's consecutive
@@ -267,7 +266,7 @@ class FusedProgramKernel:
         return self._fn(rows, lengths)
 
     def donated_call(self, rows, lengths):
-        """Streaming-path variant (see ExtractKernel.donated_call): the
+        """Streaming-path variant: the
         batch-ring staging buffers are transient, so their device copies
         are donated and XLA reuses that HBM for the outputs."""
         from .kernels.field_extract import donation_supported

@@ -226,7 +226,7 @@ class FusedScanKernel:
         return self._fn(rows, lengths)
 
     def donated_call(self, rows, lengths) -> np.ndarray:
-        """Streaming-path variant (see ExtractKernel.donated_call)."""
+        """Streaming-path variant (see DFAMatchKernel.donated_call)."""
         from .field_extract import donation_supported
         if not donation_supported():
             return self.__call__(rows, lengths)
@@ -251,8 +251,9 @@ class DFAMatchKernel:
 
     def donated_call(self, rows, lengths) -> np.ndarray:
         """Streaming-path variant: donate the per-dispatch staging buffers
-        so XLA reuses their HBM (see ExtractKernel.donated_call — same
-        contract, same CPU gating)."""
+        so XLA reuses their HBM.  NOT safe for callers that re-use a
+        device-resident input across calls; gated off on CPU, where jit
+        ignores donation with a per-call warning."""
         from .field_extract import donation_supported
         if not donation_supported():
             return self._fn(rows, lengths)

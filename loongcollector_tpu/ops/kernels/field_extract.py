@@ -516,33 +516,23 @@ class ExtractKernel:
 
     jit caches per (B, L) geometry internally; callers should quantise shapes
     (see ops/device_batch.py) to bound the number of compilations.
+
+    No donating variant: no output matches the u8 [B,L] rows or the i32
+    [B] lengths in shape, so XLA cannot alias them ("Some donated buffers
+    were not usable" on the chip) and a second jit per geometry would buy
+    nothing.
     """
+
+    family = "extract"
 
     def __init__(self, program: SegmentProgram):
         from ..compile_watch import watched_jit
         self.program = program
-        self._fn = watched_jit(build_extract_fn(program), "extract")
-        self._fn_donated = None
+        self._fn = watched_jit(build_extract_fn(program), self.family)
 
     def __call__(self, rows, lengths) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         ok, off, length = self._fn(rows, lengths)
         return ok, off, length
-
-    def donated_call(self, rows, lengths
-                     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Streaming-path dispatch: inputs are per-dispatch host staging
-        buffers (batch-ring slots), so their device copies are transient —
-        donating them lets XLA alias that HBM for the outputs.  NOT safe
-        for callers that re-use a device-resident input across calls (the
-        bench kernel loop): those stay on __call__."""
-        if not donation_supported():
-            return self._fn(rows, lengths)
-        if self._fn_donated is None:
-            from ..compile_watch import watched_jit
-            self._fn_donated = watched_jit(build_extract_fn(self.program),
-                                           "extract",
-                                           donate_argnums=(0, 1))
-        return self._fn_donated(rows, lengths)
 
     @property
     def num_caps(self) -> int:

@@ -11,12 +11,8 @@ from typing import List, Tuple
 
 import numpy as np
 
-try:  # Python 3.11+
-    from re import _constants as sre_c
-    from re import _parser as sre_parse
-except ImportError:  # pragma: no cover
-    import sre_constants as sre_c
-    import sre_parse
+from re import _constants as sre_c
+from re import _parser as sre_parse
 
 _WHITESPACE = b" \t\n\r\x0b\x0c"
 _DIGITS = b"0123456789"

@@ -20,12 +20,8 @@ from typing import Dict, FrozenSet, List, Optional, Set, Tuple, Union
 
 import numpy as np
 
-try:  # Python 3.11+
-    from re import _constants as sre_c
-    from re import _parser as sre_parse
-except ImportError:  # pragma: no cover
-    import sre_constants as sre_c
-    import sre_parse
+from re import _constants as sre_c
+from re import _parser as sre_parse
 
 from .charclass import CharClass
 

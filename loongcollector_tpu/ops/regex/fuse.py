@@ -66,12 +66,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-try:  # Python 3.11+
-    from re import _constants as sre_c
-    from re import _parser as sre_parse
-except ImportError:  # pragma: no cover
-    import sre_constants as sre_c
-    import sre_parse
+from re import _constants as sre_c
+from re import _parser as sre_parse
 
 from ... import native as native_mod
 from .charclass import CharClass

@@ -1,8 +1,9 @@
 """Host-tier Tier-1 execution: serialize SegmentPrograms for the C++ walker.
 
-When no accelerator is reachable (degraded mode) the XLA:CPU emulation of
-the masked-reduction kernel is an order of magnitude slower than a direct
-scalar walk, so the engine routes parse_batch to `lct_t1_exec`
+On a CPU backend (an explicit pin: tests, CPU drives) the XLA:CPU
+emulation of the masked-reduction kernel is an order of magnitude slower
+than a direct scalar walk, and on a chip a small batch loses to the fixed
+dispatch round trip, so there the engine routes parse_batch to `lct_t1_exec`
 (native/loongcollector_native.cpp) — the same compiled IR, executed
 per-row, mirroring ops/kernels/field_extract.py op-for-op.  The reference's
 equivalent hot loop is likewise native C++

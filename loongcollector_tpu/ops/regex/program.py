@@ -29,12 +29,8 @@ import enum
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
-try:  # Python 3.11+
-    from re import _constants as sre_c
-    from re import _parser as sre_parse
-except ImportError:  # pragma: no cover
-    import sre_constants as sre_c
-    import sre_parse
+from re import _constants as sre_c
+from re import _parser as sre_parse
 
 from .charclass import CharClass
 

@@ -97,18 +97,11 @@ class ShardedParsePlane:
             }
             return ok, off, length, stats
 
-        try:
-            from jax import shard_map  # jax ≥ 0.8 (check_rep retired)
-            kw = {}
-        except ImportError:  # pragma: no cover - older jax
-            from jax.experimental.shard_map import shard_map
-            kw = {"check_rep": False}
-        sharded = shard_map(
+        sharded = jax.shard_map(
             _local_step, mesh=self.mesh,
             in_specs=(P(axis, None), P(axis)),
             out_specs=(P(axis), P(axis, None), P(axis, None),
-                       {"matched": P(), "events": P(), "bytes": P()}),
-            **kw)
+                       {"matched": P(), "events": P(), "bytes": P()}))
         self._fn = watched_jit(sharded, "sharded_parse")
         # donated variant (loongmesh): inputs are per-dispatch staging
         # copies produced by put(), so XLA may alias their per-shard HBM
@@ -205,6 +198,9 @@ class ShardedKernel:
     compute has long finished by then, so np.asarray is a cheap copy, not
     a device wait).  ``last_stats`` keeps the most recent dispatch's
     on-device handle for tests and ad-hoc inspection."""
+
+    #: the ``watched_jit`` family the mesh step compiles under
+    family = "sharded_parse"
 
     #: fold queued stats once the backlog exceeds this many dispatches —
     #: deeper than any stream depth, so the fold never blocks on compute

@@ -381,7 +381,7 @@ class TestEngineStreaming:
     def test_overlap_2_5x_at_rtt5ms(self, device_tier):
         """The tentpole number: a concurrency-1 device behind a 5 ms round
         trip (2.25 ms wire each way + 0.5 ms serialized execution — a
-        tunneled TPU's profile: latency-dominated, execution fast).  The
+        slow device's profile: latency-dominated, execution fast).  The
         synchronous path pays the full round trip per chunk; depth-3
         streaming overlaps the wire legs of neighbouring batches and is
         bounded by max((2w+x)/3, host pack) per chunk — ≥ 2.5× asserted,

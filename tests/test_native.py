@@ -164,3 +164,36 @@ class TestNativeJsonExtract:
         (offs, lens, ok, fb), _ = self._run(lines, [b"a"])
         assert fb[0] and not ok[0]  # host json.loads also rejects this
         assert ok[1]
+
+
+class TestLoadFailure:
+    def test_a_failed_load_is_kept_and_not_retried(self, monkeypatch):
+        """One build/load attempt per process: a failure raises on every
+        call, and the 120 s `make` runs once, not once per call site."""
+        attempts = []
+
+        def failing_build():
+            attempts.append(1)
+            raise native.NativeLibraryError("native build failed: boom")
+
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_load_attempted", False)
+        monkeypatch.setattr(native, "_load_error", None)
+        monkeypatch.setattr(native, "_SO_PATH", "/nonexistent/lib.so")
+        monkeypatch.delenv("LOONG_NATIVE_LIB", raising=False)
+        monkeypatch.setattr(native, "_build", failing_build)
+        for _ in range(3):
+            with pytest.raises(native.NativeLibraryError, match="boom"):
+                native.get_lib()
+        assert len(attempts) == 1
+
+    def test_an_unloadable_library_is_a_native_library_error(
+            self, monkeypatch, tmp_path):
+        bad = tmp_path / "bad.so"
+        bad.write_bytes(b"not an ELF file")
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_load_attempted", False)
+        monkeypatch.setattr(native, "_load_error", None)
+        monkeypatch.setenv("LOONG_NATIVE_LIB", str(bad))
+        with pytest.raises(native.NativeLibraryError, match="failed to load"):
+            native.get_lib()

@@ -3,9 +3,12 @@
 The Pallas kernel body IS build_extract_core — the same walk the XLA path
 jits — so any divergence here means the pallas_call plumbing (blocking,
 state layout, output dtypes) broke semantics. Runs in interpreter mode on
-CPU (compiled Mosaic needs real TPU hardware).
+CPU (compiled Mosaic needs real TPU hardware): every construction here
+passes ``interpret=True`` — the package never infers it from the backend.
 """
 
+import ast
+import pathlib
 import re
 
 import numpy as np
@@ -57,7 +60,7 @@ def _inputs_for(pattern: str):
 def test_pallas_matches_xla_and_re(pattern):
     prog = compile_tier1(pattern)
     xla = ExtractKernel(prog)
-    pallas = PallasExtractKernel(prog)  # interpret mode on CPU
+    pallas = PallasExtractKernel(prog, interpret=True)
     labelled = _inputs_for(pattern)
     lines = [ln for ln, _ in labelled]
     arena = np.frombuffer(b"".join(lines), dtype=np.uint8)
@@ -100,6 +103,10 @@ def test_engine_pallas_env_override(monkeypatch):
     monkeypatch.setenv("LOONG_PALLAS", "1")
     from loongcollector_tpu.ops.regex.engine import RegexEngine
     eng = RegexEngine(r"(\d+)/(\w+)")
+    # off-chip the engine's own (compiled) Pallas kernel fails loudly, so
+    # the test hands it the interpreted one the engine would otherwise build
+    eng._pallas_kernel = PallasExtractKernel(eng._segment_kernel.program,
+                                             interpret=True)
     lines = [b"12/ab", b"nope", b"7/z"]
     arena = np.frombuffer(b"".join(lines), dtype=np.uint8)
     lens = np.array([len(l) for l in lines], np.int32)
@@ -109,3 +116,42 @@ def test_engine_pallas_env_override(monkeypatch):
     assert list(res.ok) == [True, False, True]
     # spans are arena-absolute
     assert (res.cap_off[2, 0], res.cap_len[2, 0]) == (9, 1)
+
+
+def test_engine_pallas_off_chip_fails_loudly(monkeypatch):
+    """LOONG_PALLAS=1 on a CPU backend must not quietly interpret: the
+    compiled kernel is asked for and jax refuses it."""
+    monkeypatch.setenv("LOONG_PALLAS", "1")
+    from loongcollector_tpu.ops.regex.engine import RegexEngine
+    eng = RegexEngine(r"(\d+)/(\w+)")
+    kern = eng._single_device_kernel()
+    assert isinstance(kern, PallasExtractKernel)
+    rows = np.zeros((256, 128), np.uint8)
+    with pytest.raises(ValueError, match="interpret mode"):
+        kern(rows, np.zeros(256, np.int32))
+
+
+def test_no_module_infers_interpret_from_backend():
+    """``interpret`` is passed by tests, never inferred: nothing under
+    loongcollector_tpu/ may compute an interpret flag from the backend."""
+    pkg = pathlib.Path(__file__).resolve().parent.parent / "loongcollector_tpu"
+    backend_words = ("default_backend", "devices(", "platform", "JAX_PLATFORMS")
+    offenders = []
+    for path in sorted(pkg.rglob("*.py")):
+        src = path.read_text()
+        if "interpret" not in src:
+            continue
+        for node in ast.walk(ast.parse(src)):
+            value = None
+            if isinstance(node, ast.keyword) and node.arg \
+                    and "interpret" in node.arg:
+                value = node.value
+            elif isinstance(node, (ast.Assign, ast.AnnAssign)):
+                targets = node.targets if isinstance(node, ast.Assign) \
+                    else [node.target]
+                if any("interpret" in ast.unparse(t) for t in targets):
+                    value = node.value
+            if value is not None and any(
+                    w in ast.unparse(value) for w in backend_words):
+                offenders.append(f"{path.relative_to(pkg)}:{node.value.lineno}")
+    assert not offenders, offenders
