@@ -1179,7 +1179,7 @@ def _collect_utilization(pqm, p, bh, runner, n_groups=24, window_s=8.0):
         u = plane.utilization()
         util.update({
             "budget_occupancy_avg": round(u["occupancy_avg"], 6),
-            "device_busy_fraction": round(u["busy_fraction"], 4),
+            "device_inflight_fraction": round(u["inflight_fraction"], 4),
             "device_idle_while_backlogged_ms":
                 round(u["idle_while_backlogged_ms"], 1),
             "submit_queue_depth": u["submit_queue_depth"],

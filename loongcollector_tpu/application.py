@@ -22,6 +22,7 @@ from .config.common_provider import CommonConfigProvider
 from .config.onetime import OnetimeConfigInfoManager
 from .config.watcher import PipelineConfigWatcher
 from .input.file.file_server import FileServer
+from .monitor import startup
 from .monitor.alarms import AlarmManager
 from .monitor.metrics import WriteMetrics
 from .monitor.watchdog import LoongCollectorMonitor
@@ -276,6 +277,7 @@ class Application:
         # data batch never stalls behind a compiler invocation
         from . import native as _native
         _native.get_lib()
+        startup.mark("native_loaded")
         # declarative runner matrix (reference PluginRegistry.cpp:162-196):
         # every singleton input runner gets wired — and later stopped —
         # through the registry, so new runners need no Application edits
@@ -316,6 +318,7 @@ class Application:
                 diff = self.config_watcher.check_config_diff()
                 if not diff.empty():
                     self.pipeline_manager.update_pipelines(diff)
+                    startup.mark("pipelines_started")
                 # a control-plane-faulted removal must complete even if
                 # the config dir never changes again (loongtenant)
                 self.pipeline_manager.retry_pending_removals()
@@ -504,6 +507,7 @@ def main(argv=None) -> int:
                              "CPU drives); without it a missing "
                              "accelerator is fatal")
     args = parser.parse_args(argv)
+    startup.mark("imports_done")
 
     # Bring the backend up BEFORE anything compiles: place the persistent
     # compile cache, say once where this process computes, and refuse to
@@ -514,6 +518,7 @@ def main(argv=None) -> int:
     except device_info.NoAcceleratorError as e:
         log.critical("%s", e)
         return 2
+    startup.mark("backend_up")
     log.info("device backend: platform=%s device_kind=%s device_count=%d "
              "jax=%s jaxlib=%s libtpu=%s compile_cache=%s "
              "runtime_rss_mb=%d (outside memory_usage_limit_mb)",

@@ -51,12 +51,11 @@ class FlusherFile(Flusher):
         return True
 
     def _flush_groups(self, groups: List[PipelineEventGroup]) -> None:
-        def write():
-            data = self.serializer.serialize(groups)
+        def write(data: bytes) -> None:
             with self._lock:
                 with open(self.file_path, "ab") as f:
                     f.write(data)
-        self._ledger_terminal_write(groups, write)
+        self._serialize_and_write(groups, self.serializer.serialize, write)
 
     def flush_all(self) -> bool:
         self.batcher.flush_all()

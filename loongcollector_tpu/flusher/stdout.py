@@ -42,11 +42,10 @@ class FlusherStdout(Flusher):
         return True
 
     def _flush_groups(self, groups: List[PipelineEventGroup]) -> None:
-        def write():
-            data = self.serializer.serialize(groups)
+        def write(data: bytes) -> None:
             self._stream.write(data.decode("utf-8", "replace"))
             self._stream.flush()
-        self._ledger_terminal_write(groups, write)
+        self._serialize_and_write(groups, self.serializer.serialize, write)
 
     def flush_all(self) -> bool:
         self.batcher.flush_all()

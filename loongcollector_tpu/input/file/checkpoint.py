@@ -24,6 +24,7 @@ import threading
 import time
 from typing import Dict, Optional, Tuple
 
+from ... import trace
 from ...runner import ack_watermark
 from .reader import ReaderCheckpoint
 
@@ -148,4 +149,7 @@ class CheckPointManager:
 
     def dump_periodically(self, interval: float = 5.0) -> None:
         if time.monotonic() - self.last_dump >= interval:
-            self.dump()
+            # runs on the file server's own thread: a slow fsync here is
+            # a pause of the reader, so it gets a span
+            with trace.span("checkpoint.dump"):
+                self.dump()

@@ -29,6 +29,45 @@ from .reader import LogFileReader
 
 log = get_logger("file_server")
 
+
+class FileInputStats:
+    """The file server's always-on plain counters: integer adds per round
+    or per chunk read, no lock.  One writer at a time — the event thread,
+    and ``stop()`` only after it has joined that thread; readers
+    (/debug/status, the metrics mirror) take whatever they find."""
+
+    __slots__ = ("rounds_total", "rounds_throttled", "throttle_sleep_s",
+                 "reads_total", "read_bytes_total", "reads_blocked_total",
+                 "push_rejected_total")
+
+    def __init__(self) -> None:
+        self.rounds_total = 0
+        self.rounds_throttled = {3: 0, 8: 0}   # by sleep stretch factor
+        self.throttle_sleep_s = 0.0      # slept in stretched sleeps
+        self.reads_total = 0             # chunk reads that shipped a group
+        self.read_bytes_total = 0        # their SOURCE bytes
+        self.reads_blocked_total = 0     # watermark high before the read
+        self.push_rejected_total = 0     # rolled back after it
+
+    def snapshot(self) -> dict:
+        return {
+            "rounds_total": self.rounds_total,
+            "rounds_throttled_total": {str(f): n for f, n in
+                                       self.rounds_throttled.items()},
+            "throttle_sleep_seconds_total": round(self.throttle_sleep_s, 6),
+            "reads_total": self.reads_total,
+            "read_bytes_total": self.read_bytes_total,
+            "reads_blocked_total": self.reads_blocked_total,
+            "push_rejected_total": self.push_rejected_total,
+        }
+
+
+def status() -> Optional[dict]:
+    """The /debug/status ``file_input`` section; None before a file
+    server exists (observe-only: never constructs one)."""
+    fs = FileServer._instance
+    return fs.stats.snapshot() if fs is not None else None
+
 DISCOVERY_INTERVAL_S = 1.0
 
 # reference parity knobs (reader/LogFileReader.cpp:70 read_delay_alarm_duration,
@@ -118,6 +157,7 @@ class FileServer:
         # waiting out the poll sleep
         self._blocked_wake = threading.Event()
         self._feedback_keys: set = set()
+        self.stats = FileInputStats()
         # path -> last alarm time (per-file alarm rate limiting)
         self._delay_alarms: Dict[str, float] = {}
         self._reader_limit_alarms: Dict[str, float] = {}
@@ -202,10 +242,12 @@ class FileServer:
     # -- main loop ----------------------------------------------------------
 
     def _run(self) -> None:
+        stats = self.stats      # this thread is its writer while it runs
         while self._running:
             if self._paused:
                 time.sleep(IDLE_SLEEP_S)
                 continue
+            stats.rounds_total += 1
             try:
                 busy = self._round()
                 self.checkpoints.dump_periodically(
@@ -216,12 +258,10 @@ class FileServer:
             base = (IDLE_SLEEP_INOTIFY_S
                     if self._listener is not None and self._watch_complete
                     else IDLE_SLEEP_S)
-            sleep = base
             level = self.cpu_level_provider() if self.cpu_level_provider else 0.0
-            if level > 0.9:
-                sleep = base * 8             # heavy throttle near the limit
-            elif level > 0.7:
-                sleep = base * 3
+            # heavy throttle near the limit, a lighter one before it
+            stretch = 8 if level > 0.9 else 3 if level > 0.7 else 0
+            sleep = base * stretch if stretch else base
             if busy and level <= 0.9:
                 continue
             if self._blocked_wake.is_set():
@@ -236,6 +276,11 @@ class FileServer:
                 # below cannot see the feedback event, so bound the sleep
                 # instead of waiting out the full (possibly throttled) tick
                 sleep = min(sleep, 0.05)
+            if stretch:
+                # the governor stretched this sleep: counted, and timed as
+                # slept (an inotify event or a feedback wakeup cuts it)
+                stats.rounds_throttled[stretch] += 1
+                t_sleep = time.monotonic()
             if self._listener is not None:
                 # sleep ON the inotify fd: an append wakes the thread now,
                 # not at the next poll tick (sub-poll-interval tail latency)
@@ -247,6 +292,8 @@ class FileServer:
                                 st.last_discovery = 0.0
             else:
                 self._blocked_wake.wait(sleep)
+            if stretch:
+                stats.throttle_sleep_s += time.monotonic() - t_sleep
         if self._listener is not None:
             self._listener.close()
             self._listener = None
@@ -465,9 +512,11 @@ class FileServer:
         """Read until empty or back-pressure; returns True if data moved."""
         moved = False
         pqm = self.process_queue_manager
+        stats = self.stats      # one writer at a time: see FileInputStats
         for _ in range(64):  # bounded burst per round
             if pqm is not None and not pqm.is_valid_to_push(st.queue_key):
                 # watermark high: requeue for the feedback wakeup
+                stats.reads_blocked_total += 1
                 self._register_feedback(st.queue_key)
                 break
             try:
@@ -476,6 +525,8 @@ class FileServer:
                 break  # reader closed concurrently (config removal)
             if group is None or not reader.is_open:
                 break
+            stats.reads_total += 1
+            stats.read_bytes_total += reader._last_consumed  # SOURCE bytes
             if recovery.suppress_duplicate(group):
                 # previous run already delivered this exact span (acked
                 # after the last checkpoint dump): count it, advance the
@@ -496,6 +547,7 @@ class FileServer:
                     # queue rejected after read: restore offset (SOURCE
                     # bytes) and the multiline stitch state together
                     reader.rollback_last()
+                    stats.push_rejected_total += 1
                     self._register_feedback(st.queue_key)
                     break
             moved = True

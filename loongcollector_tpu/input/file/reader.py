@@ -197,6 +197,23 @@ class LogFileReader:
 
     def read(self, force_flush: bool = False
              ) -> Optional[PipelineEventGroup]:
+        """`_read` under an ``input.file.read`` span when it shipped a
+        group (pread + newline align + presplit; a poll that found nothing
+        leaves none).  Tracing off: one global read."""
+        tracer = trace.active_tracer()
+        if tracer is None:
+            return self._read(force_flush)
+        t0 = time.perf_counter()
+        group = self._read(force_flush)
+        if group is not None:
+            tracer.record_timed(
+                "input", "input.file.read", t0, time.perf_counter() - t0,
+                {"offset": self.offset - self._last_consumed,
+                 "nbytes": self._last_consumed, "rows": len(group)})
+        return group
+
+    def _read(self, force_flush: bool = False
+              ) -> Optional[PipelineEventGroup]:
         """One chunked read → event group with ONE RawEvent (zero-copy).
 
         Rolls back to the last '\\n' so only complete lines ship; if the

@@ -110,6 +110,12 @@ def render() -> str:
         _slo.export_refresh()
     except Exception:  # noqa: BLE001
         pass
+    try:
+        # the file server's plain counters mirror the same way
+        from . import runtime_stats as _rs
+        _rs.refresh_file_input()
+    except Exception:  # noqa: BLE001
+        pass
     by_name: Dict[Tuple[str, str], List[str]] = {}
 
     def emit(name: str, typ: str, line: str) -> None:
@@ -394,6 +400,33 @@ def collect_status() -> dict:
                 doc["xprof"] = xdoc
     except Exception:  # noqa: BLE001
         pass
+    try:
+        # loongtrace: span / timeline-event counts and spans the ring had
+        # to evict; absent while LOONG_TRACE is off
+        from .. import trace as _trace
+        tdoc = _trace.status()
+        if tdoc is not None:
+            doc["trace"] = tdoc
+    except Exception:  # noqa: BLE001
+        pass
+    try:
+        # the file server's always-on counters: rounds, governor-stretched
+        # sleeps, chunk reads, reads blocked on a full queue; absent in an
+        # agent without a file input
+        from . import runtime_stats as _rs
+        fdoc = _rs.file_input_status()
+        if fdoc is not None:
+            doc["file_input"] = fdoc
+    except Exception:  # noqa: BLE001
+        pass
+    try:
+        # start-up phases, seconds since process start (monitor/startup.py)
+        from . import startup as _startup
+        sdoc = _startup.status()
+        if sdoc is not None:
+            doc["startup"] = sdoc
+    except Exception:  # noqa: BLE001
+        pass
     return doc
 
 
@@ -405,6 +438,7 @@ STATUS_SECTIONS = (
     "device", "streaming", "mesh", "fusion", "stage_fusion", "parse",
     "flight", "profiler", "recovery",
     "device_memory", "compile", "xprof",
+    "trace", "file_input", "startup",
 )
 
 

@@ -65,6 +65,8 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from .. import trace
+
 ENV_LEDGER = "LOONG_LEDGER"
 ENV_AUDIT = "LOONG_LEDGER_AUDIT"
 ENV_AUDIT_INTERVAL = "LOONG_LEDGER_AUDIT_INTERVAL"
@@ -589,7 +591,10 @@ class ConservationAuditor:
     def _run(self) -> None:
         while not self._stop.wait(self.interval_s):
             try:
-                self.audit_once()
+                # a pass holds the GIL against the workers: timed as a
+                # span (`ledger.audit`) while tracing is on
+                with trace.span("ledger.audit"):
+                    self.audit_once()
             except Exception:  # noqa: BLE001 — the auditor observes; it
                 # must never take the agent down with it
                 from ..utils.logger import get_logger

@@ -26,12 +26,48 @@ _prof_rec = MetricsRecord(category="profiler",
                           labels={"component": "loongprof"})
 _xprof_rec = MetricsRecord(category="device_xprof",
                            labels={"component": "loongxprof"})
+_file_rec = MetricsRecord(category="file_input",
+                          labels={"component": "file_server"})
+_file_throttle_recs = {
+    factor: MetricsRecord(category="file_input",
+                          labels={"component": "file_server",
+                                  "factor": factor})
+    for factor in ("3", "8")}
+
+
+def file_input_status():
+    """The file server's counters (/debug/status ``file_input``); None in
+    an agent that never imported the file input or has no file server yet
+    (observe-only: imports and constructs nothing)."""
+    import sys
+    mod = sys.modules.get("loongcollector_tpu.input.file.file_server")
+    return mod.status() if mod is not None else None
+
+
+def refresh_file_input() -> None:
+    """The file server's always-on counters into the metrics tree
+    (``loong_rounds_total`` … ``loong_rounds_throttled_total{factor=}``):
+    lifetime totals set as gauges, so the self-monitor's counter drain
+    never resets them.  Called before every snapshot and every scrape."""
+    doc = file_input_status()
+    if doc is None:
+        return
+    for name, value in doc.items():
+        if name == "rounds_throttled_total":
+            for factor, n in value.items():
+                _file_throttle_recs[factor].gauge(name).set(n)
+        else:
+            _file_rec.gauge(name).set(value)
 
 
 def refresh() -> None:
     """Pull current values into the gauge records (called by the
     self-monitor right before it snapshots).  Every section is fail-soft:
     telemetry must never take down the monitor thread."""
+    try:
+        refresh_file_input()
+    except Exception:  # noqa: BLE001
+        pass
     try:
         from ..ops.device_plane import DevicePlane
         plane = DevicePlane._instance   # observe-only: never construct
@@ -47,13 +83,16 @@ def refresh() -> None:
             _plane_rec.gauge("budget_held_fraction_now").set(
                 u["held_fraction"])
             _plane_rec.gauge("budget_occupancy_avg").set(u["occupancy_avg"])
-            _plane_rec.gauge("device_busy_fraction").set(u["busy_fraction"])
+            # time with bytes in flight — NOT chip load: it read 0.45 where
+            # the device trace read 0.015 busy (PERF.md section 6)
+            _plane_rec.gauge("device_inflight_fraction").set(
+                u["inflight_fraction"])
             # monotone integrals next to the lifetime averages: rate()
             # over a scrape pair recovers the RECENT fraction, which the
             # averages cannot show on a long-lived agent
             _plane_rec.gauge("budget_occupancy_integral_seconds").set(
                 u["occupancy_integral_s"])
-            _plane_rec.gauge("device_busy_seconds").set(u["busy_s"])
+            _plane_rec.gauge("device_inflight_seconds").set(u["inflight_s"])
             _plane_rec.gauge("submit_queue_depth").set(
                 u["submit_queue_depth"])
             _plane_rec.gauge("device_idle_while_backlogged_ms").set(

@@ -13,6 +13,7 @@ import threading
 import time
 from typing import Optional
 
+from .. import trace
 from ..models import PipelineEventGroup
 from ..utils.logger import get_logger
 from .alarms import AlarmManager
@@ -89,7 +90,8 @@ class SelfMonitorServer:
                 continue
             last = time.monotonic()
             try:
-                self.send_once()
+                with trace.span("self_monitor.tick"):
+                    self.send_once()
             except Exception:  # noqa: BLE001
                 log.exception("self monitor send failed")
 

@@ -220,9 +220,17 @@ class WatchedFn:
 
 
 def watched_jit(fn, family: str, **jit_kwargs) -> WatchedFn:
-    """``jax.jit(fn, **jit_kwargs)`` under compile accounting — the only
-    sanctioned way to jit a kernel under ops/ (loonglint: unwatched-jit)."""
+    """``jax.jit(fn, **jit_kwargs)`` under compile accounting and under
+    the name ``loong_<family>`` — the only sanctioned way to jit a kernel
+    under ops/ (loonglint: unwatched-jit)."""
     import jax
+    # the compiled module carries a name the program chose, whatever XLA
+    # calls the ops inside it: the profiler's "XLA Modules" line reads
+    # ``jit_loong_<family>(...)`` for every dispatch of the family
+    try:
+        fn.__name__ = fn.__qualname__ = "loong_" + family
+    except (AttributeError, TypeError):
+        pass        # a callable that cannot be renamed keeps its name
     return WatchedFn(jax.jit(fn, **jit_kwargs), family)
 
 

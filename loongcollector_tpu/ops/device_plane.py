@@ -305,6 +305,18 @@ def set_budget_relief(fn: Optional[Callable[[], bool]]) -> None:
     _tls.relief = fn
 
 
+_first_dispatch_marked = False
+
+
+def _mark_first_dispatch() -> None:
+    """Start-up phase ``first_dispatch`` (monitor/startup.py): the first
+    dispatch of the process has materialised.  One global read after."""
+    global _first_dispatch_marked
+    _first_dispatch_marked = True
+    from ..monitor import startup
+    startup.mark("first_dispatch")
+
+
 def _budget_from_env() -> int:
     try:
         return int(os.environ.get("LOONG_DEVICE_INFLIGHT_BYTES",
@@ -377,21 +389,33 @@ class DeviceFuture:
             prof.push_marker("device", "materialise")
             try:
                 xid = self._xid
-                if xid:
-                    # exec leg: dispatch return → first output ready (the
-                    # device-execution window the host can observe); d2h
-                    # leg: the numpy materialisation itself.  Without a
+                tracer = trace.active_tracer()
+                if xid or tracer is not None:
+                    # exec leg / device.wait: dispatch return → first
+                    # output ready (the device-execution window the host
+                    # can observe); d2h leg / device.d2h: the numpy
+                    # materialisation itself.  Without a
                     # block_until_ready the split collapses into d2h.
+                    # One pair of readings feeds both planes.
                     t_exec = time.perf_counter()
                     first = self._outputs[0] if self._outputs else None
                     if hasattr(first, "block_until_ready"):
                         first.block_until_ready()
                     t_d2h = time.perf_counter()
-                    xprof.leg(xid, "exec", t_exec, t_d2h - t_exec)
-                    self._materialised = [np.asarray(o)
-                                          for o in self._outputs]
-                    xprof.leg(xid, "d2h", t_d2h,
-                              time.perf_counter() - t_d2h)
+                    try:
+                        self._materialised = [np.asarray(o)
+                                              for o in self._outputs]
+                    finally:
+                        t_end = time.perf_counter()
+                        xprof.leg(xid, "exec", t_exec, t_d2h - t_exec)
+                        xprof.leg(xid, "d2h", t_d2h, t_end - t_d2h)
+                        if tracer is not None:
+                            attrs = xprof.leg_attrs(self._nbytes, xid)
+                            tracer.record_timed("device", "device.wait",
+                                                t_exec, t_d2h - t_exec,
+                                                attrs)
+                            tracer.record_timed("device", "device.d2h",
+                                                t_d2h, t_end - t_d2h, attrs)
                 else:
                     self._materialised = [np.asarray(o)
                                           for o in self._outputs]
@@ -400,6 +424,8 @@ class DeviceFuture:
             roundtrip_histogram().observe(time.perf_counter() - self._t0)
             if self._span is not None:
                 self._span.end("ok")
+            if not _first_dispatch_marked:
+                _mark_first_dispatch()
             return self._materialised
         except BaseException as e:  # noqa: BLE001 — record, release, re-raise
             self._error = e
@@ -557,12 +583,15 @@ class DevicePlane:
                 "held_fraction": (self._inflight / self.budget_bytes
                                   if self.budget_bytes else 0.0),
                 "occupancy_avg": self._occupancy_integral / elapsed,
-                "busy_fraction": self._busy_s / elapsed,
+                # time with bytes in flight over wall time: the budget's
+                # view, not the chip's (a dispatch is "in flight" from
+                # submit until the host materialises it)
+                "inflight_fraction": self._busy_s / elapsed,
                 # raw monotone integrals: lifetime averages go inert on a
                 # long-lived agent, but rate() over these recovers the
                 # RECENT occupancy/busy fraction from any scrape pair
                 "occupancy_integral_s": self._occupancy_integral,
-                "busy_s": self._busy_s,
+                "inflight_s": self._busy_s,
                 "idle_while_backlogged_ms": self._idle_backlogged_ms,
                 "submit_queue_depth": self._waiters,
                 "dispatched_total": self._dispatched,
@@ -584,6 +613,7 @@ class DevicePlane:
         deadlock-free: every waiting thread can always release the budget it
         itself holds, so some thread always makes progress."""
         waiting = False
+        wait_sp = None
         try:
             while True:
                 with self._freed:
@@ -607,6 +637,13 @@ class DevicePlane:
                         # budget (or the device behind it) gates the host
                         waiting = True
                         self._waiters += 1
+                        tracer = trace.active_tracer()
+                        if tracer is not None:
+                            # only a dispatch that really blocks gets the
+                            # span; what on_wait drains nests under it
+                            wait_sp = tracer.start_stage(
+                                "device", "device.acquire",
+                                {"nbytes": nbytes, "on": "budget"})
                 progressed = on_wait() if on_wait is not None else False
                 if not progressed:
                     relief = getattr(_tls, "relief", None)
@@ -616,6 +653,8 @@ class DevicePlane:
                     with self._freed:
                         self._freed.wait(timeout=0.05)
         finally:
+            if wait_sp is not None:
+                wait_sp.end()
             if waiting:
                 with self._lock:
                     self._waiters -= 1
@@ -656,14 +695,24 @@ class DevicePlane:
         its bookkeeping simple and errors surface at the (ordered)
         materialisation point."""
         tenant = getattr(_tls, "tenant", None)
+        tracer = trace.active_tracer()
         if tenant is not None and on_wait is not None:
             # per-tenant budget share (loongtenant): a tenant already past
             # budget/n_tenants drains ITS OWN oldest in-flight chunk before
             # dispatching more.  Other tenants never enter this loop — one
             # hot pipeline's backlog costs only that pipeline latency
-            while tenant_over_share(tenant, nbytes, self.budget_bytes):
-                if not on_wait():
-                    break
+            wait_sp = None
+            try:
+                while tenant_over_share(tenant, nbytes, self.budget_bytes):
+                    if wait_sp is None and tracer is not None:
+                        wait_sp = tracer.start_stage(
+                            "device", "device.acquire",
+                            {"nbytes": nbytes, "on": "tenant_share"})
+                    if not on_wait():
+                        break
+            finally:
+                if wait_sp is not None:
+                    wait_sp.end()
         inflight_now = self._acquire(nbytes, should_abort, on_wait)
         if tenant is not None:
             _tenant_note(tenant, nbytes)
@@ -671,13 +720,19 @@ class DevicePlane:
         if self.budget_bytes:
             held_fraction_histogram().observe(
                 inflight_now / self.budget_bytes)
-        tracer = trace.active_tracer()
-        span = (tracer.child_or_sampled("device", "device.roundtrip",
-                                        {"nbytes": nbytes})
-                if tracer is not None else None)
+        span = None
+        if tracer is not None:
+            # the round trip outlives the stage that submits it: it hangs
+            # from the group's root, so `.dispatch` keeps its self time
+            root = tracer.root_span()
+            span = (tracer.start_span("device.roundtrip", parent=root,
+                                      attrs={"nbytes": nbytes})
+                    if root is not None else
+                    tracer.child_or_sampled("device", "device.roundtrip",
+                                            {"nbytes": nbytes}))
         # loongxprof: mint the dispatch id AFTER budget admission, so the
         # submit leg measures the dispatch call, not the back-pressure
-        # wait (which the tracer's host span already covers).  0 when off.
+        # wait (the tracer's device.acquire span covers that).  0 when off.
         xid = xprof.begin_dispatch(nbytes)
         if xid and span is not None:
             # the host/device correlation key the timeline export lines
@@ -689,19 +744,28 @@ class DevicePlane:
             # budget released at the consume point (result/release)
             chaos.faultpoint(FP_SUBMIT)
             prof.push_marker("device", "dispatch")
+            timed = bool(xid) or tracer is not None
             if xid:
                 # current-dispatch TLS: code running INSIDE the kernel
                 # call (ShardedKernel._dispatch) attaches its H2D legs to
                 # this dispatch
                 xprof.set_current_dispatch(xid)
+            if timed:
                 t_submit = time.perf_counter()
             try:
                 outputs = kernel(*args)
             finally:
-                if xid:
-                    xprof.leg(xid, "submit", t_submit,
-                              time.perf_counter() - t_submit)
-                    xprof.set_current_dispatch(0)
+                if timed:
+                    # submit leg / device.submit: the dispatch call — one
+                    # pair of readings for both planes
+                    dt_submit = time.perf_counter() - t_submit
+                    if xid:
+                        xprof.leg(xid, "submit", t_submit, dt_submit)
+                        xprof.set_current_dispatch(0)
+                    if tracer is not None:
+                        tracer.record_timed("device", "device.submit",
+                                            t_submit, dt_submit,
+                                            xprof.leg_attrs(nbytes, xid))
                 prof.pop_marker()
             if not isinstance(outputs, (tuple, list)):
                 outputs = (outputs,)

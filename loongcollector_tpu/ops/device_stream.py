@@ -47,6 +47,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from .. import chaos
+from .. import trace
 from . import xprof
 from .device_batch import MIN_BATCH, pack_rows
 
@@ -185,9 +186,10 @@ class BatchSlot:
         self.lengths = np.zeros(B, dtype=np.int32)
         self.origins = np.zeros(B, dtype=np.int32)
         self._leased = False
-        # loongxprof: last pack()'s stopwatch (perf_counter start, dur s)
-        # — the dispatch loop attaches it as the h2d leg.  None while the
-        # timeline is off (the pack pays no perf_counter calls then)
+        # last pack()'s stopwatch (perf_counter start, dur s) — the
+        # dispatch loop hands it to xprof.note_dispatch, which makes it
+        # the timeline's h2d leg and the tracer's device.pack span.  None
+        # while both are off (the pack pays no perf_counter calls then)
         self.pack_t0: Optional[float] = None
         self.pack_dur: Optional[float] = None
 
@@ -197,7 +199,7 @@ class BatchSlot:
         feeds the auto-tuner (per chip lane when the dispatching worker is
         lane-bound — loongmesh keys the tuner's floors per chip so one
         sparse chip cannot shrink every lane's geometry)."""
-        if xprof.is_active():
+        if xprof.is_active() or trace.is_active():
             self.pack_t0 = time.perf_counter()
             batch = pack_rows(arena, offsets, lengths, self.L, self.B,
                               out=(self.rows, self.lengths, self.origins))

@@ -110,6 +110,10 @@ def build_extract_fn_pallas(program: SegmentProgram,
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_VMEM_LIMIT),
             interpret=interpret,
+            # the device op's name (`%extract.N` on the profiler's XLA Ops
+            # line): stated, so that renaming the jitted function around
+            # it (compile_watch.watched_jit) cannot move it
+            name="extract",
         )(rows, lengths.astype(jnp.int32)[:, None])
         return ok2[:, 0] != 0, off, length
 

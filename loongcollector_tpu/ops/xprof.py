@@ -46,6 +46,8 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from .. import trace
+
 ENV_ENABLE = "LOONG_XPROF"
 
 _DISPATCH_CAP = 50_000        # bounded like the tracer's span ring
@@ -275,18 +277,33 @@ def close_dispatch(xid: int) -> None:
     t.close(xid)
 
 
+def leg_attrs(nbytes: int, xid: int) -> dict:
+    """What every device-leg span of the tracer carries: the dispatch's
+    bytes and, where this plane is on, the id that lines the span up with
+    the timeline's leg."""
+    return {"nbytes": nbytes, "dispatch_id": xid} if xid \
+        else {"nbytes": nbytes}
+
+
 def note_dispatch(fut, program: str, geometry: str,
                   pack_t0: Optional[float] = None,
                   pack_dur: Optional[float] = None) -> None:
     """One-call convenience for the dispatch loops (PendingParse,
     FusedDispatch, DeviceStream): attribute the future's dispatch to a
-    program + geometry and attach the pack/H2D leg the caller timed.
-    Disabled: a single branch."""
+    program + geometry and attach the pack/H2D leg the caller timed —
+    to the timeline as ``h2d`` and, the same reading, to the tracer as a
+    ``device.pack`` span under the stage that dispatched.  Both planes
+    off: the pack was not timed, two branches."""
     t = _timeline
-    if t is None:
+    if pack_dur is None and t is None:
         return
     xid = getattr(fut, "dispatch_id", 0)
-    if not xid:
+    if pack_dur is not None and pack_t0 is not None:
+        tracer = trace.active_tracer()
+        if tracer is not None:
+            tracer.record_timed("device", "device.pack", pack_t0, pack_dur,
+                                leg_attrs(getattr(fut, "_nbytes", 0), xid))
+    if t is None or not xid:
         return
     t.annotate(xid, program=program, geometry=geometry)
     if pack_dur is not None and pack_t0 is not None:

@@ -33,6 +33,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import trace
 from ..monitor import ledger
 from ..ops.device_batch import LENGTH_BUCKETS
 from ..ops.fused_pipeline import (FusedDispatch, fusion_enabled,
@@ -132,15 +133,28 @@ class FusedRun:
     def dispatch(self, groups) -> List:
         """Per-group tokens; a group fusion cannot take runs the member
         instances per-stage INLINE here (synchronously — the fused plane's
-        exception path, not its steady state) and gets a None token."""
-        tokens: List = []
-        for g in groups:
-            tok = self._dispatch_group(g)
-            if tok is None:
-                for inst in self.instances:
-                    inst.process([g])
-            tokens.append(tok)
-        return tokens
+        exception path, not its steady state) and gets a None token.
+        The run is one stage to the tracer too: a span of the processors'
+        own shape (``processor.fused_chain.dispatch`` / ``.complete``),
+        current for its body, so the device legs nest under it."""
+        tracer = trace.active_tracer()
+        sp = (tracer.start_stage("processor",
+                                 "processor.fused_chain.dispatch")
+              if tracer is not None else None)
+        ok = False
+        try:
+            tokens: List = []
+            for g in groups:
+                tok = self._dispatch_group(g)
+                if tok is None:
+                    for inst in self.instances:
+                        inst.process([g])
+                tokens.append(tok)
+            ok = True
+            return tokens
+        finally:
+            if sp is not None:
+                sp.end(None if ok else "error")
 
     def _dispatch_group(self, group):
         from ..processor.common import extract_source
@@ -161,34 +175,45 @@ class FusedRun:
         return (src, d)
 
     def complete(self, groups, tokens) -> None:
-        for g, tok in zip(groups, tokens):
-            if tok is None:
-                continue
-            src, d = tok
-            res = d.result()
-            rowmap = np.arange(res.n)
-            for inst, member, out in zip(self.instances, self.members,
-                                         res.stages):
-                # in/out booked per member at ITS apply point, after the
-                # previous members' compaction — the same funnel the
-                # staged path reports (a fused filter's drop must show as
-                # reduced input on the NEXT member, not phantom volume)
-                n_before = len(g)
-                inst.in_events.add(n_before)
-                inst.in_bytes.add(g.data_size())
-                t0 = time.perf_counter()
-                ok = False
-                try:
-                    rowmap = member.apply(g, src, out, rowmap)
-                    ok = True
-                finally:
-                    dt = time.perf_counter() - t0
-                    inst.stage_hist.observe(dt)
-                    inst.cost_ms.add(int(dt * 1000))
-                    if ledger.is_on():
-                        inst._ledger_delta(n_before, [g])
-                    if ok:
-                        inst.out_events.add(len(g))
+        tracer = trace.active_tracer()
+        sp = (tracer.start_stage("processor",
+                                 "processor.fused_chain.complete")
+              if tracer is not None else None)
+        ok = False
+        try:
+            for g, tok in zip(groups, tokens):
+                if tok is not None:
+                    self._complete_group(g, *tok)
+            ok = True
+        finally:
+            if sp is not None:
+                sp.end(None if ok else "error")
+
+    def _complete_group(self, g, src, d) -> None:
+        res = d.result()
+        rowmap = np.arange(res.n)
+        for inst, member, out in zip(self.instances, self.members,
+                                     res.stages):
+            # in/out booked per member at ITS apply point, after the
+            # previous members' compaction — the same funnel the
+            # staged path reports (a fused filter's drop must show as
+            # reduced input on the NEXT member, not phantom volume)
+            n_before = len(g)
+            inst.in_events.add(n_before)
+            inst.in_bytes.add(g.data_size())
+            t0 = time.perf_counter()
+            ok = False
+            try:
+                rowmap = member.apply(g, src, out, rowmap)
+                ok = True
+            finally:
+                dt = time.perf_counter() - t0
+                inst.stage_hist.observe(dt)
+                inst.cost_ms.add(int(dt * 1000))
+                if ledger.is_on():
+                    inst._ledger_delta(n_before, [g])
+                if ok:
+                    inst.out_events.add(len(g))
 
 
 def plan_fusion(chain) -> List[FusedRun]:

@@ -37,7 +37,9 @@ class ProcessorInstance:
         self.cost_ms = self.metrics.counter("total_process_time_ms")
         # per-stage latency distribution (the ParPaRaw per-stage balance
         # view); the async device stage observes dispatch and complete
-        # phases separately
+        # phases separately.  A stage's span is current on its thread for
+        # its body (start_stage) and popped when it ends in the finally,
+        # so the device legs and the flush spans nest under it
         self.stage_hist = self.metrics.histogram("stage_seconds")
 
     def init(self, config: Dict[str, Any], context: PluginContext) -> bool:
@@ -85,8 +87,8 @@ class ProcessorInstance:
         self.in_events.add(n_in)
         self.in_bytes.add(sum(g.data_size() for g in groups))
         tracer = trace.active_tracer()
-        sp = (tracer.child_or_sampled("processor",
-                                      "processor." + self.plugin.name)
+        sp = (tracer.start_stage("processor",
+                                 "processor." + self.plugin.name)
               if tracer is not None else None)
         prof.push_marker("plugin", self.plugin_id or self.plugin.name)
         t0 = time.perf_counter()
@@ -113,9 +115,9 @@ class ProcessorInstance:
         self.in_events.add(n_in)
         self.in_bytes.add(sum(g.data_size() for g in groups))
         tracer = trace.active_tracer()
-        sp = (tracer.child_or_sampled("processor",
-                                      "processor." + self.plugin.name
-                                      + ".dispatch")
+        sp = (tracer.start_stage("processor",
+                                 "processor." + self.plugin.name
+                                 + ".dispatch")
               if tracer is not None else None)
         prof.push_marker("plugin", self.plugin_id or self.plugin.name)
         t0 = time.perf_counter()
@@ -138,9 +140,9 @@ class ProcessorInstance:
                          tokens) -> None:
         n_in = sum(len(g) for g in groups)
         tracer = trace.active_tracer()
-        sp = (tracer.child_or_sampled("processor",
-                                      "processor." + self.plugin.name
-                                      + ".complete")
+        sp = (tracer.start_stage("processor",
+                                 "processor." + self.plugin.name
+                                 + ".complete")
               if tracer is not None else None)
         prof.push_marker("plugin", self.plugin_id or self.plugin.name)
         t0 = time.perf_counter()
@@ -208,9 +210,9 @@ class FlusherInstance:
         # batch + serialize + sender-queue enqueue all live under the
         # flusher plugin's send — one span covers the serialize stage
         tracer = trace.active_tracer()
-        sp = (tracer.child_or_sampled("flusher", "flusher.send",
-                                      attrs={"flusher": self.plugin.name,
-                                             "events": len(group)})
+        sp = (tracer.start_stage("flusher", "flusher.send",
+                                 attrs={"flusher": self.plugin.name,
+                                        "events": len(group)})
               if tracer is not None else None)
         ok = False
         try:

@@ -8,8 +8,10 @@ its own sender queue (interface/Flusher.cpp:57).
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, List, Optional
 
+from ... import trace
 from ...models import PipelineEventGroup
 from ...monitor import ledger, slo
 from ...runner import ack_watermark
@@ -222,6 +224,34 @@ class Flusher(Plugin):
             ledger.record(self._ledger_pipeline(), ledger.B_SEND_OK,
                           n_events, n_bytes, tag=self.name)
         return True
+
+    def _serialize_and_write(self, groups: List[PipelineEventGroup],
+                             serialize_fn, write_fn) -> bool:
+        """The batcher's flush of a write-through sink (file, stdout):
+        ``write_fn(serialize_fn(groups))`` under the terminal accounting
+        above, each half under a span of its own — ``flusher.serialize``
+        and ``flusher.write`` — children of ``flusher.send`` when the size
+        trigger fires on the worker, rootless when the batcher's timeout
+        thread fires."""
+        def run():
+            tracer = trace.active_tracer()
+            if tracer is None:
+                write_fn(serialize_fn(groups))
+                return
+            attrs = {"flusher": self.name, "groups": len(groups),
+                     "events": sum(len(g) for g in groups)}
+            none = contextlib.nullcontext()
+            sp = tracer.child_or_sampled("flusher", "flusher.serialize",
+                                         attrs)
+            with sp or none:
+                data = serialize_fn(groups)
+                attrs["nbytes"] = len(data)
+                if sp is not None:
+                    sp.set_attr("nbytes", len(data))
+            with tracer.child_or_sampled("flusher", "flusher.write",
+                                         attrs) or none:
+                write_fn(data)
+        return self._ledger_terminal_write(groups, run)
 
     def __init__(self) -> None:
         super().__init__()

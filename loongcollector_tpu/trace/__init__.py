@@ -5,14 +5,14 @@ this package is a single module-global read + branch when disabled —
 scripts/trace_overhead.py gates that contract.
 """
 
-from .tracer import (ENV_ENABLE, ENV_SAMPLE, ENV_SEED, Span, TraceConfig,
-                     TraceEvent, Tracer, active, active_tracer, current_span,
-                     disable, enable, event, install_from_env, is_active,
-                     span, start_span)
+from .tracer import (ENV_ENABLE, ENV_SAMPLE, ENV_SEED, VOLATILE_SPANS, Span,
+                     TraceConfig, TraceEvent, Tracer, active, active_tracer,
+                     current_span, disable, enable, event, install_from_env,
+                     is_active, span, start_span, status)
 
 __all__ = [
-    "ENV_ENABLE", "ENV_SAMPLE", "ENV_SEED", "Span", "TraceConfig",
-    "TraceEvent", "Tracer", "active", "active_tracer", "current_span",
-    "disable", "enable", "event", "install_from_env", "is_active", "span",
-    "start_span",
+    "ENV_ENABLE", "ENV_SAMPLE", "ENV_SEED", "VOLATILE_SPANS", "Span",
+    "TraceConfig", "TraceEvent", "Tracer", "active", "active_tracer",
+    "current_span", "disable", "enable", "event", "install_from_env",
+    "is_active", "span", "start_span", "status",
 ]

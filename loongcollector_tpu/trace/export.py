@@ -23,7 +23,7 @@ import json
 from typing import List, Optional
 
 from ..models import PipelineEventGroup
-from .tracer import _VOLATILE_ATTRS, Span, TraceEvent
+from .tracer import _VOLATILE_ATTRS, VOLATILE_SPANS, Span, TraceEvent
 
 
 def _put(ev, sb, key: str, value: str) -> None:
@@ -170,11 +170,14 @@ def chrome_trace(tracer=None, timeline=None) -> dict:
 def canonicalize(doc: dict) -> bytes:
     """The Chrome-trace document reduced to its timing-independent
     structure, canonically serialized: timestamps/durations dropped,
-    volatile args (dispatch ids, wall/thread) stripped, entries sorted.
+    volatile args (dispatch ids, wall/thread) stripped, spans that are
+    there by chance (``runtime.gc`` and its like) left out, entries sorted.
     Two runs of the same seeded storm yield identical bytes — the
     re-run-the-seed acceptance artifact, timeline edition."""
     entries: List[tuple] = []
     for ev in doc.get("traceEvents", []):
+        if ev.get("cat") == "host" and ev.get("name") in VOLATILE_SPANS:
+            continue    # a collection, a budget wait: present by chance
         args = tuple(sorted(
             (k, str(v)) for k, v in (ev.get("args") or {}).items()
             if k not in _CANON_VOLATILE))

@@ -28,7 +28,9 @@ via ``install_from_env()`` at application start.
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import gc
 import itertools
 import json
 import os
@@ -41,7 +43,12 @@ ENV_ENABLE = "LOONG_TRACE"
 ENV_SAMPLE = "LOONG_TRACE_SAMPLE"
 ENV_SEED = "LOONG_TRACE_SEED"
 
-_SPAN_CAP = 50_000      # finished-span ring bound
+#: finished-span ring bound.  The ring keeps the NEWEST spans (a full
+#: store used to refuse new ones, so /debug/timeline froze on the first
+#: 50,000); an evicted span counts as dropped.  Sized to hold a whole
+#: benchmark window that nobody drains (45 s at ~2,500 spans/s, twice
+#: over) — about 120 MB when full, held only while tracing is on.
+_SPAN_CAP = 1 << 18
 _EVENT_CAP = 100_000    # timeline bound (matches chaos._SCHEDULE_CAP)
 _MAX_EVENTS_PER_SPAN = 256
 
@@ -74,6 +81,18 @@ class Span:
     def set_attr(self, key: str, value) -> None:
         if not self._ended:
             self.attrs[key] = value
+
+    def close_at(self, start_perf: float, duration_s: float,
+                 store: bool = True) -> None:
+        """End with an interval somebody else measured (the device legs:
+        one pair of perf_counter readings feeds this tracer and xprof)."""
+        if self._ended:
+            return
+        self._ended = True
+        self.start_wall += start_perf - self._start_perf
+        self._start_perf = start_perf
+        self.duration_s = duration_s
+        self.tracer._record(self, store)
 
     def add_event(self, name: str, **attrs) -> None:
         if self._ended or len(self.events) >= _MAX_EVENTS_PER_SPAN:
@@ -143,7 +162,8 @@ class Tracer:
     def __init__(self, config: Optional[TraceConfig] = None):
         self.config = config or TraceConfig()
         self._lock = threading.Lock()
-        self._spans: List[Span] = []          # finished spans, arrival order
+        # finished spans, arrival order; the newest _SPAN_CAP of them
+        self._spans: collections.deque = collections.deque(maxlen=_SPAN_CAP)
         self._timeline: List[TraceEvent] = []
         self._event_seq = itertools.count()
         self._span_ids = itertools.count(1)
@@ -151,6 +171,15 @@ class Tracer:
         self._sample_cache: Dict[str, bool] = {}
         self._group_seq: Dict[str, int] = {}
         self._tls = threading.local()
+        # every finished span's duration, by name, in the normal metrics
+        # tree (loong_span_seconds{name=...}): rare spans are counted over
+        # the whole run, whatever the span ring still holds
+        self._span_hists: Dict[str, object] = {}
+        # collections the gc hook saw, not yet folded in: the hook runs at
+        # any bytecode boundary, possibly under this tracer's own lock, so
+        # it only appends here (no lock, no span) — see note_gc
+        self._gc_pending: List[tuple] = []
+        self._gc_fold_lock = threading.Lock()
 
     # -- sampling (deterministic per key) -----------------------------------
 
@@ -210,12 +239,67 @@ class Tracer:
             return None
         return self.start_span(name, trace_id=key, attrs=attrs)
 
-    def _record(self, span: Span) -> None:
-        with self._lock:
-            if len(self._spans) < _SPAN_CAP:
+    def start_stage(self, stream: str, name: str,
+                    attrs: Optional[dict] = None) -> Optional[Span]:
+        """`child_or_sampled`, made current on this thread: what the stage
+        calls synchronously nests under it, so its self time is its own.
+        `end()` pops it — call that from the stage's ``finally``."""
+        sp = self.child_or_sampled(stream, name, attrs)
+        if sp is not None:
+            self.push_current(sp)
+        return sp
+
+    def record_timed(self, stream: str, name: str, start_perf: float,
+                     duration_s: float, attrs: Optional[dict] = None) -> None:
+        """A finished span from an interval already measured, under the
+        `child_or_sampled` policy: child of the current span, else drawn
+        from ``stream``'s key sequence."""
+        sp = self.child_or_sampled(stream, name, attrs)
+        if sp is not None:
+            sp.close_at(start_perf, duration_s)
+
+    def span_histogram(self, name: str):
+        h = self._span_hists.get(name)
+        if h is None:
+            from ..monitor.metrics import shared_histogram
+            h = self._span_hists[name] = shared_histogram(
+                "span_seconds", category="trace", labels={"name": name})
+        return h
+
+    def note_gc(self, start_perf: float, duration_s: float,
+                generation: int) -> None:
+        """The gc hook's whole work: one lock-free append.  The next
+        recorded span folds the list (the periodic spans see to it that
+        one comes within the minute on an idle agent)."""
+        self._gc_pending.append((start_perf, duration_s, generation,
+                                 self.current_span()))
+
+    def _fold_gc(self) -> None:
+        """Pending collections into `runtime.gc` spans: the histogram takes
+        every one, the span ring only generation >= 1 or >= 1 ms."""
+        if not self._gc_fold_lock.acquire(blocking=False):
+            return                      # another thread is folding them
+        try:
+            pending, self._gc_pending = self._gc_pending, []
+            for start, dur, gen, parent in pending:
+                Span(self, "runtime.gc",
+                     parent.trace_id if parent is not None else "",
+                     next(self._span_ids),
+                     parent.span_id if parent is not None else None,
+                     {"generation": gen}).close_at(
+                    start, dur, store=gen >= 1 or dur >= 1e-3)
+        finally:
+            self._gc_fold_lock.release()
+
+    def _record(self, span: Span, store: bool = True) -> None:
+        if self._gc_pending and span.name != "runtime.gc":
+            self._fold_gc()
+        self.span_histogram(span.name).observe(span.duration_s or 0.0)
+        if store:
+            with self._lock:
+                if len(self._spans) == _SPAN_CAP:
+                    self._dropped_spans += 1
                 self._spans.append(span)
-            else:
-                self._dropped_spans += 1
         stack = getattr(self._tls, "stack", None)
         if stack and span in stack:
             stack.remove(span)
@@ -241,6 +325,18 @@ class Tracer:
         stack = getattr(self._tls, "stack", None)
         return stack[-1] if stack else None
 
+    def root_span(self) -> Optional[Span]:
+        """The nearest parentless span under the current one on this
+        thread (the group's ``pipeline.process`` while a stage runs): the
+        parent for work that outlives the stage that starts it.  None when
+        the current span is itself the root, or nothing is current."""
+        stack = getattr(self._tls, "stack", None)
+        if stack:
+            for sp in reversed(stack):
+                if sp.parent_id is None:
+                    return sp if sp is not stack[-1] else None
+        return None
+
     # -- timeline -----------------------------------------------------------
 
     def event(self, name: str, **attrs) -> None:
@@ -256,6 +352,8 @@ class Tracer:
     # -- retrieval ----------------------------------------------------------
 
     def finished_spans(self) -> List[Span]:
+        if self._gc_pending:
+            self._fold_gc()
         with self._lock:
             return list(self._spans)
 
@@ -272,8 +370,11 @@ class Tracer:
     def drain(self) -> Tuple[List[Span], List[TraceEvent]]:
         """Remove-and-return everything recorded so far (self-monitor
         export cadence): each span/event ships exactly once."""
+        if self._gc_pending:
+            self._fold_gc()
         with self._lock:
-            spans, self._spans = self._spans, []
+            spans = list(self._spans)
+            self._spans.clear()
             events, self._timeline = self._timeline, []
         return spans, events
 
@@ -294,7 +395,8 @@ class Tracer:
               tuple(sorted((k, _stable(v)) for k, v in s.attrs.items()
                            if k not in _VOLATILE_ATTRS)),
               tuple(e[0] for e in s.events))
-             for s in self.finished_spans()))
+             for s in self.finished_spans()
+             if s.name not in VOLATILE_SPANS))
         out.extend(("span",) + s for s in spans)
         return out
 
@@ -306,6 +408,8 @@ class Tracer:
                           default=str).encode("utf-8")
 
     def stats(self) -> dict:
+        if self._gc_pending:
+            self._fold_gc()
         with self._lock:
             return {"spans": len(self._spans),
                     "events": len(self._timeline),
@@ -318,6 +422,13 @@ class Tracer:
 #: under concurrency may renumber dispatches between identical runs
 _VOLATILE_ATTRS = frozenset({"duration_ms", "wall", "thread",
                              "dispatch_id"})
+
+
+#: spans whose very presence is run-dependent (a collection, a wait on
+#: the budget, work on a wall-clock cadence) — excluded the same way
+VOLATILE_SPANS = frozenset({"runtime.gc", "device.acquire",
+                             "checkpoint.dump", "ledger.audit",
+                             "self_monitor.tick"})
 
 
 # ---------------------------------------------------------------------------
@@ -341,12 +452,33 @@ def enable(config: Optional[TraceConfig] = None) -> Tracer:
     global _tracer
     t = Tracer(config)
     _tracer = t
+    if _gc_hook not in gc.callbacks:
+        gc.callbacks.append(_gc_hook)
     return t
 
 
 def disable() -> None:
     global _tracer
     _tracer = None
+    if _gc_hook in gc.callbacks:
+        gc.callbacks.remove(_gc_hook)
+
+
+_gc_t0 = 0.0
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    """``gc.callbacks`` entry, installed only while a tracer is active:
+    times every collection (they never nest) for `runtime.gc`."""
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+    elif _gc_t0:
+        t = _tracer
+        if t is not None:
+            t.note_gc(_gc_t0, time.perf_counter() - _gc_t0,
+                      info.get("generation", -1))
+        _gc_t0 = 0.0
 
 
 @contextlib.contextmanager
@@ -415,3 +547,11 @@ def current_span() -> Optional[Span]:
     if t is None:
         return None
     return t.current_span()
+
+
+def status() -> Optional[dict]:
+    """The /debug/status ``trace`` section; None while tracing is off."""
+    t = _tracer
+    if t is None:
+        return None
+    return t.stats()

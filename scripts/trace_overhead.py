@@ -22,6 +22,13 @@ ways and exits non-zero when the contract regresses:
    Gate: disabled must be within 5% of baseline.  The tracer-enabled
    time is also measured and reported (informational — enabling tracing
    MAY cost; disabling it MUST NOT).
+
+3. **The per-group sites** — the same paired gate over the sites that fire
+   once per group, dispatch, flush or file-server round rather than per
+   stage: `LogFileReader.read`, `FlusherFile` send → flush,
+   `DevicePlane.submit` → `DeviceFuture.result()` with the pack stopwatch
+   handed to `xprof.note_dispatch`, and `FileServer._round` with its
+   always-on counters.
 """
 
 import sys
@@ -82,18 +89,81 @@ def make_runner():
     return inst, run_timed
 
 
-def main() -> int:
+def make_sites(tmp_dir: str):
+    """One pass over the per-group sites, 64 of each: a 64 KiB chunk read,
+    a group sent through flusher_file (every send flushes), a dispatch
+    materialised, a file-server round that finds nothing new."""
+    import os
+    import numpy as np
     from loongcollector_tpu import trace
-    hooks = bench_hooks()
-    print("disabled hook cost (ns/call): "
-          + ", ".join(f"{k}={v:.0f}" for k, v in hooks.items()))
-    bad = {k: v for k, v in hooks.items() if v > MAX_HOOK_NS}
-    if bad:
-        print(f"FAIL: disabled hooks over {MAX_HOOK_NS} ns: {bad}")
-        return 1
+    from loongcollector_tpu.flusher.file import FlusherFile
+    from loongcollector_tpu.input.file.file_server import (FileServer,
+                                                           _ConfigState)
+    from loongcollector_tpu.input.file.polling import FileDiscoveryConfig
+    from loongcollector_tpu.input.file.reader import LogFileReader
+    from loongcollector_tpu.models import PipelineEventGroup
+    from loongcollector_tpu.ops import xprof
+    from loongcollector_tpu.ops.device_plane import DevicePlane
+    from loongcollector_tpu.pipeline.plugin.instance import FlusherInstance
+    from loongcollector_tpu.pipeline.plugin.interface import PluginContext
+    n = 64
+    log_path = os.path.join(tmp_dir, "in.log")
+    line = b"2024-01-02 03:04:05 INFO request handled ok\n"
+    with open(log_path, "wb") as f:
+        f.write(line * (n * 65536 // len(line) + 1))
+    flusher = FlusherFile()
+    assert flusher.init({"FilePath": os.path.join(tmp_dir, "sink.jsonl"),
+                         "MinSizeBytes": 1}, PluginContext("overhead"))
+    sink = FlusherInstance(flusher, "flusher_file/overhead")
+    plane = DevicePlane(budget_bytes=1 << 20)
+    rows = np.zeros((256, 128), np.uint8)
+    server = FileServer()
+    server._configs["overhead"] = _ConfigState(
+        "overhead", FileDiscoveryConfig([log_path]), queue_key=1,
+        tail_existing=False)
+    server._round()                    # opens the reader at the file's end
 
+    def run_timed():
+        reader = LogFileReader(log_path, chunk_size=65536,
+                               presplit_lines=True)
+        groups = []
+        t0 = time.perf_counter()
+        for _ in range(n):
+            groups.append(reader.read())
+        for g in groups:
+            sink.send(g)
+        for _ in range(n):
+            t_pack = (time.perf_counter()
+                      if xprof.is_active() or trace.is_active() else None)
+            fut = plane.submit(lambda r: r.sum(axis=1), (rows,), rows.nbytes)
+            xprof.note_dispatch(fut, "overhead", "256x128", t_pack,
+                                None if t_pack is None else 0.0)
+            fut.result()
+        for _ in range(n):
+            server._round()
+        dt = time.perf_counter() - t0
+        reader.close()
+        assert all(g is not None for g in groups)
+        return dt
+
+    def close():
+        flusher.stop()
+        sink.metrics.mark_deleted()
+
+    return run_timed, close
+
+
+def paired_rounds(run_timed):
+    """Paired rounds: on a shared single core, absolute ms-scale timings
+    drift more than the 5% budget (co-tenant steal), but a REAL
+    disabled-path regression is systematic — it shows up in EVERY
+    baseline/disabled pair measured back-to-back.  So the gate is the
+    MINIMUM paired ratio across rounds: if even one round ran the
+    shipped hooks within 5% of the no-op baseline, the hooks are one
+    branch; sustained overhead fails all rounds and trips the gate.
+    Returns the disabled/baseline and enabled/baseline ratios."""
     import gc
-    inst, run_timed = make_runner()
+    from loongcollector_tpu import trace
     noop_active = lambda: False                       # noqa: E731
     noop_none = lambda *a, **k: None                  # noqa: E731
     real = (trace.is_active, trace.start_span, trace.active_tracer)
@@ -112,13 +182,6 @@ def main() -> int:
         trace.is_active, trace.start_span, trace.active_tracer = real
         trace.enable()
 
-    # Paired rounds: on a shared single core, absolute ms-scale timings
-    # drift more than the 5% budget (co-tenant steal), but a REAL
-    # disabled-path regression is systematic — it shows up in EVERY
-    # baseline/disabled pair measured back-to-back.  So the gate is the
-    # MINIMUM paired ratio across rounds: if even one round ran the
-    # shipped hooks within 5% of the no-op baseline, the hooks are one
-    # branch; sustained overhead fails all rounds and trips the gate.
     dis_ratios, en_ratios = [], []
     try:
         run_timed()                                   # warm the path
@@ -137,10 +200,12 @@ def main() -> int:
     finally:
         trace.is_active, trace.start_span, trace.active_tracer = real
         trace.disable()
-        inst.metrics.mark_deleted()
+    return dis_ratios, en_ratios
 
+
+def gate(label: str, dis_ratios, en_ratios) -> int:
     ratio = min(dis_ratios)
-    print(f"{N_EVENTS}-event synthetic pipeline, {REPEATS} paired rounds: "
+    print(f"{label}, {REPEATS} paired rounds: "
           f"disabled/baseline min={ratio:.3f} "
           f"median={sorted(dis_ratios)[len(dis_ratios) // 2]:.3f}  "
           f"enabled/baseline min={min(en_ratios):.3f}")
@@ -149,6 +214,36 @@ def main() -> int:
               f"> {(MAX_DISABLED_OVER_BASELINE - 1) * 100:.0f}% in every "
               "round — the disabled tracer must stay one branch per hook")
         return 1
+    return 0
+
+
+def main() -> int:
+    hooks = bench_hooks()
+    print("disabled hook cost (ns/call): "
+          + ", ".join(f"{k}={v:.0f}" for k, v in hooks.items()))
+    bad = {k: v for k, v in hooks.items() if v > MAX_HOOK_NS}
+    if bad:
+        print(f"FAIL: disabled hooks over {MAX_HOOK_NS} ns: {bad}")
+        return 1
+
+    inst, run_timed = make_runner()
+    try:
+        rc = gate(f"{N_EVENTS}-event synthetic pipeline",
+                  *paired_rounds(run_timed))
+    finally:
+        inst.metrics.mark_deleted()
+    if rc:
+        return rc
+    import tempfile
+    with tempfile.TemporaryDirectory(prefix="trace_overhead_") as tmp:
+        run_sites, close = make_sites(tmp)
+        try:
+            rc = gate("per-group sites (read, flush, result, round)",
+                      *paired_rounds(run_sites))
+        finally:
+            close()
+    if rc:
+        return rc
     rc = smoke_multiworker()
     if rc:
         return rc

@@ -434,7 +434,7 @@ class TestWatchdogBreach:
 
 
 class TestDeviceUtilization:
-    def test_occupancy_and_busy_fraction(self):
+    def test_occupancy_and_inflight_fraction(self):
         plane = DevicePlane(budget_bytes=4096)
         kernel = LatencyInjectedKernel(lambda x: x, rtt_s=0.02)
         fut = plane.submit(kernel, (np.arange(4),), nbytes=2048)
@@ -445,7 +445,7 @@ class TestDeviceUtilization:
         u = plane.utilization()
         assert u["inflight_bytes"] == 0
         assert u["held_fraction"] == 0.0
-        assert u["busy_fraction"] > 0.0
+        assert u["inflight_fraction"] > 0.0
         assert 0.0 < u["occupancy_avg"] <= 0.5 + 1e-6
         assert u["dispatched_total"] == 1
 

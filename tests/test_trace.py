@@ -275,8 +275,8 @@ class TestDeviceRoundtrip:
         assert fut.result()[0][1] == 2
         assert roundtrip_histogram().count == base + 1
         assert roundtrip_histogram().snapshot()["max"] >= 0.002
-        (sp,) = t.finished_spans()
-        assert sp.name == "device.roundtrip"
+        (sp,) = [s for s in t.finished_spans()
+                 if s.name == "device.roundtrip"]
         assert sp.status == "ok"
         assert sp.attrs["nbytes"] == 64
         assert sp.duration_s >= 0.002
@@ -291,7 +291,8 @@ class TestDeviceRoundtrip:
         fut = plane.submit(boom, (np.arange(2),), nbytes=8)
         with pytest.raises(RuntimeError):
             fut.result()
-        (sp,) = t.finished_spans()
+        (sp,) = [s for s in t.finished_spans()
+                 if s.name == "device.roundtrip"]
         assert sp.status == "error"
         assert plane.inflight_bytes() == 0
 
@@ -592,3 +593,575 @@ class TestTimelineBounds:
         assert len(spans) == 1 and len(events) == 1
         spans, events = t.drain()
         assert spans == [] and events == []
+
+
+# ---------------------------------------------------------------------------
+# PR 25: host spans that tell work from waiting — nested stages, the device
+# legs, the reader, the sink and the pauses in the one tracer; always-on
+# file-input counters and start-up phases
+
+
+import gc
+import os
+import json
+
+from loongcollector_tpu.flusher.file import FlusherFile
+from loongcollector_tpu.input.file.file_server import (FileInputStats,
+                                                       FileServer,
+                                                       _ConfigState)
+from loongcollector_tpu.input.file.polling import FileDiscoveryConfig
+from loongcollector_tpu.input.file.reader import LogFileReader
+from loongcollector_tpu.models import PipelineEventGroup
+from loongcollector_tpu.monitor import startup
+from loongcollector_tpu.ops import xprof
+from loongcollector_tpu.pipeline.plugin.instance import (FlusherInstance,
+                                                         ProcessorInstance)
+from loongcollector_tpu.pipeline.plugin.interface import (PluginContext,
+                                                          Processor)
+
+LEGS = ("device.submit", "device.wait", "device.d2h")
+
+
+class _DeviceStage(Processor):
+    """A processor with the split dispatch/complete protocol whose device
+    work is one dispatch through the plane (slow enough to wait on)."""
+
+    name = "processor_stub_device"
+    supports_async_dispatch = True
+    supports_columnar = True
+
+    def __init__(self, plane):
+        super().__init__()
+        self.plane = plane
+        self.kernel = LatencyInjectedKernel(lambda x: x + 1, rtt_s=0.004)
+
+    def process_dispatch(self, group):
+        return self.plane.submit(self.kernel,
+                                 (np.arange(8, dtype=np.int64),), nbytes=64)
+
+    def process_complete(self, group, token):
+        token.result()
+
+
+def _one_group() -> PipelineEventGroup:
+    g = PipelineEventGroup()
+    ev = g.add_log_event(1)
+    ev.set_content(g.source_buffer.copy_string(b"k"),
+                   g.source_buffer.copy_string(b"v"))
+    return g
+
+
+def _staged_roundtrip(with_xprof: bool):
+    """One group through dispatch → complete under a `pipeline.process`
+    root, as the runner's overlapped loop drives it.  Returns the spans by
+    name and the root."""
+    plane = DevicePlane(budget_bytes=1 << 20)
+    inst = ProcessorInstance(_DeviceStage(plane), "stub/0")
+    t = trace.enable()
+    timeline = xprof.enable() if with_xprof else None
+    try:
+        root = t.start_span("pipeline.process", trace_id="p:0")
+        t.push_current(root)
+        groups = [_one_group()]
+        tokens = inst.process_dispatch(groups)
+        assert t.current_span() is root      # the stage popped itself
+        t.pop_current(root)                  # detach, as the runner does
+        t.push_current(root)                 # re-attach at completion
+        inst.process_complete(groups, tokens)
+        assert t.current_span() is root
+        t.pop_current(root)
+        root.end("ok")
+        by_name = {}
+        for s in t.finished_spans():
+            by_name.setdefault(s.name, []).append(s)
+        return by_name, root, timeline
+    finally:
+        trace.disable()
+        xprof.disable()
+
+
+class TestNestedStages:
+    def test_complete_children_nest_and_self_time_excludes_them(self):
+        by, _root, _tl = _staged_roundtrip(False)
+        (complete,) = by["processor.processor_stub_device.complete"]
+        kids = [s for n in ("device.wait", "device.d2h") for s in by[n]]
+        assert kids and all(s.parent_id == complete.span_id for s in kids)
+        waited = sum(s.duration_s for s in kids)
+        assert waited >= 0.003               # the stage really waited
+        self_time = complete.duration_s - waited
+        assert 0.0 <= self_time < complete.duration_s - 0.003
+
+    def test_dispatch_holds_the_submit_leg(self):
+        by, _root, _tl = _staged_roundtrip(False)
+        (dispatch,) = by["processor.processor_stub_device.dispatch"]
+        (submit,) = by["device.submit"]
+        assert submit.parent_id == dispatch.span_id
+        assert submit.attrs == {"nbytes": 64}
+
+    def test_roundtrip_keeps_the_root_as_parent(self):
+        by, root, _tl = _staged_roundtrip(False)
+        (rt,) = by["device.roundtrip"]
+        (dispatch,) = by["processor.processor_stub_device.dispatch"]
+        assert rt.parent_id == root.span_id != dispatch.span_id
+        assert rt.trace_id == "p:0"
+        # it outlives the stage that submitted it
+        assert rt.duration_s > dispatch.duration_s
+
+    @pytest.mark.parametrize("name", [
+        "processor.processor_stub_device.dispatch",
+        "processor.processor_stub_device.complete"])
+    def test_stage_spans_hang_from_the_root(self, name):
+        by, root, _tl = _staged_roundtrip(False)
+        (stage,) = by[name]
+        assert stage.parent_id == root.span_id and stage.status == "ok"
+
+    def test_rootless_stage_is_current_for_its_body(self):
+        seen = []
+
+        class _Peek(Processor):
+            name = "processor_stub_peek"
+            supports_columnar = True
+
+            def process(self, group):
+                seen.append(trace.current_span().name)
+
+        t = trace.enable()
+        ProcessorInstance(_Peek(), "peek/0").process([_one_group()])
+        assert seen == ["processor.processor_stub_peek"]
+        assert t.current_span() is None
+
+    def test_a_raising_stage_pops_itself(self):
+        class _Boom(Processor):
+            name = "processor_stub_boom"
+            supports_columnar = True
+
+            def process(self, group):
+                raise RuntimeError("boom")
+
+        t = trace.enable()
+        with pytest.raises(RuntimeError):
+            ProcessorInstance(_Boom(), "boom/0").process([_one_group()])
+        assert t.current_span() is None
+        (sp,) = t.finished_spans()
+        assert sp.status == "error"
+
+
+class TestDeviceLegs:
+    @pytest.mark.parametrize("leg", LEGS)
+    def test_leg_with_xprof_off(self, leg):
+        by, _root, _tl = _staged_roundtrip(False)
+        (sp,) = by[leg]
+        assert sp.attrs == {"nbytes": 64}    # no dispatch id without xprof
+        assert sp.duration_s >= 0.0
+
+    @pytest.mark.parametrize("leg", LEGS)
+    def test_leg_with_xprof_on_carries_the_one_dispatch_id(self, leg):
+        by, _root, timeline = _staged_roundtrip(True)
+        (rec,) = timeline.dispatches()
+        (sp,) = by[leg]
+        assert sp.attrs == {"nbytes": 64, "dispatch_id": rec.id}
+        assert by["device.roundtrip"][0].attrs["dispatch_id"] == rec.id
+
+    def test_one_measurement_feeds_both_planes(self):
+        by, _root, timeline = _staged_roundtrip(True)
+        (rec,) = timeline.dispatches()
+        legs = {name: (t0 + timeline.epoch, dur)
+                for name, t0, dur, _a in rec.legs}
+        for span_name, leg in (("device.submit", "submit"),
+                               ("device.wait", "exec"),
+                               ("device.d2h", "d2h")):
+            (sp,) = by[span_name]
+            assert sp._start_perf == pytest.approx(legs[leg][0], abs=1e-9)
+            assert sp.duration_s == pytest.approx(legs[leg][1], abs=1e-12)
+
+    def test_wait_is_split_from_copy_without_xprof(self):
+        by, _root, _tl = _staged_roundtrip(False)
+        assert by["device.wait"][0].duration_s >= 0.003   # the rtt
+        assert by["device.d2h"][0].duration_s < 0.003
+
+    def test_pack_span_from_the_callers_stopwatch(self):
+        plane = DevicePlane(budget_bytes=1 << 20)
+        t = trace.enable()
+        with t.start_span("processor.x.dispatch") as stage:
+            t.push_current(stage)
+            t0 = time.perf_counter()
+            fut = plane.submit(lambda x: x, (np.arange(4),), nbytes=32)
+            xprof.note_dispatch(fut, "regex", "4x8", t0, 0.002)
+            fut.result()
+        (pack,) = [s for s in t.finished_spans() if s.name == "device.pack"]
+        assert pack.parent_id == stage.span_id
+        assert pack.attrs == {"nbytes": 32}
+        assert pack._start_perf == t0 and pack.duration_s == 0.002
+
+    def test_acquire_span_only_when_the_budget_blocks(self):
+        plane = DevicePlane(budget_bytes=100)
+        kernel = LatencyInjectedKernel(lambda x: x, rtt_s=0.0)
+        t = trace.enable()
+        held = [plane.submit(kernel, (np.arange(2),), nbytes=80)]
+
+        def drain_one():
+            if not held:
+                return False
+            held.pop().result()
+            return True
+
+        fut = plane.submit(kernel, (np.arange(2),), nbytes=80,
+                           on_wait=drain_one)       # must wait for 80 bytes
+        fut.result()
+        plane.submit(kernel, (np.arange(2),), nbytes=80).result()  # fits
+        acquires = [s for s in t.finished_spans()
+                    if s.name == "device.acquire"]
+        assert len(acquires) == 1
+        assert acquires[0].attrs == {"nbytes": 80, "on": "budget"}
+        # what it drained while waiting nests under it
+        waits = [s for s in t.finished_spans() if s.name == "device.wait"
+                 and s.parent_id == acquires[0].span_id]
+        assert len(waits) == 1
+
+    def test_disabled_result_takes_no_legs(self):
+        plane = DevicePlane(budget_bytes=1 << 20)
+        fut = plane.submit(lambda x: x, (np.arange(4),), nbytes=32)
+        assert fut.result()[0][3] == 3 and not trace.is_active()
+
+
+def _flusher(tmp_path, **config):
+    f = FlusherFile()
+    ctx = PluginContext(pipeline_name="p")
+    assert f.init({"FilePath": str(tmp_path / "sink.jsonl"), **config}, ctx)
+    return f, FlusherInstance(f, "flusher_file/0")
+
+
+class TestSinkSpans:
+    @pytest.mark.parametrize("name", ["flusher.serialize", "flusher.write"])
+    def test_size_triggered_flush_nests_under_send(self, tmp_path, name):
+        f, inst = _flusher(tmp_path, MinSizeBytes=1)
+        t = trace.enable()
+        try:
+            assert inst.send(_one_group())
+        finally:
+            f.stop()
+        (send,) = [s for s in t.finished_spans() if s.name == "flusher.send"]
+        (sp,) = [s for s in t.finished_spans() if s.name == name]
+        assert sp.parent_id == send.span_id
+        assert sp.attrs["groups"] == 1 and sp.attrs["events"] == 1
+        assert sp.attrs["nbytes"] == os.path.getsize(tmp_path / "sink.jsonl")
+
+    @pytest.mark.parametrize("name", ["flusher.serialize", "flusher.write"])
+    def test_timeout_thread_flush_is_rootless(self, tmp_path, name):
+        f, inst = _flusher(tmp_path, MinSizeBytes=1 << 30, TimeoutSecs=0.01)
+        t = trace.enable()
+        try:
+            assert inst.send(_one_group())       # staged, nothing flushed
+            assert not [s for s in t.finished_spans() if s.name == name]
+            time.sleep(0.02)
+            th = threading.Thread(target=f.batcher.flush_timeout)
+            th.start()
+            th.join()
+        finally:
+            f.stop()
+        (sp,) = [s for s in t.finished_spans() if s.name == name]
+        assert sp.parent_id is None and sp.attrs["flusher"] == "flusher_file"
+        assert os.path.getsize(tmp_path / "sink.jsonl") == sp.attrs["nbytes"]
+
+    def test_flush_with_tracing_off_writes_the_same_bytes(self, tmp_path):
+        f, inst = _flusher(tmp_path, MinSizeBytes=1)
+        assert inst.send(_one_group())
+        f.stop()
+        doc = json.loads((tmp_path / "sink.jsonl").read_text())
+        assert doc["k"] == "v"
+
+
+class TestReaderSpan:
+    def test_read_span_and_timeline_event(self, tmp_path):
+        p = tmp_path / "a.log"
+        p.write_bytes(b"one\ntwo\nthree\n")
+        t = trace.enable()
+        r = LogFileReader(str(p), presplit_lines=True)
+        g = r.read()
+        assert g is not None and r.read() is None     # nothing more: no span
+        (sp,) = [s for s in t.finished_spans()
+                 if s.name == "input.file.read"]
+        assert sp.attrs == {"offset": 0, "nbytes": 14, "rows": len(g)}
+        (ev,) = t.timeline_by_name()["input.read"]    # stays, as it was
+        assert ev.attrs == {"path": str(p), "offset": 0, "nbytes": 14}
+
+    def test_read_with_tracing_off(self, tmp_path):
+        p = tmp_path / "a.log"
+        p.write_bytes(b"one\n")
+        assert LogFileReader(str(p)).read() is not None
+
+
+class TestPauses:
+    def test_gc_span_after_a_collection_and_hook_gone_after_disable(self):
+        before = list(gc.callbacks)
+        t = trace.enable()
+        assert len(gc.callbacks) == len(before) + 1
+        gc.collect()
+        spans = [s for s in t.finished_spans() if s.name == "runtime.gc"]
+        assert spans and spans[-1].attrs == {"generation": 2}
+        trace.disable()
+        assert gc.callbacks == before
+        gc.collect()                               # nothing listens now
+
+    def test_gc_nests_under_the_stage_it_interrupted(self):
+        t = trace.enable()
+        stage = t.start_stage("processor", "processor.x")
+        gc.collect()
+        stage.end()
+        (g,) = [s for s in t.finished_spans() if s.name == "runtime.gc"]
+        assert g.parent_id == stage.span_id
+
+    def test_young_collections_feed_the_histogram_not_the_store(self):
+        t = trace.enable()
+        hist = t.span_histogram("runtime.gc")
+        base = hist.count
+        for _ in range(3):
+            gc.collect(0)
+        t.start_span("x").end()                    # folds what is pending
+        assert hist.count >= base + 3
+        stored = [s for s in t.finished_spans() if s.name == "runtime.gc"
+                  and s.attrs["generation"] == 0]
+        assert all(s.duration_s >= 1e-3 for s in stored)
+
+    def test_volatile_spans_stay_out_of_the_structure(self):
+        t = trace.enable()
+        t.start_span("a").end()
+        one = t.structure_bytes()
+        gc.collect()
+        with trace.span("checkpoint.dump"):
+            pass
+        assert t.structure_bytes() == one
+
+    @pytest.mark.parametrize("name", ["checkpoint.dump", "ledger.audit",
+                                      "self_monitor.tick"])
+    def test_periodic_work_gets_a_span(self, name, tmp_path):
+        t = trace.enable()
+        if name == "checkpoint.dump":
+            from loongcollector_tpu.input.file.checkpoint import \
+                CheckPointManager
+            mgr = CheckPointManager()
+            mgr.path = str(tmp_path / "cp.json")
+            mgr.last_dump = 0.0
+            mgr.dump_periodically(0.0)
+        elif name == "ledger.audit":
+            from loongcollector_tpu.monitor import ledger
+            aud = ledger.ConservationAuditor(ledger.EventLedger(),
+                                             interval_s=0.01)
+            aud.start()
+            time.sleep(0.08)
+            aud.stop()
+        else:
+            mon = SelfMonitorServer()
+            mon.interval_s = 0.0
+            mon.start()
+            time.sleep(0.7)
+            mon.stop()
+        assert [s for s in t.finished_spans() if s.name == name]
+
+    def test_nothing_installed_while_tracing_is_off(self, tmp_path):
+        before = list(gc.callbacks)
+        from loongcollector_tpu.input.file.checkpoint import \
+            CheckPointManager
+        mgr = CheckPointManager()
+        mgr.path = str(tmp_path / "cp.json")
+        mgr.dump_periodically(0.0)
+        assert gc.callbacks == before and os.path.exists(mgr.path)
+
+
+class TestSpanHistogramsAndStatus:
+    def test_every_finished_span_lands_in_loong_span_seconds(self):
+        t = trace.enable()
+        base = t.span_histogram("stage.a").count
+        for _ in range(4):
+            t.start_span("stage.a").end()
+        assert t.span_histogram("stage.a").count == base + 4
+        text = exposition.render()
+        assert 'loong_span_seconds_count{category="trace",name="stage.a"}' \
+            in text
+        assert 'loong_span_seconds_bucket{category="trace",name="stage.a"' \
+            in text
+
+    def test_the_ring_keeps_the_newest_and_counts_what_it_evicts(
+            self, monkeypatch):
+        import collections
+        from loongcollector_tpu.trace import tracer as tracer_mod
+        monkeypatch.setattr(tracer_mod, "_SPAN_CAP", 4)
+        t = trace.enable()
+        t._spans = collections.deque(maxlen=4)
+        for i in range(6):
+            t.start_span(f"s{i}").end()
+        assert [s.name for s in t.finished_spans()] == ["s2", "s3", "s4",
+                                                        "s5"]
+        assert t.stats() == {"spans": 4, "events": 0, "dropped_spans": 2}
+
+    @pytest.mark.parametrize("key", ["spans", "events", "dropped_spans"])
+    def test_status_trace_section(self, key):
+        assert "trace" not in exposition.collect_status()   # off: absent
+        t = trace.enable()
+        t.start_span("a").end()
+        doc = exposition.collect_status()["trace"]
+        assert key in doc and doc["dropped_spans"] == 0 and doc["spans"] == 1
+
+    @pytest.mark.parametrize("key", [
+        "rounds_total", "rounds_throttled_total",
+        "throttle_sleep_seconds_total", "reads_total", "read_bytes_total",
+        "reads_blocked_total", "push_rejected_total"])
+    def test_status_file_input_section(self, key, monkeypatch):
+        fs = FileServer()
+        monkeypatch.setattr(FileServer, "_instance", fs)
+        doc = exposition.collect_status()["file_input"]
+        assert key in doc
+        assert doc["rounds_throttled_total"] == {"3": 0, "8": 0}
+        assert "file_input" in exposition.STATUS_SECTIONS
+
+    @pytest.mark.parametrize("phase", startup.PHASES)
+    def test_status_startup_section(self, phase, monkeypatch):
+        monkeypatch.setattr(startup, "_phases", {})
+        assert "startup" not in exposition.collect_status()
+        for p in startup.PHASES:
+            startup.mark(p)
+        first = dict(startup._phases)
+        startup.mark(phase)                        # written once
+        doc = exposition.collect_status()["startup"]
+        assert doc == first and doc[phase] >= 0.0
+        assert list(doc) == list(startup.PHASES)
+
+    def test_first_dispatch_marks_its_phase_once(self, monkeypatch):
+        from loongcollector_tpu.ops import device_plane as dp
+        monkeypatch.setattr(startup, "_phases", {})
+        monkeypatch.setattr(dp, "_first_dispatch_marked", False)
+        plane = DevicePlane(budget_bytes=1 << 20)
+        plane.submit(lambda x: x, (np.arange(2),), nbytes=8).result()
+        t_first = startup.status()["first_dispatch"]
+        plane.submit(lambda x: x, (np.arange(2),), nbytes=8).result()
+        assert startup.status()["first_dispatch"] == t_first
+        assert dp._first_dispatch_marked
+
+    def test_file_input_counters_reach_the_metrics_tree(self, monkeypatch):
+        fs = FileServer()
+        fs.stats.reads_total = 7
+        fs.stats.rounds_throttled[8] = 2
+        monkeypatch.setattr(FileServer, "_instance", fs)
+        text = exposition.render()
+        assert ('loong_reads_total{category="file_input",'
+                'component="file_server"} 7') in text
+        assert ('loong_rounds_throttled_total{category="file_input",'
+                'component="file_server",factor="8"} 2') in text
+
+    def test_inflight_fraction_is_not_called_busy(self):
+        plane = DevicePlane(budget_bytes=1 << 20)
+        u = plane.utilization()
+        assert "inflight_fraction" in u and "inflight_s" in u
+        assert "busy_fraction" not in u and "busy_s" not in u
+
+
+class _CountingPQM:
+    def __init__(self, valid=True, accept=True):
+        self.valid, self.accept, self.pushed = valid, accept, 0
+
+    def is_valid_to_push(self, key):
+        return self.valid
+
+    def push_queue(self, key, group):
+        self.pushed += self.accept
+        return self.accept
+
+    def get_queue(self, key):
+        return None
+
+
+def _file_server(tmp_path, pqm, content=b"a\nb\n"):
+    fs = FileServer()
+    path = tmp_path / "in.log"
+    path.write_bytes(content)
+    st = _ConfigState("t", FileDiscoveryConfig([str(path)]), queue_key=1,
+                      tail_existing=True)
+    fs._configs["t"] = st
+    fs.process_queue_manager = pqm
+    return fs
+
+
+def _run_rounds(fs, seconds):
+    fs._running = True
+    th = threading.Thread(target=fs._run, daemon=True)
+    th.start()
+    time.sleep(seconds)
+    fs._running = False
+    fs._blocked_wake.set()
+    th.join(timeout=5)
+    assert not th.is_alive()
+
+
+class TestFileInputCounters:
+    @pytest.mark.parametrize("level,factor,other", [(0.8, 3, 8),
+                                                    (0.95, 8, 3)])
+    def test_governor_stretched_rounds_are_counted(self, tmp_path,
+                                                   monkeypatch, level,
+                                                   factor, other):
+        monkeypatch.setenv("LOONG_DISABLE_INOTIFY", "1")
+        fs = _file_server(tmp_path, _CountingPQM())
+        fs.cpu_level_provider = lambda: level
+        _run_rounds(fs, 0.25)
+        s = fs.stats
+        assert s.rounds_total >= 1
+        assert s.rounds_throttled[factor] >= 1
+        assert s.rounds_throttled[other] == 0
+        assert s.throttle_sleep_s > 0.0
+        assert s.rounds_throttled[factor] <= s.rounds_total
+
+    def test_an_unthrottled_server_counts_rounds_only(self, tmp_path,
+                                                     monkeypatch):
+        monkeypatch.setenv("LOONG_DISABLE_INOTIFY", "1")
+        fs = _file_server(tmp_path, _CountingPQM())
+        fs.cpu_level_provider = lambda: 0.1
+        _run_rounds(fs, 0.15)
+        assert fs.stats.rounds_total >= 2
+        assert fs.stats.rounds_throttled == {3: 0, 8: 0}
+        assert fs.stats.throttle_sleep_s == 0.0
+
+    @pytest.mark.parametrize("counter", ["reads_total", "read_bytes_total"])
+    def test_reads_are_counted(self, tmp_path, counter):
+        pqm = _CountingPQM()
+        fs = _file_server(tmp_path, pqm)
+        fs._round()
+        assert pqm.pushed == 1
+        assert getattr(fs.stats, counter) == {"reads_total": 1,
+                                              "read_bytes_total": 4}[counter]
+        assert fs.stats.reads_blocked_total == 0
+        assert fs.stats.push_rejected_total == 0
+
+    def test_full_queue_before_the_read(self, tmp_path):
+        fs = _file_server(tmp_path, _CountingPQM(valid=False))
+        fs._round()
+        fs._round()
+        assert fs.stats.reads_blocked_total == 2
+        assert fs.stats.reads_total == 0
+
+    def test_push_rejected_after_the_read(self, tmp_path):
+        fs = _file_server(tmp_path, _CountingPQM(accept=False))
+        fs._round()
+        s = fs.stats
+        assert s.push_rejected_total == 1 and s.reads_total == 1
+        reader = fs._configs["t"].readers[str(tmp_path / "in.log")]
+        assert reader.offset == 0               # rolled back
+
+    def test_snapshot_has_the_documented_keys(self):
+        assert list(FileInputStats().snapshot()) == [
+            "rounds_total", "rounds_throttled_total",
+            "throttle_sleep_seconds_total", "reads_total",
+            "read_bytes_total", "reads_blocked_total",
+            "push_rejected_total"]
+
+
+class TestProgramNames:
+    def test_watched_jit_names_the_module_after_the_family(self):
+        import jax.numpy as jnp
+        from loongcollector_tpu.ops.compile_watch import watched_jit
+
+        def anything(x):
+            return x + 1
+
+        w = watched_jit(anything, "unit_family")
+        lowered = w._fn.lower(jnp.zeros((4,), jnp.int32))
+        assert "jit_loong_unit_family" in lowered.as_text()[:200]
+        assert int(w(jnp.zeros((4,), jnp.int32))[0]) == 1
