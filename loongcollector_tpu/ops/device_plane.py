@@ -7,7 +7,9 @@ its BoundedProcessQueue watermarks gate the producers
 analogue (SURVEY.md §7 step 4, §5.8) is this plane: device kernel dispatches
 are ASYNC (jax returns device buffers immediately; computation proceeds in the
 background), so the host packs and dispatches chunk N+1 while the device
-executes chunk N, and materialises results strictly as needed.
+executes chunk N, and materialises results strictly as needed.  The copy
+back of a dispatch's outputs is started by `submit` itself, behind the program
+on the runtime's own threads, so materialisation finds them on the host.
 
 Back-pressure contract: every dispatch acquires from a process-wide in-flight
 byte budget and releases it on materialisation.  When the device stalls, the
@@ -325,11 +327,33 @@ def _budget_from_env() -> int:
         return _DEFAULT_BUDGET
 
 
+def _start_copy_back(outputs: Sequence) -> bool:
+    """Start the device→host copy of every output that can start one (a
+    `jax.Array`, sharded or not): the call only enqueues the transfer
+    behind the program and returns, so it lands while the worker packs
+    and dispatches the next groups.  Outputs without the method (numpy
+    from a host kernel, the latency-injection fakes) are skipped.  True
+    when every output started its copy.  A start that raises is dropped
+    here: `result()` surfaces the buffer's error at the consume point."""
+    started = 0
+    try:
+        for o in outputs:
+            start = getattr(o, "copy_to_host_async", None)
+            if start is not None:
+                start()
+                started += 1
+    except Exception:  # noqa: BLE001 — fail at consume, not at submit
+        return False
+    return bool(outputs) and started == len(outputs)
+
+
 class DeviceFuture:
     """A dispatched kernel call whose results are not yet materialised.
 
-    `result()` converts the device buffers to numpy (blocking until the
-    device finishes) and releases the plane budget exactly once.  If the
+    The copy back of the outputs started when the dispatch was issued
+    (`DevicePlane.submit`).  `result()` waits until the device has finished,
+    converts the outputs to numpy — picking up the host copies that landed
+    meanwhile — and releases the plane budget exactly once.  If the
     kernel raised at dispatch or materialisation, the error is surfaced from
     `result()` so callers keep the reference's fail-at-consume semantics
     (engine.py routes Mosaic failures to the XLA path there).
@@ -394,9 +418,11 @@ class DeviceFuture:
                     # exec leg / device.wait: dispatch return → first
                     # output ready (the device-execution window the host
                     # can observe); d2h leg / device.d2h: the numpy
-                    # materialisation itself.  Without a
-                    # block_until_ready the split collapses into d2h.
-                    # One pair of readings feeds both planes.
+                    # materialisation itself — the copy was started at
+                    # submit, so this is what of it the host still has
+                    # to wait for.  Without a block_until_ready the
+                    # split collapses into d2h.  One pair of readings
+                    # feeds both planes.
                     t_exec = time.perf_counter()
                     first = self._outputs[0] if self._outputs else None
                     if hasattr(first, "block_until_ready"):
@@ -484,6 +510,7 @@ class DevicePlane:
         self.budget_bytes = budget_bytes or _budget_from_env()
         self._inflight = 0
         self._dispatched = 0
+        self._prefetched = 0   # dispatches whose copy back began at submit
         self._lock = threading.Lock()
         self._freed = threading.Condition(self._lock)
         self._closed = False
@@ -595,6 +622,7 @@ class DevicePlane:
                 "idle_while_backlogged_ms": self._idle_backlogged_ms,
                 "submit_queue_depth": self._waiters,
                 "dispatched_total": self._dispatched,
+                "d2h_prefetched_total": self._prefetched,
                 "elapsed_s": elapsed,
             }
 
@@ -754,10 +782,16 @@ class DevicePlane:
                 t_submit = time.perf_counter()
             try:
                 outputs = kernel(*args)
+                if not isinstance(outputs, (tuple, list)):
+                    outputs = (outputs,)
+                if _start_copy_back(outputs):
+                    with self._lock:
+                        self._prefetched += 1
             finally:
                 if timed:
-                    # submit leg / device.submit: the dispatch call — one
-                    # pair of readings for both planes
+                    # submit leg / device.submit: the dispatch call and
+                    # the start of the copy back — one pair of readings
+                    # for both planes
                     dt_submit = time.perf_counter() - t_submit
                     if xid:
                         xprof.leg(xid, "submit", t_submit, dt_submit)
@@ -767,8 +801,6 @@ class DevicePlane:
                                             t_submit, dt_submit,
                                             xprof.leg_attrs(nbytes, xid))
                 prof.pop_marker()
-            if not isinstance(outputs, (tuple, list)):
-                outputs = (outputs,)
             return DeviceFuture(self, nbytes, outputs=outputs, span=span,
                                 tenant=tenant, xid=xid)
         except DispatchAborted:

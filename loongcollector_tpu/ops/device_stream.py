@@ -559,7 +559,9 @@ class DeviceStream:
     ``submit`` never lets more than ``depth`` batches stay in flight: a
     full window first advances the ring (materialises the OLDEST batch),
     so with depth 3 the host is packing batch N+1 while the device
-    computes N and N-1's spans return.  ``drain()`` materialises the rest.
+    computes N and N-1's spans return — each batch's copy back starts at
+    its dispatch (``DevicePlane.submit``), so the advance finds the outputs
+    on the host.  ``drain()`` materialises the rest.
     Results arrive strictly in submit order as ``(tag, outputs)`` — an
     errored batch (kernel failure or injected ``device_plane.h2d`` /
     ``device_plane.ring_advance`` fault) delivers ``(tag, exception)`` in

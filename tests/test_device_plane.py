@@ -347,3 +347,172 @@ class TestBudgetLeakRegression:
         assert plane.inflight_bytes() == 0
         with pytest.raises(RuntimeError):
             fut.result()  # released futures surface an error, not data
+
+
+# ---------------------------------------------------------------------------
+# the copy back starts when the dispatch is issued
+
+
+class _Plain:
+    """An output that records, on one shared list, when it was converted
+    (what, which output, when).  It cannot start a copy: numpy from a host
+    kernel."""
+
+    def __init__(self, value, log, name):
+        self._value, self._log, self._name = value, log, name
+
+    def _note(self, what):
+        self._log.append((what, self._name, time.perf_counter()))
+
+    def __array__(self, dtype=None, copy=None):
+        self._note("asarray")
+        return self._value
+
+
+class _Recording(_Plain):
+    """The same with what a `jax.Array` lets the plane see: a copy back
+    that can be started ahead.  `fail` makes the start raise."""
+
+    fail = False
+
+    def copy_to_host_async(self):
+        self._note("start")
+        if self.fail:
+            raise RuntimeError("transfer refused")
+
+    def block_until_ready(self):
+        return self
+
+
+class _Refusing(_Recording):
+    fail = True
+
+
+_OUTPUTS = {"plain": _Plain, "dev": _Recording, "bad": _Refusing}
+
+
+def _recording_kernel(log, kinds):
+    """A kernel returning one output per entry of `kinds`: "dev" (starts
+    its copy), "plain" (cannot), "bad" (its start raises)."""
+    def kernel(x):
+        log.append(("kernel", None, time.perf_counter()))
+        return tuple(_OUTPUTS[kind](x + i, log, i)
+                     for i, kind in enumerate(kinds))
+    return kernel
+
+
+def _events(log):
+    return [e[:2] for e in log]
+
+
+# kinds of the outputs, copies started at submit, counted as prefetched
+PREFETCH_CASES = [
+    pytest.param(("dev",), [0], 1, id="one_output"),
+    pytest.param(("dev", "dev", "dev"), [0, 1, 2], 1, id="three_outputs"),
+    pytest.param(("plain", "plain"), [], 0, id="plain_outputs"),
+    pytest.param(("dev", "plain", "dev"), [0, 2], 0, id="mixed_outputs"),
+    pytest.param(("dev", "bad", "dev"), [0, 1], 0, id="start_raises"),
+]
+
+
+class TestCopyBackStartsAtSubmit:
+    @pytest.mark.parametrize("kinds,started,counted", PREFETCH_CASES)
+    def test_copies_start_in_submit_once_each_before_result(
+            self, kinds, started, counted):
+        plane = DevicePlane.reset_for_testing(budget_bytes=1000)
+        log = []
+        fut = plane.submit(_recording_kernel(log, kinds),
+                           (np.arange(4),), 100)      # never raises
+        assert _events(log) == [("kernel", None)] + [("start", i)
+                                                    for i in started]
+        assert plane.utilization()["d2h_prefetched_total"] == counted
+        assert plane.utilization()["dispatched_total"] == 1
+        assert plane.inflight_bytes() == 100
+        out = fut.result()
+        # the one result() path: every output converted once, in order,
+        # whether its copy was started or not
+        assert _events(log)[1 + len(started):] == [
+            ("asarray", i) for i in range(len(kinds))]
+        for i, o in enumerate(out):
+            np.testing.assert_array_equal(o, np.arange(4) + i)
+        assert plane.inflight_bytes() == 0
+        fut.release()                                  # no second release
+        assert plane.inflight_bytes() == 0
+
+    @pytest.mark.parametrize("kinds,started,counted", PREFETCH_CASES)
+    def test_release_without_result_returns_the_budget(
+            self, kinds, started, counted):
+        plane = DevicePlane.reset_for_testing(budget_bytes=1000)
+        log = []
+        held = plane.submit(LatencyInjectedKernel(lambda x: x, 0.0),
+                            (np.arange(2),), 300)
+        fut = plane.submit(_recording_kernel(log, kinds),
+                           (np.arange(4),), 100)
+        assert plane.inflight_bytes() == 400
+        fut.release()
+        assert plane.inflight_bytes() == 300           # exactly once
+        fut.release()
+        assert plane.inflight_bytes() == 300
+        assert not [e for e in log if e[0] == "asarray"]
+        with pytest.raises(RuntimeError):
+            fut.result()
+        held.result()
+        assert plane.inflight_bytes() == 0
+
+    def test_kernel_error_starts_nothing_and_surfaces_at_result(self):
+        plane = DevicePlane.reset_for_testing(budget_bytes=1000)
+
+        def bad(x):
+            raise ValueError("boom")
+
+        fut = plane.submit(bad, (np.arange(3),), 100)
+        assert plane.utilization()["d2h_prefetched_total"] == 0
+        with pytest.raises(ValueError):
+            fut.result()
+        assert plane.inflight_bytes() == 0
+
+    @pytest.mark.parametrize("kinds,started,counted", PREFETCH_CASES)
+    def test_legs_recorded_once_and_submit_holds_the_starts(
+            self, kinds, started, counted):
+        from loongcollector_tpu import trace
+        plane = DevicePlane.reset_for_testing(budget_bytes=1000)
+        log = []
+        t = trace.enable()
+        try:
+            plane.submit(_recording_kernel(log, kinds),
+                         (np.arange(4),), 100).result()
+            by = {}
+            for s in t.finished_spans():
+                by.setdefault(s.name, []).append(s)
+        finally:
+            trace.disable()
+        for leg in ("device.submit", "device.wait", "device.d2h"):
+            assert len(by[leg]) == 1, leg
+        (submit,) = by["device.submit"]
+        stamps = [at for what, _i, at in log if what in ("kernel", "start")]
+        assert len(stamps) == 1 + len(started)
+        for at in stamps:        # the call and the starts are inside the leg
+            assert submit._start_perf <= at \
+                <= submit._start_perf + submit.duration_s
+
+    def test_jax_outputs_are_prefetched_and_values_unchanged(self):
+        import jax.numpy as jnp
+        plane = DevicePlane.reset_for_testing(budget_bytes=1 << 20)
+        x = np.arange(12, dtype=np.int32).reshape(3, 4)
+        fut = plane.submit(lambda a: (jnp.asarray(a) * 2, jnp.asarray(a).sum(1)),
+                           (x,), x.nbytes)
+        assert plane.utilization()["d2h_prefetched_total"] == 1
+        doubled, sums = fut.result()
+        assert isinstance(doubled, np.ndarray)
+        np.testing.assert_array_equal(doubled, x * 2)
+        np.testing.assert_array_equal(sums, x.sum(1))
+
+    def test_engine_dispatches_all_count_as_prefetched(self):
+        plane = DevicePlane.reset_for_testing()
+        eng = RegexEngine(r"(\w+) (\d+)")
+        arena, offsets, lengths = _arena(b"abc 123", 1024)   # 4 chunks @256
+        res = eng.parse_batch(arena, offsets, lengths)
+        assert res.ok.all()
+        u = plane.utilization()
+        assert u["dispatched_total"] >= 4
+        assert u["d2h_prefetched_total"] == u["dispatched_total"]

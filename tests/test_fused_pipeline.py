@@ -164,6 +164,35 @@ class TestSingleDispatch:
         assert plane.dispatched_total() == 2
         assert program.dispatch_count == 2
 
+    @pytest.mark.parametrize("prefetching", [True, False],
+                             ids=["prefetching_outputs", "plain_outputs"])
+    def test_order_and_values_whether_or_not_outputs_prefetch(
+            self, prefetching):
+        """The program's own outputs start their copy back at submit; the
+        same outputs as numpy (nothing to start) settle through the same
+        `result()` to the same rows in the same order."""
+        p = build_pipeline(name="fused-pf")
+        program = p._fused_runs[0].program()
+        plane = DevicePlane.reset_for_testing()
+        words = [bytes(97 + i // d % 26 for d in (676, 26, 1))
+                 for i in range(600)]
+        # every third line falls to the second filter (num ~ 1\d*)
+        lines = [b"%s %d%d" % (w, 1 if i % 3 else 2, i)
+                 for i, w in enumerate(words)]
+        if not prefetching:
+            program.set_kernel_override(
+                lambda r, l: tuple(np.asarray(o) for o in program._fn(r, l)))
+        try:
+            groups = [process_one(p, lines[i:i + 200]) for i in (0, 200, 400)]
+        finally:
+            program.set_kernel_override(None)
+        got = [w for g in groups for w in snapshot(g)["fields"]["word"]]
+        assert got == [w for i, w in enumerate(words) if i % 3]
+        u = plane.utilization()
+        assert u["dispatched_total"] == program.dispatch_count >= 3
+        assert u["d2h_prefetched_total"] \
+            == (u["dispatched_total"] if prefetching else 0)
+
     def test_byte_identical_to_per_stage_path(self, monkeypatch):
         p_fused = build_pipeline(name="fused-a")
         g1 = process_one(p_fused, LINES)

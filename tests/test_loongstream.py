@@ -36,6 +36,7 @@ from loongcollector_tpu.monitor import ledger
 from loongcollector_tpu.monitor.alarms import AlarmManager, AlarmType
 from loongcollector_tpu.ops import device_stream as ds
 from loongcollector_tpu.ops.device_plane import (DevicePlane,
+                                                 LatencyInjectedArray,
                                                  LatencyInjectedKernel)
 from loongcollector_tpu.ops.regex import engine as engine_mod
 from loongcollector_tpu.ops.regex.engine import RegexEngine, get_engine
@@ -273,11 +274,31 @@ class TestWidthAutoTuner:
 # DeviceStream: ordered window + fault isolation
 
 
+class _StartsItsCopy(LatencyInjectedArray):
+    """The latency fake with what a `jax.Array` adds: a copy back that can
+    be started ahead of the conversion."""
+
+    __slots__ = ()
+    started = 0
+
+    def copy_to_host_async(self):
+        type(self).started += 1
+
+
 class TestDeviceStream:
-    def test_results_in_submit_order_with_overlap(self):
+    @pytest.mark.parametrize("prefetching", [False, True],
+                             ids=["plain_outputs", "prefetching_outputs"])
+    def test_results_in_submit_order_with_overlap(self, prefetching):
         plane = DevicePlane.reset_for_testing(budget_bytes=1 << 22)
         kern = LatencyInjectedKernel(lambda x: x + 1, rtt_s=0.005,
                                      serialize=False)
+        if prefetching:
+            _StartsItsCopy.started = 0
+            slow = kern
+
+            def kern(x):
+                return tuple(_StartsItsCopy(o._value, o._deadline)
+                             for o in slow(x))
         stream = plane.open_stream(depth=3)
         t0 = time.perf_counter()
         for i in range(9):
@@ -290,6 +311,10 @@ class TestDeviceStream:
                                           np.full(4, t) + 1)
         assert elapsed < 9 * 0.005, "depth-3 window must overlap RTTs"
         assert plane.inflight_bytes() == 0
+        assert plane.utilization()["d2h_prefetched_total"] \
+            == (9 if prefetching else 0)
+        if prefetching:
+            assert _StartsItsCopy.started == 9    # once per batch, at submit
 
     @pytest.mark.parametrize("point", ["device_plane.ring_advance",
                                        "device_plane.h2d"])
