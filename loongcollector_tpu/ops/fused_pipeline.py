@@ -9,16 +9,20 @@ execution.  This module does the same for a pipeline's consecutive
 device-capable stages:
 
 * **StageSpec / StageCond** — the declarative resident form of one stage
-  (Tier-1 extract, fused multi-accept scan, structural index, filter keep
-  mask).  A filter condition over a field the in-program extract stage
-  just captured binds to that stage's DEVICE-RESIDENT span columns
-  (``("capture", producer, cap)``) — no host bounce, no re-pack between
-  stages.
+  (Tier-1 extract, JSON field spans, fused multi-accept scan, structural
+  index, filter keep mask).  A filter condition over a field an earlier
+  stage of the program publishes binds to that stage's DEVICE-RESIDENT
+  span columns (``("capture", producer, cap)``) — no host bounce, no
+  re-pack between stages.  The contract is the columns, not the kind: any
+  stage in ``SPAN_STAGES`` publishes ``(ok[B], off[B, C], len[B, C])`` as
+  its first three outputs (row-relative offsets, length −1 where the
+  capture is absent), and that is all a ``span_match`` reads.
 
 * **FusedProgramKernel** — ONE jitted program per (stage list, B, L)
   geometry composed from the existing kernel cores
   (``build_extract_fn`` / ``build_fused_scan_fn`` / ``build_index_fn`` /
-  ``build_dfa_match_fn`` / ``build_dfa_span_match_fn``): inputs packed
+  ``build_dfa_match_fn`` / ``build_dfa_span_match_fn`` /
+  ``build_json_fields_fn``): inputs packed
   once, inter-stage columns stay in HBM, every stage's outputs
   materialise together in one D2H.  ``donated_call`` mirrors the
   loongstream donated-buffer contract.
@@ -75,7 +79,13 @@ ENV_WARM = "LOONG_FUSED_WARM"
 ENV_CACHE = "LOONG_FUSED_CACHE"
 
 #: flat-output width per stage kind
-_STAGE_WIDTH = {"extract": 3, "scan": 1, "struct_index": 4, "keep": 1}
+_STAGE_WIDTH = {"extract": 3, "scan": 1, "struct_index": 4, "keep": 1,
+                "json_fields": 6}
+
+#: stage kinds that publish span columns: their first three outputs are
+#: ``(ok[B], off[B, C], len[B, C])`` with C = ``payload.num_caps``, and a
+#: ``span_match`` condition may bind any of them
+SPAN_STAGES = ("extract", "json_fields")
 
 
 def fusion_enabled() -> bool:
@@ -106,8 +116,9 @@ class StageCond:
 
     kind: ``match`` (DFA full-match over the source rows), ``extract_ok``
     (Tier-1 program ok bit over the source rows), ``span_match`` (DFA
-    full-match over a PRIOR stage's capture span, device-resident —
-    ``binding=(producer_stage_idx, cap_idx)``).  ``staged`` is the
+    full-match over a span column a PRIOR stage publishes, device-resident
+    — ``binding=(producer_stage_idx, cap_idx)``; the producer is any stage
+    of ``SPAN_STAGES``).  ``staged`` is the
     condition's own kernel for the per-stage demotion path."""
 
     __slots__ = ("kind", "payload", "binding", "negate", "staged", "ident")
@@ -127,8 +138,10 @@ class StageSpec:
     """Declarative resident form of one device-capable pipeline stage.
 
     kind: ``extract`` (Tier-1 segment program → ok + capture spans),
-    ``scan`` (fused multi-accept automaton → tag bitmask), ``struct_index``
-    (structural bitmaps), ``keep`` (filter mask over StageConds).
+    ``json_fields`` (top-level JSON members → ok + value spans, status,
+    member count, key signature), ``scan`` (fused multi-accept automaton →
+    tag bitmask), ``struct_index`` (structural bitmaps), ``keep`` (filter
+    mask over StageConds).
 
     ``ident`` is the canonical content identity (pattern strings, mode)
     the program cache hashes; ``staged`` is the stage's OWN kernel (the
@@ -162,12 +175,16 @@ def build_fused_fn(specs: Sequence[StageSpec]):
                                    build_dfa_span_match_fn,
                                    build_fused_scan_fn)
     from .kernels.field_extract import build_extract_fn
+    from .kernels.json_fields import build_json_fields_fn
     from .kernels.struct_index import build_index_fn
 
     stage_fns: List = []
     for spec in specs:
         if spec.kind == "extract":
             stage_fns.append(build_extract_fn(spec.payload))
+        elif spec.kind == "json_fields":
+            stage_fns.append(build_json_fields_fn(
+                spec.payload.kmax, tuple(spec.payload.bound)))
         elif spec.kind == "scan":
             stage_fns.append(build_fused_scan_fn(spec.payload))
         elif spec.kind == "struct_index":
@@ -192,12 +209,10 @@ def build_fused_fn(specs: Sequence[StageSpec]):
         stage_outs: List[Tuple] = []
         flat: List = []
         for spec, fn in zip(specs, stage_fns):
-            if spec.kind == "extract":
+            if spec.kind in SPAN_STAGES or spec.kind == "struct_index":
                 outs = tuple(fn(rows, lengths))
             elif spec.kind == "scan":
                 outs = (fn(rows, lengths),)
-            elif spec.kind == "struct_index":
-                outs = tuple(fn(rows, lengths))
             else:  # keep
                 keep = None
                 for cond, cfn in zip(spec.payload, fn):
@@ -207,9 +222,10 @@ def build_fused_fn(specs: Sequence[StageSpec]):
                         ok = cfn(rows, lengths) & (lengths >= 0)
                     elif cond.kind == "extract_ok":
                         ok = cfn(rows, lengths)[0] & (lengths >= 0)
-                    else:  # span_match: prior stage's device-resident spans
+                    else:  # span_match: the span columns a prior stage
+                        # publishes (SPAN_STAGES), still device-resident
                         prod, cap = cond.binding
-                        _p_ok, p_off, p_len = stage_outs[prod]
+                        p_off, p_len = stage_outs[prod][1:3]
                         ok = cfn(rows, lengths, p_off[:, cap], p_len[:, cap])
                     if cond.negate:
                         ok = ~ok
@@ -316,7 +332,7 @@ class FusedProgramKernel:
         outs: List[Tuple[np.ndarray, ...]] = []
         lens_np = np.asarray(lengths)
         for spec in self.specs:
-            if spec.kind in ("extract", "scan", "struct_index"):
+            if spec.kind != "keep":
                 raw = spec.staged(rows, lengths)
                 if not isinstance(raw, (tuple, list)):
                     raw = (raw,)
@@ -337,7 +353,7 @@ class FusedProgramKernel:
                             & (lens_np >= 0)
                     else:
                         prod, cap = cond.binding
-                        _ok, p_off, p_len = outs[prod]
+                        p_off, p_len = outs[prod][1:3]
                         # loonglint: disable=host-bounce
                         ok = np.asarray(cond.staged(
                             rows, lengths, p_off[:, cap], p_len[:, cap]))
@@ -375,6 +391,10 @@ class FusedProgramKernel:
         return {
             "signature": self.signature,
             "stages": [s.label for s in self.specs],
+            # span columns each stage publishes (0: none) — with the
+            # geometries, the shapes of a call's outputs
+            "captures": [s.payload.num_caps if s.kind in SPAN_STAGES else 0
+                         for s in self.specs],
             "dispatches": self.dispatch_count,
             "demotions": self.demotions,
             "lane_respills": self.lane_respills,
@@ -562,10 +582,28 @@ def _note_demotion(program: FusedProgramKernel, reason: str) -> None:
         pass
 
 
+#: why a row of a ``json_fields`` stage was handed to the host's emitter
+JSON_HOST_REASONS = ("escape", "shape", "not_object", "overlong")
+
+
+def note_json_rows(rows: int, host_rows: Optional[dict] = None,
+                   signatures_decoded: int = 0) -> None:
+    """One group through a ``json_fields`` stage: the rows it held, those
+    the host's emitter had to take by reason (`JSON_HOST_REASONS`), and the
+    key signatures decoded into names for it."""
+    _count("json_rows_total", rows)
+    for reason, n in (host_rows or {}).items():
+        if n:
+            _count(f"json_host_rows_{reason}_total", int(n))
+    if signatures_decoded:
+        _count("json_signatures_decoded_total", signatures_decoded)
+
+
 def stage_fusion_status() -> dict:
     """The /debug/status ``stage_fusion`` section and bench.py
     ``extra.stage_fusion`` source: per-program dispatch/demotion rows plus
-    the cache counters."""
+    the cache counters, and under ``json`` the ``json_fields`` stage's row
+    accounting (absent until such a stage has seen a group)."""
     with _mem_cache_lock:
         programs = [p.status() for p in _mem_cache.values()]
     doc = {"enabled": fusion_enabled(), "programs": programs}
@@ -576,6 +614,15 @@ def stage_fusion_status() -> dict:
                      "fused_demotions_total", "fused_dispatch_total",
                      "fused_lane_respill_total"):
             doc[name] = int(rec.counter(name).value)
+        rows = int(rec.counter("json_rows_total").value)
+        if rows:
+            doc["json"] = {
+                "rows_total": rows,
+                "host_rows_total": {
+                    r: int(rec.counter(f"json_host_rows_{r}_total").value)
+                    for r in JSON_HOST_REASONS},
+                "signatures_decoded_total": int(rec.counter(
+                    "json_signatures_decoded_total").value)}
     except Exception:  # noqa: BLE001
         pass
     return doc
@@ -602,7 +649,9 @@ class FusedBatchResult:
     """Assembled per-stage outputs in original row order.
 
     ``stages[i]`` for stage kind: extract → (ok bool [n], cap_off i32
-    [n, C] ARENA-ABSOLUTE, cap_len i32 [n, C]); scan → (tags uint32 [n],);
+    [n, C] ARENA-ABSOLUTE, cap_len i32 [n, C]); json_fields → the same
+    three, then (status i32 [n], members i32 [n], signature i32 [n, 2]);
+    scan → (tags uint32 [n],);
     keep → (keep bool [n],); struct_index → (in_string, structural,
     escaped, quote) bool [n, Lmax]."""
 
@@ -653,11 +702,16 @@ class FusedDispatch:
         n = self._n
         bufs: List = []
         for spec in self.program.specs:
-            if spec.kind == "extract":
+            if spec.kind in SPAN_STAGES:
                 C = max(spec.payload.num_caps, 1)
-                bufs.append((np.zeros(n, dtype=bool),
-                             np.zeros((n, C), dtype=np.int32),
-                             np.full((n, C), -1, dtype=np.int32)))
+                spans = (np.zeros(n, dtype=bool),
+                         np.zeros((n, C), dtype=np.int32),
+                         np.full((n, C), -1, dtype=np.int32))
+                if spec.kind == "json_fields":
+                    spans += (np.zeros(n, dtype=np.int32),
+                              np.zeros(n, dtype=np.int32),
+                              np.zeros((n, 2), dtype=np.int32))
+                bufs.append(spans)
             elif spec.kind == "scan":
                 bufs.append((np.zeros(n, dtype=np.uint32),))
             elif spec.kind == "keep":
@@ -849,13 +903,15 @@ class FusedDispatch:
         for si, spec in enumerate(self.program.specs):
             start, width = self.program.layout[si]
             outs = flat[start:start + width]
-            if spec.kind == "extract":
-                ok_b, off_b, len_b = self._stage_bufs[si]
+            if spec.kind in SPAN_STAGES:
+                ok_b, off_b, len_b = self._stage_bufs[si][:3]
                 ok_b[chunk] = outs[0][:n_real]
                 # row-relative -> arena-absolute via the pack origins
                 off_b[chunk] = (outs[1][:n_real]
                                 + batch.origins[:n_real, None])
                 len_b[chunk] = outs[2][:n_real]
+                for buf, out in zip(self._stage_bufs[si][3:], outs[3:]):
+                    buf[chunk] = out[:n_real]
             elif spec.kind == "scan":
                 self._stage_bufs[si][0][chunk] = \
                     outs[0][:n_real].astype(np.uint32)

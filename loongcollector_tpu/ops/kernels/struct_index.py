@@ -26,6 +26,17 @@ The kernel is a single jitted function per (mode, B, L) geometry —
 `ops.device_batch` length buckets the streaming plane uses and counts one
 dispatch per slot (asserted single-invocation in the device test).  The
 numpy twin below is the no-JAX fallback tier and the reference for both.
+
+Who runs it: no served path calls `StructIndexKernel` — tests, the
+equivalence gate and the compile check do.  Its four masks are as large as
+the rows they index, and nothing turned device-made masks into JSON field
+spans, so `processor_parse_json_tpu` parsed on the host's native plane in
+every pipeline until PR 27.  What the served path runs of this module is
+`_index_core`: the `json_fields` stage (json_fields.py) starts from its
+masks and goes on, on the device, to the value spans a fused run needs
+(docs/performance.md, "Structural-index parsing", says which pipelines
+reach it).  `emit_delim_spans` is the delimiter mode's span step on the
+host; `processor_parse_delimiter_tpu` uses the native walk instead.
 """
 
 from __future__ import annotations

@@ -12,11 +12,18 @@ fused program computed them for ALL packed rows, which is equivalent
 because member stages are per-row independent).
 
 Binding rules (`FusionPlanContext`): the run packs ONE source column;
-members either consume those same rows or bind a PRIOR member's capture
-column (device-resident span binding).  A stage whose inputs cannot be
-proven statically — a field minted outside the run, a source key a prior
-member consumed — refuses to fuse and ends the run; those stages keep
-the per-stage dispatch path untouched.
+members either consume those same rows or bind a span column a PRIOR
+member publishes (device-resident span binding).  The producer is any
+stage that publishes ``(ok, off, len)`` columns (`SPAN_STAGES` in
+ops/fused_pipeline.py: the regex `extract`, whose column names are its
+config's `Keys`, and `json_fields`, whose names are the data's own — it
+mints a named capture for each key a later member asks for,
+`note_open_fields`).  A stage whose inputs cannot be proven statically —
+a field minted outside the run, a source key a prior member consumed —
+refuses to fuse and ends the run, and so does one that binds a stage
+which publishes no span columns (the planner says which, instead of an
+unpack error while the program is traced); those stages keep the
+per-stage dispatch path untouched.
 
 Execution contract with CollectionPipeline.process_begin: a run behaves
 like one async-dispatch-capable processor (dispatch → token →
@@ -36,8 +43,9 @@ import numpy as np
 from .. import trace
 from ..monitor import ledger
 from ..ops.device_batch import LENGTH_BUCKETS
-from ..ops.fused_pipeline import (FusedDispatch, fusion_enabled,
-                                  get_fused_program)
+from ..ops.fused_pipeline import (SPAN_STAGES, FusedDispatch,
+                                  fusion_enabled, get_fused_program,
+                                  note_json_rows)
 from ..utils.logger import get_logger
 
 log = get_logger("fused_chain")
@@ -45,16 +53,22 @@ log = get_logger("fused_chain")
 
 class FusionPlanContext:
     """What the planner knows while growing one run: the packed source
-    column, capture columns produced by prior members (name →
-    (stage_idx, cap_idx)), and which keys a member consumed — the
-    information that decides whether the NEXT stage's inputs are
-    statically resident."""
+    column, span columns published by prior members (name →
+    (stage_idx, cap_idx)), a prior member whose column names are the
+    data's own (it mints a capture for a name on request), and which keys
+    a member consumed — the information that decides whether the NEXT
+    stage's inputs are statically resident."""
 
     def __init__(self) -> None:
         self.source_key: Optional[bytes] = None
         self.consumed: set = set()
         self.fields: Dict[str, Tuple[int, int]] = {}
         self.n_stages = 0
+        #: the configuration's processors ahead of the run's first member
+        #: (an input's inner processors, the line splitters, not counted):
+        #: 0 means nothing outside the run has given the group a field
+        self.user_stages_ahead = 0
+        self._open: Optional[Tuple[int, object]] = None
 
     def bind_source(self, key: bytes) -> bool:
         """True when this stage may read the run's packed source rows."""
@@ -81,12 +95,24 @@ class FusionPlanContext:
         if self.source_key is None:
             # a filter heading the run establishes the source column
             return "source"
+        if self._open is not None:
+            stage_idx, mint = self._open
+            self.fields[skey] = (stage_idx, mint(skey))
+            return ("capture",) + self.fields[skey]
         return None
 
     def note_fields(self, stage_idx: int, names: Sequence[str]) -> None:
         for cap, name in enumerate(names):
             if name:
                 self.fields[name] = (stage_idx, cap)
+
+    def note_open_fields(self, stage_idx: int, mint) -> None:
+        """A stage whose field names are not known when the run is planned
+        (a JSON object's keys): ``mint(name)`` returns the capture index
+        under which that stage will publish the named field, and `resolve`
+        records it beside the `note_fields` columns when a later member
+        asks."""
+        self._open = (stage_idx, mint)
 
     def note_consumed(self, key) -> None:
         skey = key.decode("latin-1") if isinstance(key, bytes) else key
@@ -164,6 +190,9 @@ class FusedRun:
         if int(src.lengths.max()) > LENGTH_BUCKETS[-1]:
             # overlong rows keep the per-stage path (its CPU fallback
             # machinery owns them)
+            if any(m.spec.kind == "json_fields" for m in self.members):
+                note_json_rows(len(src.offsets),
+                               {"overlong": len(src.offsets)})
             return None
         try:
             d = FusedDispatch(self.program(), src.arena, src.offsets,
@@ -216,8 +245,35 @@ class FusedRun:
                     inst.out_events.add(len(g))
 
 
-def plan_fusion(chain) -> List[FusedRun]:
-    """Walk the processor chain; every maximal run of ≥ 2 consecutive
+def _unbound_span(spec, members) -> Optional[str]:
+    """Why a ``keep`` stage cannot join the run: one of its ``span_match``
+    conditions binds a member that publishes no ``(ok, off, len)`` columns,
+    or a capture that member does not have.  None when every binding
+    holds."""
+    if spec.kind != "keep":
+        return None
+    for cond in spec.payload:
+        if cond.kind != "span_match":
+            continue
+        prod, cap = cond.binding
+        if not 0 <= prod < len(members):
+            return f"a condition binds stage {prod}, which is not a " \
+                   f"prior member of the run"
+        producer = members[prod].spec
+        if producer.kind not in SPAN_STAGES:
+            return f"a condition binds capture {cap} of stage {prod} " \
+                   f"({producer.label}), which publishes no span columns " \
+                   f"(only {', '.join(SPAN_STAGES)} stages do)"
+        if not 0 <= cap < producer.payload.num_caps:
+            return f"a condition binds capture {cap} of stage {prod} " \
+                   f"({producer.label}), which publishes " \
+                   f"{producer.payload.num_caps}"
+    return None
+
+
+def plan_fusion(chain, n_inner: int = 0) -> List[FusedRun]:
+    """Walk the processor chain (its first ``n_inner`` members the inputs'
+    inner processors); every maximal run of ≥ 2 consecutive
     stages whose plugins produce a statically-bindable StageSpec becomes
     a FusedRun.  Planning is description — no jit, no device transfers
     (capture-bound filter conditions pay one host-side DFA determinize to
@@ -229,6 +285,7 @@ def plan_fusion(chain) -> List[FusedRun]:
     n = len(chain)
     while i < n:
         ctx = FusionPlanContext()
+        ctx.user_stages_ahead = max(i - n_inner, 0)
         members: List[FusedMemberStage] = []
         insts = []
         j = i
@@ -242,6 +299,12 @@ def plan_fusion(chain) -> List[FusedRun]:
                     # must degrade to the per-stage path, not kill init
                     log.exception("fused_stage_spec failed for %s",
                                   chain[j].plugin.name)
+                    ms = None
+            if ms is not None:
+                why = _unbound_span(ms.spec, members)
+                if why:
+                    log.warning("%s does not join the fused run: %s",
+                                chain[j].plugin.name, why)
                     ms = None
             if ms is None:
                 break

@@ -216,7 +216,8 @@ class CollectionPipeline:
         # planning, so flipping it needs no pipeline reload.
         from .fused_chain import plan_fusion
         self._fused_runs = plan_fusion(self.inner_processors
-                                       + self.processors)
+                                       + self.processors,
+                                       len(self.inner_processors))
         self._fused_by_head = {r.head: r for r in self._fused_runs}
 
         # process queue: a modified pipeline keeps its key so queued groups

@@ -34,12 +34,6 @@ TS_NONE = native.NDJSON_TS_NONE
 TS_EPOCH = native.NDJSON_TS_EPOCH
 TS_ISO8601 = native.NDJSON_TS_ISO8601
 
-# 1 where a byte is outside single-byte UTF-8 (>= 0x80): such spans must
-# take the CPython path so invalid sequences get codec-identical treatment
-_HIGH = np.zeros(256, dtype=np.uint8)
-_HIGH[0x80:] = 1
-
-
 def dumps_row(obj: Dict[str, object]) -> bytes:
     """The one canonical row encoder every JSON sink shares (identical to
     the four ``json.dumps(obj, ensure_ascii=False)`` copies it replaced)."""
@@ -113,23 +107,24 @@ def _columnar_layout(group: PipelineEventGroup):
 def _spans_are_ascii(group: PipelineEventGroup, offs: np.ndarray,
                      lens: np.ndarray) -> bool:
     """True when every present span is single-byte UTF-8 (no byte >=
-    0x80).  Cheap max() over the arena answers the common machine-log case
-    in one SIMD pass; only arenas that do contain high bytes pay the
-    per-span cumulative-sum classification."""
+    0x80; a span with one takes the CPython path, so that invalid
+    sequences get codec-identical treatment).  Cheap max() over the arena
+    answers the common machine-log case in one SIMD pass; an arena that does hold high bytes (a JSON group's
+    decoded escapes sit in its side arena whether or not their rows
+    survived a filter) pays one compare for their positions and a binary
+    search per present span — the cost follows the spans that are left,
+    not the arena."""
     raw = group.source_buffer.raw
     if len(raw) == 0:
         return True
     arena = np.frombuffer(raw, dtype=np.uint8, count=len(raw))
     if int(arena.max()) < 0x80:
         return True
-    csum = np.zeros(len(arena) + 1, dtype=np.int64)
-    np.cumsum(_HIGH[arena], out=csum[1:])
+    high = np.flatnonzero(arena.view(np.int8) < 0)
     present = lens >= 0
-    o = np.where(present, offs, 0).astype(np.int64)
-    ln = np.where(present, lens, 0).astype(np.int64)
-    e = np.minimum(o + ln, len(arena))
-    o = np.minimum(o, len(arena))
-    return not bool(((csum[e] - csum[o]) > 0).any())
+    o = offs[present].astype(np.int64)
+    e = o + lens[present]
+    return bool((np.searchsorted(high, o) == np.searchsorted(high, e)).all())
 
 
 def native_group_rows(group: PipelineEventGroup,

@@ -26,6 +26,10 @@ class SourceColumns:
     columnar: bool               # True → spans index the group's arena
     present: np.ndarray          # bool [N] source field existed
     from_content: bool = False   # True → spans are the raw content column
+    #: fused runs only: bool [N], the packed rows whose spans the device did
+    #: not produce (a json_fields stage handed them to the host's emitter);
+    #: a later keep member decides those rows on the host.  None: none.
+    undecided: Optional[np.ndarray] = None
 
 
 def extract_source(group: PipelineEventGroup,

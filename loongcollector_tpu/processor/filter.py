@@ -104,6 +104,15 @@ class ProcessorFilter(Processor):
 
     def _fused_apply(self, group, src, out, rowmap):
         keep = np.asarray(out[0], dtype=bool)[rowmap]
+        if src.undecided is not None:
+            # rows whose spans the device did not produce (a json_fields
+            # producer handed them to the host's emitter): the device's
+            # mask says nothing of them, the staged matcher decides them
+            # from the columns the emitter installed.  An extract producer
+            # leaves none: a row it did not parse has no field to match.
+            rows = np.nonzero(src.undecided[rowmap])[0]
+            if len(rows):
+                keep[rows] = self._host_keep(group, rows)
         if keep.all():
             return rowmap
         cols = group.columns
@@ -115,22 +124,32 @@ class ProcessorFilter(Processor):
         return rowmap[keep]
 
     def _match_field(self, group: PipelineEventGroup, key: bytes,
-                     engine: RegexEngine, n: int) -> np.ndarray:
+                     engine: RegexEngine, n: int, rows=None) -> np.ndarray:
         src = extract_source(group, key)
         if src is None:
             return np.zeros(n, dtype=bool)
-        ok = engine.match_batch(src.arena, src.offsets, src.lengths)
-        return ok & src.present
+        offsets, lengths, present = src.offsets, src.lengths, src.present
+        if rows is not None:
+            offsets, lengths, present = \
+                offsets[rows], lengths[rows], present[rows]
+        ok = engine.match_batch(src.arena, offsets, lengths)
+        return ok & present
 
-    def process(self, group: PipelineEventGroup) -> None:
-        n = len(group)
-        if n == 0:
-            return
+    def _host_keep(self, group: PipelineEventGroup, rows=None) -> np.ndarray:
+        """The staged decision for every event of the group, or for the
+        events ``rows`` (indices) alone."""
+        n = len(group) if rows is None else len(rows)
         keep = np.ones(n, dtype=bool)
         for key, engine in self.include:
-            keep &= self._match_field(group, key, engine, n)
+            keep &= self._match_field(group, key, engine, n, rows)
         for key, engine in self.exclude:
-            keep &= ~self._match_field(group, key, engine, n)
+            keep &= ~self._match_field(group, key, engine, n, rows)
+        return keep
+
+    def process(self, group: PipelineEventGroup) -> None:
+        if len(group) == 0:
+            return
+        keep = self._host_keep(group)
         if keep.all():
             return
         cols = group.columns

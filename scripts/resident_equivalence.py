@@ -14,9 +14,10 @@ over the canonical column snapshot.
 Families where fusion engages (a planned run of ≥ 2 stages exists) also
 assert that the fused side really did fuse — one device dispatch for the
 run — so the gate cannot rot into comparing the staged path to itself.
-The json family intentionally has NO fusable run (parse_json's span
-emission is native-plane): there the gate pins that fusion leaves the
-pipeline untouched.
+The json family fuses since PR 27 (filter → ``json_fields``: the device
+stage emits the field spans, rows it cannot prove go back to the native
+emitter); a lone processor_parse_json_tpu still has no run, which
+tests/test_json_fields.py pins.
 
 Exit 0 = identical everywhere; exit 1 = any digest mismatch (printed
 per family).
@@ -77,7 +78,7 @@ FAMILIES = [
         {"Type": "processor_filter_native",
          "Include": {"content": r"\{.*"}},
         {"Type": "processor_parse_json_tpu"},
-    ], False),  # parse_json has no resident stage form — must not fuse
+    ], True),
     ("multiline", ML_LINES, [
         {"Type": "processor_split_multiline_log_string_native",
          "Multiline": {"StartPattern": r"\[\d+\] .*",
