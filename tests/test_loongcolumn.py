@@ -61,9 +61,13 @@ RX_KEYS = ["src", "seq"]
 
 @pytest.fixture(autouse=True)
 def _columnar_on():
-    """Every test starts on the columnar fast path with fresh counters."""
+    """Every test starts on the columnar fast path with fresh counters,
+    and with no ledger or alarm an earlier file on this worker left behind
+    (test_loongprof leaves the ledger on)."""
     prev = models.set_columnar_enabled(True)
     models.reset_churn_stats()
+    ledger.disable()
+    AlarmManager.instance().flush()
     yield
     models.set_columnar_enabled(prev)
 
