@@ -81,8 +81,8 @@ class HostRouted(SmokeFailure):
 
 
 # ---------------------------------------------------------------------------
-# data: seeded 512-byte Apache lines (scripts/resource_bench.py:_make_line's
-# shape — short fields, the size capture padded with digits to the width)
+# data: seeded 512-byte Apache lines (short fields, the size capture
+# padded with digits to the width)
 
 _METHODS = ("GET", "POST", "PUT", "HEAD")
 _STATUS = ("200", "201", "301", "304", "404", "500")

@@ -158,7 +158,7 @@ class AggregatorMetricRollup(Aggregator):
         self._last_event_wall = 0.0
         self._evict_alarmed = False
         self._device_kern = None
-        # fold→merge key interning (BENCH_r11 device-cliff satellite):
+        # fold→merge key interning:
         # the numpy/device substrates hand back the representatives' raw
         # key-matrix rows (BatchFold.rep_key_blob) — steady-state batches
         # look their merge key tuple up by those hash-key bytes instead

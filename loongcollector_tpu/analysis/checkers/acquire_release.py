@@ -8,6 +8,14 @@ list.  If pack/submit raises mid-loop, the already-submitted futures are
 abandoned, the budget never returns, and every later dispatch stalls
 forever: a liveness bug with no crash.
 
+Today the pending list is the DeviceStream window (ops/device_stream.py),
+the one place that leases, submits and releases; its owners (PendingParse,
+FusedDispatch) loop over ``window.submit_rows(...)``, and the same shape
+applies one level up: every chunk already in the window holds budget, a
+ring slot and lane bytes until ``advance`` / ``drain`` settles it or
+``abandon`` gives it up, so the loop must sit in a try whose handler
+calls one of them.
+
 Rule: a call to an acquire API whose returned obligation ESCAPES the
 statement (stored into a container/attribute, or made in a loop) must be
 lexically covered by a try that can discharge the obligation — a
@@ -21,6 +29,8 @@ Acquire APIs (attr call + receiver filter, to stay quiet on unrelated
 
   .submit(...)    when the receiver mentions a device plane, or the call
                   passes the plane-protocol kwargs (nbytes / on_wait)
+  .submit_rows(...) / .submit(...) on a receiver that mentions a stream
+                  or a window: a chunk handed to the DeviceStream window
   ._acquire(...)  the raw budget primitive, same escape rules
   .lease(...)     loongstream batch-ring slots (receiver mentions a ring
                   OR a chip lane — loongmesh workers lease per-lane slots
@@ -52,7 +62,9 @@ CHECK = "acquire-release"
 
 _RELEASE_ATTRS = {
     "result", "release", "_release", "on_done", "drain", "close",
-    "force_release", "_drain_one", "clear", "cancel",
+    "force_release", "clear", "cancel",
+    # the DeviceStream window's verbs
+    "advance", "abandon",
 }
 
 
@@ -66,9 +78,13 @@ def _is_acquire_call(node: ast.Call) -> bool:
         # lane-keyed pool, or a chip-lane wrapper exposing .lease)
         recv = receiver_repr(node).lower()
         return "ring" in recv or "lane" in recv
-    if tail != "submit":
+    if tail not in ("submit", "submit_rows"):
         return False
     recv = receiver_repr(node).lower()
+    if "window" in recv or "stream" in recv:
+        return True
+    if tail != "submit":
+        return False
     if "plane" in recv:
         return True
     kwargs = {kw.arg for kw in node.keywords}
@@ -191,6 +207,7 @@ class AcquireReleaseChecker(Checker):
                 if _guarding_try(parents, node, func):
                     continue
                 what = ("ring slot leased" if tail == "lease"
+                        else "chunk put in flight" if tail == "submit_rows"
                         else "budget acquired")
                 stranded = ("the leased ring slot"
                             if tail == "lease" else "the in-flight budget")

@@ -1,7 +1,7 @@
 """host-bounce: host pulls between two device dispatches in one function.
 
-The loongresident contract (docs/performance.md "Single-dispatch pipeline
-fusion"): consecutive device-capable stages hand their intermediates to
+The loongresident contract (ops/fused_pipeline.py): consecutive
+device-capable stages hand their intermediates to
 each other IN HBM — one pack, one dispatch, one materialise.  A function
 that dispatches a kernel, pulls the result to the host
 (``np.asarray`` / ``jax.device_get`` / ``.block_until_ready()`` /

@@ -1,13 +1,13 @@
 """hot-path-materialize: per-event object churn on the columnar fast path.
 
-loongcolumn's contract (docs/performance.md "Columnar event path"): groups
+loongcolumn's contract (docs/observability.md "Columnar event path"): groups
 flow as arena-span columns from ingest to sink, and per-event Python
 objects are minted ONLY at the instance-wrapper boundary of a plugin that
 declared no columnar support — explicitly, counted in
 ``models.churn_stats()``.  Code in the hot scopes below that touches the
 materializing surface silently re-introduces exactly the per-event
-allocation the columnar plane removed (BENCH_r08: the dict path spent its
-time building ``_contents`` tuples, not parsing).
+allocation the columnar plane removed (the dict path spent its time
+building ``_contents`` tuples, not parsing).
 
 Flagged in ``ops/`` and ``pipeline/serializer/`` (the device + wire hot
 scopes):

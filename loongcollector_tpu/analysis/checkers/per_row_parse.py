@@ -1,12 +1,12 @@
 """per-row-parse: per-row Python parsing inside columnar-capable plugins.
 
-loongstruct's contract (docs/performance.md "Structural-index parsing"):
+loongstruct's contract (docs/device_plane.md "Structural indexing"):
 columnar groups parse through whole-buffer passes — the native structural
 index, the device kernel, or a vectorised numpy emitter.  A `json.loads`
 or CSV-FSM call sitting inside a loop in a columnar-capable processor
 body re-introduces exactly the per-row Python tail this plane retired
-(BENCH_r09: JSON at 497 MB/s against 1328 for simple-line, because every
-escape-bearing row dropped to `json.loads`).
+(every escape-bearing row once dropped to `json.loads`, and JSON ran at
+a third of the simple-line rate for it).
 
 Flagged inside any class body declaring ``supports_columnar = True``:
 

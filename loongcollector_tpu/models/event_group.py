@@ -74,8 +74,8 @@ def _note_materialized(n_events: int, where: str) -> None:
 def churn_stats() -> Dict[str, object]:
     """Process-lifetime materialization counters: how many per-event
     Python objects the lazy boundary actually minted, and at which plugin
-    boundaries.  The columnar fast path's regression signal — see
-    bench.py extra.alloc and docs/performance.md."""
+    boundaries.  The columnar fast path's regression signal
+    (/debug/status ``columnar``)."""
     with _churn_lock:
         return {"materialized_events": _materialized_events,
                 "materialized_groups": _materialized_groups,

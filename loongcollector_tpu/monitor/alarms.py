@@ -113,8 +113,7 @@ class AlarmType(str, enum.Enum):
     PARSE_FALLBACK_DEGRADED = "PARSE_FALLBACK_DEGRADED_ALARM"
     # loongresident: a fused pipeline program demoted a chunk to the
     # per-stage dispatch path — answers identical, but that chunk paid N
-    # round trips instead of one (docs/performance.md "Single-dispatch
-    # pipeline fusion")
+    # round trips instead of one (ops/fused_pipeline.py)
     FUSED_DEMOTED = "FUSED_DISPATCH_DEMOTED_ALARM"
     # loongledger: a quiesced conservation snapshot balanced to nonzero —
     # an event crossed into the agent and left without a ledgered exit

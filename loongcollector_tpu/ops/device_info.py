@@ -1,6 +1,6 @@
 """Which device this process computes on, and where its compiles are cached.
 
-Every process that touches JAX (the agent, bench.py) calls
+Every process that touches JAX (the agent, chip_smoke.py) calls
 :func:`start` once, before its first compile.  It places the persistent
 compile cache, initialises the backend, and refuses to run on a CPU the
 operator did not ask for: CPU is reached only by an explicit pin

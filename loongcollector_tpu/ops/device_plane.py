@@ -299,11 +299,11 @@ def note_host_backlog() -> None:
 def set_budget_relief(fn: Optional[Callable[[], bool]]) -> None:
     """Register this thread's last-resort budget releaser.  While a thread
     waits for budget in `submit`, the plane first lets the in-dispatch
-    PendingParse drain its own chunks (`on_wait`); if that owns nothing, the
-    relief hook runs — the ProcessorRunner registers one that completes the
-    overlapped group it still holds.  Together they enforce the no-deadlock
-    invariant: a thread waiting for budget never holds unmaterialised
-    futures it cannot release itself."""
+    DeviceStream window drain its own chunks (`on_wait`); if that owns
+    nothing, the relief hook runs — the ProcessorRunner registers one that
+    completes the overlapped group it still holds.  Together they enforce
+    the no-deadlock invariant: a thread waiting for budget never holds
+    unmaterialised futures it cannot release itself."""
     _tls.relief = fn
 
 
@@ -703,13 +703,15 @@ class DevicePlane:
 
     # -- dispatch -----------------------------------------------------------
 
-    def open_stream(self, depth: Optional[int] = None):
+    def open_stream(self, depth: Optional[int] = None, **owner):
         """A pipelined dispatch window over this plane (loongstream): up to
         ``depth`` batches in flight, strict submit-order results, ring
         advance on overflow — the streaming replacement for the
-        submit→materialise round trip.  See ops/device_stream.DeviceStream."""
+        submit→materialise round trip.  ``owner`` is what the dispatcher
+        that owns the window tells it (program tag, lane, recovery, …).
+        See ops/device_stream.DeviceStream."""
         from .device_stream import DeviceStream
-        return DeviceStream(self, depth)
+        return DeviceStream(self, depth, **owner)
 
     def submit(self, kernel: Callable, args: Sequence, nbytes: int,
                should_abort: Optional[Callable[[], bool]] = None,

@@ -10,10 +10,9 @@ closes that gap on the host side of the dispatch:
   slot's arrays instead of allocating per dispatch (no allocator churn, no
   fresh page faults on the H2D path), and every pack records padding waste
   (padded-vs-real rows and bytes) per geometry, observable in
-  /debug/status, the Prometheus exposition and ``bench.py``
-  ``extra.utilization``.  Slots are leased and MUST be released exactly
-  once — the loonglint acquire-release checker enforces the pairing the
-  same way it does for device-budget futures.
+  /debug/status and the Prometheus exposition.  Slots are leased and
+  MUST be released exactly once — the loonglint acquire-release checker
+  enforces the pairing the same way it does for device-budget futures.
 
 * **DeviceStream** — the pipelined dispatch window (ParPaRaw's feeding
   discipline): up to ``depth`` batches stay in flight; submitting into a
@@ -31,9 +30,10 @@ closes that gap on the host side of the dispatch:
   to buy overlap; when the device keeps up, the deadline shrinks back for
   latency).
 
-Chaos fault points ``device_plane.h2d`` (pack/transfer stage — wrap the
-kernel with :func:`h2d_gated`) and ``device_plane.ring_advance``
-(materialise stage) make the async stages stormable.
+Chaos fault points ``device_plane.h2d`` (pack/transfer stage — the window
+submits every call through :func:`h2d_gated`) and
+``device_plane.ring_advance`` (materialise stage; an owner may name its
+own point) make the async stages stormable.
 """
 
 from __future__ import annotations
@@ -48,8 +48,9 @@ import numpy as np
 
 from .. import chaos
 from .. import trace
-from . import xprof
-from .device_batch import MIN_BATCH, pack_rows
+from . import chip_lanes, xprof
+from .device_batch import (LENGTH_BUCKETS, MIN_BATCH, pack_rows, pad_batch,
+                           pick_length_bucket)
 
 FP_RING_ADVANCE = chaos.register_point("device_plane.ring_advance")
 FP_H2D = chaos.register_point("device_plane.h2d")
@@ -69,7 +70,7 @@ def stream_depth(env=os.environ) -> int:
     """Pipeline depth: how many batches one dispatch loop keeps in flight
     (pack N+1 / compute N / span-return N-1 needs 3).  ``LOONG_STREAM_DEPTH``
     overrides; clamped to [1, 8] — 1 degenerates to the synchronous
-    submit→materialise round-trip (the bench sweep's baseline)."""
+    submit→materialise round-trip."""
     raw = env.get(ENV_DEPTH)
     if raw:
         try:
@@ -490,8 +491,8 @@ class WidthAutoTuner:
     # -- observability ------------------------------------------------------
 
     def chosen(self) -> dict:
-        """The tuner's current decisions — /debug/status and bench.py
-        record these so every geometry the auto-tuner picked is auditable."""
+        """The tuner's current decisions — /debug/status records these so
+        every geometry the auto-tuner picked is auditable."""
         def _bucket(st: _BucketState) -> dict:
             return {"floor": st.floor,
                     "ewma_row_padding_fraction": round(st.ewma_pad, 4),
@@ -553,34 +554,100 @@ def reset_for_testing() -> None:
 # the pipelined dispatch window
 
 
+class Chunk:
+    """One dispatch riding the window: the caller's tag, the packed batch
+    and its ring slot, the future, and the bare kernel it was submitted on
+    (a recovery re-run must not pass the dispatch-side fault gates again).
+    ``t_advance`` is stamped when its materialisation starts."""
+
+    __slots__ = ("tag", "batch", "slot", "fut", "kernel", "nbytes",
+                 "t_advance")
+
+    def __init__(self, tag, batch, slot, fut, kernel, nbytes):
+        self.tag = tag
+        self.batch = batch
+        self.slot = slot
+        self.fut = fut
+        self.kernel = kernel
+        self.nbytes = nbytes
+        self.t_advance = 0.0
+
+
+def _deliver_error(chunk: Chunk, exc: BaseException):
+    """Default recovery: the fault IS the chunk's entry, in its position."""
+    return exc
+
+
 class DeviceStream:
-    """Ordered pipelined dispatch over a DevicePlane.
+    """THE in-flight chunk ring: ordered pipelined dispatch over a
+    DevicePlane.  The regex engine's PendingParse and the fused plane's
+    FusedDispatch each own one; nothing else keeps chunks in flight.
 
-    ``submit`` never lets more than ``depth`` batches stay in flight: a
-    full window first advances the ring (materialises the OLDEST batch),
-    so with depth 3 the host is packing batch N+1 while the device
-    computes N and N-1's spans return — each batch's copy back starts at
-    its dispatch (``DevicePlane.submit``), so the advance finds the outputs
-    on the host.  ``drain()`` materialises the rest.
-    Results arrive strictly in submit order as ``(tag, outputs)`` — an
-    errored batch (kernel failure or injected ``device_plane.h2d`` /
-    ``device_plane.ring_advance`` fault) delivers ``(tag, exception)`` in
-    its slot's position: the fault costs one batch, never the ring.
+    The window owns ring order and every release:
 
-    NOTE: the regex engine's PendingParse implements the same window
-    discipline inline (ops/regex/engine.py) because its per-chunk error
-    handling is engine-specific (Pallas→XLA pinning, CPU re-run of a
-    faulted chunk).  A change to the ring invariants here — advance
-    order, slot/budget release, fault isolation — almost certainly needs
-    a mirror there.
+    * ``admit`` — the lane gate (a lane whose breaker is OPEN, or whose
+      half-open probe is already in flight, makes the caller respill the
+      chunk), then room: a full window first materialises its OLDEST chunk,
+      and a lane holding more than its share of the plane budget drains
+      its own oldest — one slow chip backs up its own lane only.
+    * ``submit_rows`` — geometry (length bucket, tuner floor, the kernel's
+      batch multiple), ``ring.lease`` / ``slot.pack`` / ``plane.submit``
+      of the ``h2d_gated`` call.  When the budget would block, the
+      ``on_wait`` hook materialises this window's own oldest chunk — never
+      sleep in submit while owning the budget you wait for.  The slot
+      returns if pack or submit raises.
+    * ``advance`` — in submit order; each chunk's copy back started at its
+      dispatch (``DevicePlane.submit``), so the advance finds the outputs
+      on the host.  One ``finally`` returns slot, budget and lane bytes,
+      whatever the chunk's fate.
+    * ``abandon`` — the cleanup of a dispatch or a drain that failed:
+      every pending chunk gives back budget, lane bytes, a held half-open
+      probe (no health sample) and its slot.  The round-5 budget leak was
+      one missing copy of exactly this.
+
+    The owner says what differs, as arguments: ``program`` (the timeline
+    tag), ``lane`` (its chip lane, if bound), ``tuner_key`` (whose
+    geometry floors an unbound dispatch moves), ``advance_point`` (the
+    chaos point evaluated per advance), and three callbacks —
+
+    ``recover(chunk, exc)`` for a chunk whose materialisation raised:
+    return its outputs from another path, return None when the recovery
+    wrote the results itself, or raise.  The window reports the lane's
+    breaker from that, once: an injected async-stage fault
+    (``chaos.ChaosFault``) says nothing about the chip, so a recovery that
+    returns is the probe's success and one that raises is inconclusive; a
+    ``ChipLaneFault`` or a real failure is the chip's own (failure, and a
+    ``ChipLaneFault`` is also counted as respilled rows).  Without a
+    callback the fault is delivered as the chunk's entry: it costs one
+    batch, never the ring.
+    ``deliver(chunk, outputs)`` writes outputs into the owner's buffers
+    (the slot may be repacked the moment it returns, so this runs before
+    the release); by default entries collect as ``(tag, outputs)`` for
+    ``drain()`` to return.
+    ``settled(chunk)`` runs once per submitted chunk after its releases,
+    for accounting the owner opened at submit.
     """
 
-    def __init__(self, plane=None, depth: Optional[int] = None):
+    def __init__(self, plane=None, depth: Optional[int] = None, *,
+                 program: str = "stream", lane=None, tuner_key=None,
+                 advance_point: str = FP_RING_ADVANCE,
+                 recover=None, deliver=None, settled=None):
         if plane is None:
             from .device_plane import DevicePlane
             plane = DevicePlane.instance()
         self.plane = plane
         self.depth = max(1, depth if depth is not None else stream_depth())
+        self.program = program
+        self.lane = lane
+        self._lane_count = chip_lanes.router().lane_count() \
+            if lane is not None else 0
+        # loongmesh keys the tuner's floors per chip; an unbound owner
+        # may name a pseudo-lane of its own
+        self._tuner_key = lane.index if lane is not None else tuner_key
+        self._advance_point = advance_point
+        self._recover = recover or _deliver_error
+        self._deliver = deliver or self._collect
+        self._settled = settled
         self._window: deque = deque()
         self._results: List[Tuple[object, object]] = []
         self.advances = 0
@@ -588,8 +655,55 @@ class DeviceStream:
     def inflight(self) -> int:
         return len(self._window)
 
+    # -- dispatch -----------------------------------------------------------
+
+    def admit(self, n_rows: int) -> bool:
+        """Gate and room for the next chunk.  False: this chip is sick —
+        the caller respills the chunk's rows (counted on the lane here);
+        events still flow, in order, and the other lanes never notice."""
+        lane = self.lane
+        if lane is not None and not lane.breaker.allow_probe():
+            lane.note_respill(n_rows)
+            return False
+        while len(self._window) >= self.depth:
+            self.advance()
+        while lane is not None and self._window \
+                and lane.over_share(self.plane, self._lane_count):
+            self.advance()
+        return True
+
+    def pack(self, arena: np.ndarray, offsets: np.ndarray,
+             lengths: np.ndarray, multiple_of: int = 1):
+        """Choose the geometry, lease a ring slot and pack the rows into
+        it.  Returns ``(slot, batch)``; the slot is the caller's to
+        release (``submit`` takes it over)."""
+        n = len(offsets)
+        L = pick_length_bucket(int(lengths.max()) if n else 1) \
+            or LENGTH_BUCKETS[-1]
+        key = self._tuner_key
+        B = pad_batch(n, min_batch=auto_tuner().min_batch_for(L, key),
+                      multiple_of=multiple_of)
+        slot = batch_ring().lease(B, L)
+        try:
+            return slot, slot.pack(arena, offsets, lengths, lane=key)
+        except BaseException:
+            slot.release()
+            raise
+
+    def submit_rows(self, call, arena: np.ndarray, offsets: np.ndarray,
+                    lengths: np.ndarray, tag=None, kernel=None) -> Chunk:
+        """Pack the rows into a ring slot and dispatch ``call`` on them.
+        ``kernel`` is the bare kernel behind ``call`` (what a recovery
+        re-runs, and whose ``batch_multiple`` sizes the slot)."""
+        slot, batch = self.pack(arena, offsets, lengths,
+                                getattr(kernel, "batch_multiple", 1))
+        return self.submit(call, (batch.rows, batch.lengths),
+                           batch.rows.nbytes, tag=tag, slot=slot,
+                           batch=batch, bare=kernel)
+
     def submit(self, kernel, args, nbytes: int, tag=None,
-               slot: Optional[BatchSlot] = None) -> None:
+               slot: Optional[BatchSlot] = None, batch=None,
+               bare=None) -> Chunk:
         """Dispatch under the plane budget, advancing first if the window
         is full.  When ``slot`` is given the stream owns its release (at
         materialisation, success or error — including a failure in the
@@ -603,49 +717,123 @@ class DeviceStream:
             if slot is not None:
                 slot.release()
             raise
-        self._window.append((tag, slot, fut))
+        chunk = Chunk(tag, batch, slot, fut, bare or kernel, nbytes)
+        self._window.append(chunk)
         if slot is not None:
-            xprof.note_dispatch(fut, "stream", f"{slot.B}x{slot.L}",
-                                slot.pack_t0, slot.pack_dur)
+            geometry, t0, dur = f"{slot.B}x{slot.L}", slot.pack_t0, \
+                slot.pack_dur
         else:
-            xprof.note_dispatch(fut, "stream", "-")
+            geometry, t0, dur = "-", None, None
+        xprof.note_dispatch(fut, self.program, geometry, t0, dur)
+        lane = self.lane
+        if lane is not None:
+            if batch is not None:
+                lane.note_pack(slot.B, batch.n_real)
+            lane.note_dispatch(nbytes)
+        return chunk
 
     def _advance_if_any(self) -> bool:
+        """Budget-wait hook: materialise our oldest in-flight chunk so the
+        bytes we hold are released while we wait (DevicePlane._acquire's
+        deadlock-freedom rule)."""
         if not self._window:
             return False
         self.advance()
         return True
 
+    # -- materialisation ----------------------------------------------------
+
     def advance(self):
-        """Materialise the oldest in-flight batch (the ring advance) and
-        append its result.  Errors are captured per batch — the window
-        keeps its order and the slot/budget always return."""
+        """Materialise the oldest in-flight chunk (the ring advance) and
+        deliver it.  A fault is the owner's ``recover`` to answer; the
+        window keeps its order and slot, budget and lane bytes always
+        return."""
         if not self._window:
             return None
-        tag, slot, fut = self._window.popleft()
+        chunk = self._window.popleft()
         self.advances += 1
+        chunk.t_advance = time.perf_counter()
         try:
             try:
-                chaos.faultpoint(FP_RING_ADVANCE)
-                out = fut.result()
-            except Exception as e:  # noqa: BLE001 — delivered in-order
-                fut.release()
-                out = e
-            except BaseException:
-                # KeyboardInterrupt/SystemExit must reach the caller, not
-                # become a ring entry — release and propagate
-                fut.release()
-                raise
+                chaos.faultpoint(self._advance_point)
+                out = chunk.fut.result()
+            except Exception as e:  # noqa: BLE001 — the owner's recovery
+                chunk.fut.release()
+                out = self._recovered(chunk, e)
+            else:
+                if self.lane is not None:
+                    # healthy materialisation on this chip: breaker sample
+                    # (re-closes a half-open lane when this was the probe)
+                    self.lane.breaker.on_success()
+            if out is not None:
+                self._deliver(chunk, out)
         finally:
-            if slot is not None:
-                slot.release()
-        self._results.append((tag, out))
+            self._settle(chunk)
         return out
 
-    def drain(self) -> List[Tuple[object, object]]:
-        """Advance until the window empties; returns (and clears) all
-        results in submit order."""
+    def _recovered(self, chunk: Chunk, exc: Exception):
+        """Run the owner's recovery and tell the lane's breaker how the
+        chunk ended — exactly once, or a chunk holding the half-open probe
+        wedges its slot and the lane respills for probe_timeout_s."""
+        lane = self.lane
+        if lane is None:
+            return self._recover(chunk, exc)
+        chip_fault = isinstance(exc, chip_lanes.ChipLaneFault)
+        injected = isinstance(exc, chaos.ChaosFault) and not chip_fault
+        out = exc
+        try:
+            out = self._recover(chunk, exc)
+            return out
+        finally:
+            if not injected:
+                lane.breaker.on_failure()
+                lane.note_fault()
+                if chip_fault:
+                    lane.note_respill(int(chunk.batch.n_real))
+            elif out is not exc:
+                lane.breaker.on_success()
+            else:
+                lane.breaker.on_inconclusive()
+
+    def _collect(self, chunk: Chunk, out) -> None:
+        self._results.append((chunk.tag, out))
+
+    def _settle(self, chunk: Chunk) -> None:
+        """Give back everything a chunk holds: budget (a no-op once
+        ``result()`` returned it), lane bytes, then the slot — last,
+        because it may be repacked the moment it returns to the ring."""
+        try:
+            chunk.fut.release()
+            if self.lane is not None:
+                self.lane.note_done(chunk.nbytes)
+        finally:
+            if chunk.slot is not None:
+                chunk.slot.release()
+            if self._settled is not None:
+                self._settled(chunk)
+
+    def abandon(self) -> None:
+        """Release every pending chunk unmaterialised: the owner gives
+        this dispatch up (a pack, a submit or another chunk's recovery
+        raised) and nobody will ask for them.  A chunk may hold its lane's
+        half-open probe — freed with no health sample."""
         while self._window:
-            self.advance()
+            chunk = self._window.popleft()
+            try:
+                if self.lane is not None:
+                    self.lane.breaker.on_inconclusive()
+            finally:
+                self._settle(chunk)
+
+    def drain(self) -> List[Tuple[object, object]]:
+        """Advance until the window empties; returns (and clears) what the
+        default ``deliver`` collected, in submit order.  A chunk whose
+        recovery raises abandons the rest before the error leaves."""
+        try:
+            while self._window:
+                self.advance()
+        except BaseException:
+            self.abandon()
+            raise
         out, self._results = self._results, []
         return out

@@ -48,7 +48,7 @@ device-capable stages:
 Chaos point ``device_plane.fused_dispatch`` faults the materialise edge:
 ERROR demotes that one chunk to the per-stage path, DELAY exercises the
 ring deadline.  ``stage_fusion_status()`` feeds the /debug/status
-``stage_fusion`` section and ``bench.py`` ``extra.stage_fusion``.
+``stage_fusion`` section.
 """
 
 from __future__ import annotations
@@ -64,12 +64,10 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import chaos
-from . import chip_lanes, xprof
+from . import chip_lanes
 from .chip_lanes import ChipLaneFault, lane_gated
-from .device_batch import (LENGTH_BUCKETS, MAX_BATCH, pad_batch,
-                           pick_length_bucket)
+from .device_batch import MAX_BATCH
 from .device_plane import mem_note_alloc, mem_note_free
-from .device_stream import auto_tuner, batch_ring, h2d_gated, stream_depth
 
 FP_FUSED_DISPATCH = chaos.register_point("device_plane.fused_dispatch")
 
@@ -600,10 +598,10 @@ def note_json_rows(rows: int, host_rows: Optional[dict] = None,
 
 
 def stage_fusion_status() -> dict:
-    """The /debug/status ``stage_fusion`` section and bench.py
-    ``extra.stage_fusion`` source: per-program dispatch/demotion rows plus
-    the cache counters, and under ``json`` the ``json_fields`` stage's row
-    accounting (absent until such a stage has seen a group)."""
+    """The /debug/status ``stage_fusion`` section: per-program
+    dispatch/demotion rows plus the cache counters, and under ``json`` the
+    ``json_fields`` stage's row accounting (absent until such a stage has
+    seen a group)."""
     with _mem_cache_lock:
         programs = [p.status() for p in _mem_cache.values()]
     doc = {"enabled": fusion_enabled(), "programs": programs}
@@ -645,6 +643,12 @@ def reset_for_testing() -> None:
 # ---------------------------------------------------------------------------
 
 
+def _free_resident(chunk) -> None:
+    """A chunk settled (materialised, demoted or abandoned): its
+    inter-stage columns no longer live on the device."""
+    mem_note_free("resident_columns", chunk.nbytes)
+
+
 class FusedBatchResult:
     """Assembled per-stage outputs in original row order.
 
@@ -664,18 +668,20 @@ class FusedBatchResult:
 
 class FusedDispatch:
     """One group's fused parse in flight (the PendingParse of the fused
-    plane).  ``dispatch()`` packs chunks into leased batch-ring slots and
-    submits the ONE fused program per chunk under the DevicePlane budget
-    with ≤ depth chunks in flight; ``result()`` materialises in order and
-    assembles per-stage outputs.  Fault isolation mirrors PendingParse:
-    an injected ``device_plane.fused_dispatch`` (or h2d/submit) fault, a
-    chip-lane fault, or a real kernel failure costs that ONE chunk a
-    demotion to the per-stage dispatch path — never events, never ring
-    order.  Every path releases the chunk's slot, budget and lane bytes."""
+    plane).  ``dispatch()`` submits the ONE fused program per chunk through
+    a `DeviceStream` window (ops/device_stream.py), which owns the ring
+    discipline — ≤ depth chunks in flight under the DevicePlane budget,
+    in-order materialisation, every release; ``result()`` drains it and
+    assembles per-stage outputs.  This class's own: the callable a chunk
+    rides, the recovery of a faulted chunk — an injected
+    ``device_plane.fused_dispatch`` (or h2d/submit) fault, a chip-lane
+    fault, or a real kernel failure costs that ONE chunk a demotion to the
+    per-stage dispatch path, never events, never ring order — the assembly,
+    and the fused plane's accounting."""
 
-    __slots__ = ("program", "arena", "offsets", "lengths", "depth",
-                 "_pending", "_stage_bufs", "_struct_parts", "_result",
-                 "_n", "_idle_ms0", "_plane")
+    __slots__ = ("program", "arena", "offsets", "lengths", "_window",
+                 "_stage_bufs", "_struct_parts", "_result", "_n",
+                 "_idle_ms0", "_plane")
 
     def __init__(self, program: FusedProgramKernel, arena: np.ndarray,
                  offsets: np.ndarray, lengths: np.ndarray,
@@ -684,15 +690,19 @@ class FusedDispatch:
         self.arena = arena
         self.offsets = np.asarray(offsets, dtype=np.int64)
         self.lengths = np.asarray(lengths, dtype=np.int32)
-        self.depth = max(1, depth if depth is not None else stream_depth())
         self._n = len(self.offsets)
-        # [(chunk_idx, DeviceBatch, BatchSlot, DeviceFuture, lane)]
-        self._pending: List = []
         self._stage_bufs = self._alloc_stage_bufs()
         self._struct_parts: Dict[int, List] = {}
         self._result: Optional[FusedBatchResult] = None
         from .device_plane import DevicePlane
         self._plane = DevicePlane.instance()
+        # fused programs key their tuner floors per program: a sparse
+        # fused pipeline must not shrink the staged plane's geometry
+        self._window = self._plane.open_stream(
+            depth, program="fused", lane=chip_lanes.current_lane(),
+            tuner_key=f"fused:{program.signature[:8]}",
+            advance_point=FP_FUSED_DISPATCH, recover=self._recover,
+            deliver=self._deliver, settled=_free_resident)
         self._idle_ms0 = \
             self._plane.utilization()["idle_while_backlogged_ms"]
 
@@ -727,33 +737,22 @@ class FusedDispatch:
         return self._result is not None
 
     def dispatch(self) -> "FusedDispatch":
-        ring = batch_ring()
-        tuner = auto_tuner()
         program = self.program
-        lane = chip_lanes.current_lane()
-        lane_count = chip_lanes.router().lane_count() if lane is not None \
-            else 0
-        max_bucket = LENGTH_BUCKETS[-1]
+        window = self._window
+        lane = window.lane
         device_idx = np.arange(self._n)
         try:
             for start in range(0, self._n, MAX_BATCH):
                 chunk = device_idx[start:start + MAX_BATCH]
-                if lane is not None and not lane.breaker.allow_probe():
+                if not window.admit(len(chunk)):
                     # lane OPEN (or the half-open probe is in flight): the
                     # chip is sick — this chunk demotes to the per-stage
                     # path on the base kernels until the probe re-closes
                     # it.  Events still flow, counted as lane respill.
-                    lane.note_respill(len(chunk))
                     program.lane_respills += 1
                     _count("fused_lane_respill_total")
                     self._staged_into(chunk)
                     continue
-                while len(self._pending) >= self.depth:
-                    self._drain_one()
-                while lane is not None \
-                        and lane.over_share(self._plane, lane_count) \
-                        and self._pending:
-                    self._drain_one()
                 override = program._kernel_override
                 if override is not None:
                     def call(r, l, _o=override, _p=program):
@@ -764,136 +763,63 @@ class FusedDispatch:
                 else:
                     call = lane_gated(lane,
                                       program.for_lane(lane).donated_call)
-                d_off = self.offsets[chunk]
-                d_len = self.lengths[chunk]
-                L = pick_length_bucket(int(d_len.max()) if len(d_len)
-                                       else 1) or max_bucket
-                lane_key = lane.index if lane is not None \
-                    else f"fused:{program.signature[:8]}"
-                B = pad_batch(len(chunk),
-                              min_batch=tuner.min_batch_for(L, lane_key))
-                program.note_geometry(B, L)
-                slot = ring.lease(B, L)
-                try:
-                    batch = slot.pack(self.arena, d_off, d_len,
-                                      lane=lane_key)
-                    fut = self._plane.submit(
-                        h2d_gated(call), (batch.rows, batch.lengths),
-                        batch.rows.nbytes, on_wait=self._drain_if_pending)
-                except BaseException:
-                    slot.release()
-                    raise
+                c = window.submit_rows(call, self.arena, self.offsets[chunk],
+                                       self.lengths[chunk], tag=chunk)
+                program.note_geometry(c.slot.B, c.slot.L)
                 _count("fused_dispatch_total")
-                xprof.note_dispatch(fut, "fused", f"{B}x{L}",
-                                    slot.pack_t0, slot.pack_dur)
                 # loongxprof device-memory ledger: while this chunk is in
                 # flight its inter-stage columns live device-side (that
                 # residency is the whole point of fusion) — accounted at
                 # the input-bytes proxy the plane budget already uses,
-                # credited back when the chunk settles
-                mem_note_alloc("resident_columns", batch.rows.nbytes)
-                if lane is not None:
-                    lane.note_pack(B, batch.n_real)
-                    lane.note_dispatch(batch.rows.nbytes)
-                self._pending.append((chunk, batch, slot, fut, lane))
+                # credited back when the chunk settles (_free_resident)
+                mem_note_alloc("resident_columns", c.nbytes)
         except BaseException:
             # a failed pack/submit must not strand the budget, ring slots
             # or lane accounting held by already-submitted chunks
-            for _c, b, slot, fut, ln in self._pending:
-                fut.release()
-                mem_note_free("resident_columns", b.rows.nbytes)
-                if ln is not None:
-                    ln.note_done(b.rows.nbytes)
-                    ln.breaker.on_inconclusive()
-                slot.release()
-            self._pending.clear()
+            window.abandon()
             raise
         return self
 
-    def _drain_if_pending(self) -> bool:
-        if not self._pending:
-            return False
-        self._drain_one()
-        return True
-
     # -- materialisation ----------------------------------------------------
 
-    def _drain_one(self) -> None:
-        chunk, batch, slot, fut, lane = self._pending.pop(0)
+    def _recover(self, c, exc) -> List[np.ndarray]:
+        """A chunk whose materialisation raised (the window's callback):
+        demote this ONE chunk to the per-stage path — its slot still holds
+        the packed rows.  A failure there too propagates (that path is the
+        proven one)."""
         program = self.program
-        t0 = time.perf_counter()
-        try:
-            try:
-                chaos.faultpoint(FP_FUSED_DISPATCH)
-                flat = fut.result()
-                if lane is not None:
-                    lane.breaker.on_success()
-            except ChipLaneFault:
-                # injected single-chip fault: feed the lane breaker and
-                # demote THIS chunk to the per-stage path on the base
-                # kernels — the other chips' lanes never notice
-                fut.release()
-                lane.breaker.on_failure()
-                lane.note_fault()
-                lane.note_respill(int(batch.n_real))
-                program.lane_respills += 1
-                _count("fused_lane_respill_total")
-                flat = self._staged_flat(batch, lane)
-            except chaos.ChaosFault:
-                # injected fused-dispatch (or h2d/submit) fault: the slot
-                # still holds the packed rows — demote this ONE chunk to
-                # the existing per-stage dispatch path, keep ring order
-                fut.release()
-                _note_demotion(program, "chaos fault at materialise")
-                flat = self._staged_flat(batch, lane)
-            except Exception as e:  # noqa: BLE001
-                # real kernel failure (Mosaic/mesh/runtime): cost must be
-                # dispatch count, never liveness — demote the chunk; a
-                # failure on the per-stage path too propagates (that path
-                # is the proven one)
-                fut.release()
-                if lane is not None:
-                    lane.breaker.on_failure()
-                    lane.note_fault()
-                _note_demotion(program, f"kernel failure: {e!r}")
-                flat = self._staged_flat(batch, lane)
-            self._assemble(chunk, batch, flat)
-            program.roundtrip_ms_total += (time.perf_counter() - t0) * 1e3
-        finally:
-            mem_note_free("resident_columns", batch.rows.nbytes)
-            if lane is not None:
-                lane.note_done(batch.rows.nbytes)
-            slot.release()
+        if isinstance(exc, ChipLaneFault):
+            # injected single-chip fault: the window feeds the lane
+            # breaker; the other chips' lanes never notice
+            program.lane_respills += 1
+            _count("fused_lane_respill_total")
+        elif isinstance(exc, chaos.ChaosFault):
+            # injected fused-dispatch (or h2d/submit) fault
+            _note_demotion(program, "chaos fault at materialise")
+        else:
+            # real kernel failure (Mosaic/mesh/runtime): cost must be
+            # dispatch count, never liveness
+            _note_demotion(program, f"kernel failure: {exc!r}")
+        return self._staged_flat(c.batch)
 
-    def _staged_flat(self, batch, lane) -> List[np.ndarray]:
-        """Per-stage re-run of a demoted chunk (already packed in its
-        slot).  The half-open probe outcome must reach the breaker: a
-        clean per-stage run closes it, a failing one is inconclusive."""
-        try:
-            outs = self.program.staged_run(batch.rows, batch.lengths)
-        except BaseException:
-            if lane is not None:
-                lane.breaker.on_inconclusive()
-            raise
-        if lane is not None:
-            lane.breaker.on_success()
+    def _deliver(self, c, flat) -> None:
+        self._assemble(c.tag, c.batch, flat)
+        self.program.roundtrip_ms_total += \
+            (time.perf_counter() - c.t_advance) * 1e3
+
+    def _staged_flat(self, batch) -> List[np.ndarray]:
+        """Per-stage run of a packed chunk, flattened like the program's
+        outputs."""
+        outs = self.program.staged_run(batch.rows, batch.lengths)
         return [a for tup in outs for a in tup]
 
     def _staged_into(self, chunk: np.ndarray) -> None:
         """Pre-dispatch demotion (lane OPEN): pack into a ring slot and
         run the per-stage path synchronously."""
-        ring = batch_ring()
-        d_len = self.lengths[chunk]
-        L = pick_length_bucket(int(d_len.max()) if len(d_len) else 1) \
-            or LENGTH_BUCKETS[-1]
-        B = pad_batch(len(chunk))
-        slot = ring.lease(B, L)
+        slot, batch = self._window.pack(self.arena, self.offsets[chunk],
+                                        self.lengths[chunk])
         try:
-            batch = slot.pack(self.arena, self.offsets[chunk], d_len)
-            flat = [a for tup in
-                    self.program.staged_run(batch.rows, batch.lengths)
-                    for a in tup]
-            self._assemble(chunk, batch, flat)
+            self._assemble(chunk, batch, self._staged_flat(batch))
         finally:
             slot.release()
 
@@ -925,21 +851,7 @@ class FusedDispatch:
     def result(self) -> FusedBatchResult:
         if self._result is not None:
             return self._result
-        try:
-            while self._pending:
-                self._drain_one()
-        except BaseException:
-            for _c, b, slot, fut, ln in self._pending:
-                try:
-                    fut.result()
-                except Exception:  # noqa: BLE001 — releasing, not consuming
-                    pass
-                if ln is not None:
-                    ln.note_done(b.rows.nbytes)
-                    ln.breaker.on_inconclusive()
-                slot.release()
-            self._pending.clear()
-            raise
+        self._window.drain()
         stages: List[Tuple[np.ndarray, ...]] = []
         for si, spec in enumerate(self.program.specs):
             if spec.kind == "struct_index":
@@ -949,7 +861,10 @@ class FusedDispatch:
         idle_now = self._plane.utilization()["idle_while_backlogged_ms"]
         self.program.idle_attr_ms += max(0.0, idle_now - self._idle_ms0)
         self._result = FusedBatchResult(stages, self._n)
-        self.arena = None
+        # drop references so the arena frees promptly; the window holds
+        # this object's bound methods, so letting go of it also undoes the
+        # cycle (no wait for the collector)
+        self.arena = self._window = None
         return self._result
 
     def _finish_struct(self, si: int) -> Tuple[np.ndarray, ...]:

@@ -33,7 +33,6 @@ partition, same aggregates, or the gate fails per row.
 from __future__ import annotations
 
 import math
-import os
 import re
 from dataclasses import dataclass
 from typing import Optional
@@ -108,18 +107,12 @@ def parse_values(arena: np.ndarray, val_offs: np.ndarray,
     scripts/agg_equivalence.py gate asserts against the reference loop.
     Everything else (exponents, inf, over-long, malformed) drops to the
     per-row reference path — the counted exception, not the steady
-    state.  Part of the BENCH_r11 device-substrate cliff fix: the per-row
-    float() loop priced every twin's fold, not the kernel
-    (``LOONG_AGG_PREP=0`` restores the r11 prep for the bench's
-    before/after)."""
+    state.  The per-row float() loop used to price every twin's fold, not
+    the kernel."""
     n = len(val_offs)
     values = np.zeros(n, dtype=np.float64)
     valid = np.zeros(n, dtype=bool)
     if n == 0:
-        return values, valid
-    if not _prep_opt_enabled():
-        _parse_values_rows(arena, val_offs, val_lens, range(n), values,
-                           valid)
         return values, valid
     offs = np.asarray(val_offs, dtype=np.int64)
     lens = np.asarray(val_lens, dtype=np.int64)
@@ -212,18 +205,11 @@ def _key_matrix(arena: np.ndarray, slots: np.ndarray,
     return np.concatenate(parts, axis=1), tuple(widths)
 
 
-def _prep_opt_enabled() -> bool:
-    """``LOONG_AGG_PREP=0`` restores the r11 host-prep path (per-row
-    float() parse + full-byte-matrix np.unique) — the bench's before/after
-    comparator for the device-substrate cliff fix."""
-    return os.environ.get("LOONG_AGG_PREP") != "0"
-
-
 def _first_seen_ids_exact(mat: np.ndarray):
     """Reference grouping: np.unique over the whole byte matrix is
     lexicographic, so remap through the argsort of first occurrences to
-    match the native assignment order.  This was the BENCH_r11 device
-    cliff's dominant term (~107 of 137 ms per 16 k-row fold)."""
+    match the native assignment order.  Sorting the full matrix is slow;
+    `_first_seen_ids` keeps it for the empty matrix and a hash collision."""
     _uniq, first_idx, inv = np.unique(mat, axis=0, return_index=True,
                                       return_inverse=True)
     order = np.argsort(first_idx, kind="stable")
@@ -244,8 +230,6 @@ def _first_seen_ids(mat: np.ndarray):
     64-bit collision, astronomically rare) falls back to the byte-exact
     reference, so the partition and the first-seen id order are always
     identical to the native assignment."""
-    if not _prep_opt_enabled():
-        return _first_seen_ids_exact(mat)
     n, W = mat.shape
     if n == 0:
         return _first_seen_ids_exact(mat)
@@ -280,8 +264,8 @@ class BatchFold:
     #: [G, W] uint8 key-matrix rows of the representatives, when the
     #: substrate already gathered them (numpy/device twins): the fold's
     #: hash-key bytes, reusable by the window merge as interning keys so
-    #: steady-state batches never rebuild per-group key tuples
-    #: (BENCH_r11 device-cliff satellite).  None on the native substrate.
+    #: steady-state batches never rebuild per-group key tuples.  None on
+    #: the native substrate.
     rep_key_blob: Optional[np.ndarray] = None
     #: per-key padded widths of ``rep_key_blob`` (see _key_matrix): blob
     #: rows are only comparable across batches together with these —
@@ -411,11 +395,11 @@ class SegmentReduceKernel:
         self.dispatch_count = 0
         # per-geometry staging buffers (the batch-slot idiom): the padded
         # value/segment/bucket arrays are reused across folds instead of
-        # re-allocated per batch — part of the BENCH_r11 device-cliff fix
-        # (host prep must not price the kernel).  Buffers are LEASED out
-        # of the pool under the lock and returned after the fold, so two
-        # pipelines sharing the module-global kernel never race one
-        # tuple yet still overlap their device round trips.
+        # re-allocated per batch (host prep must not price the kernel).
+        # Buffers are LEASED out of the pool under the lock and returned
+        # after the fold, so two pipelines sharing the module-global
+        # kernel never race one tuple yet still overlap their device
+        # round trips.
         import threading
         self._staging: dict = {}
         self._staging_lock = threading.Lock()

@@ -34,7 +34,7 @@ spans, so `processor_parse_json_tpu` parsed on the host's native plane in
 every pipeline until PR 27.  What the served path runs of this module is
 `_index_core`: the `json_fields` stage (json_fields.py) starts from its
 masks and goes on, on the device, to the value spans a fused run needs
-(docs/performance.md, "Structural-index parsing", says which pipelines
+(docs/device_plane.md, "Structural indexing", says which pipelines
 reach it).  `emit_delim_spans` is the delimiter mode's span step on the
 host; `processor_parse_delimiter_tpu` uses the native walk instead.
 """

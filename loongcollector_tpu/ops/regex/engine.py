@@ -1,9 +1,12 @@
 """Unified regex engine: tier dispatch + batch orchestration.
 
 The single entry point processors use.  Given a pattern, picks the execution
-tier (segment kernel / DFA kernel / CPU `re`), owns geometry bucketing and
-row packing, and returns arena-absolute capture spans so downstream stays
+tier (segment kernel / DFA kernel / CPU `re`), routes small batches to the
+host walker, and returns arena-absolute capture spans so downstream stays
 zero-copy (SURVEY.md §7 step 4: spans must index the ORIGINAL arena).
+Device chunks ride one `DeviceStream` window (ops/device_stream.py), which
+owns geometry bucketing, row packing, the in-flight ring and its releases;
+`PendingParse` supplies the kernel, the recovery and the span assembly.
 
 Oversize events (> largest length bucket) and CPU-tier patterns run through
 the Python `re` fallback with identical semantics — the reference's
@@ -22,12 +25,10 @@ import numpy as np
 import threading
 
 from ... import chaos
-from .. import chip_lanes, xprof
+from .. import chip_lanes
 from ..chip_lanes import ChipLaneFault, lane_gated
-from ..device_batch import (LENGTH_BUCKETS, MAX_BATCH, pack_rows, pad_batch,
+from ..device_batch import (LENGTH_BUCKETS, MAX_BATCH, pack_rows,
                             pick_length_bucket)
-from ..device_stream import (FP_RING_ADVANCE, auto_tuner, batch_ring,
-                             h2d_gated, stream_depth)
 from ..kernels.dfa_scan import DFAMatchKernel
 from ..kernels.field_extract import ExtractKernel
 from .dfa import DFAUnsupported, compile_dfa
@@ -69,10 +70,11 @@ _device_min_bytes_cached: Optional[int] = None
 _dispatch_probe_doc: Optional[dict] = None
 
 # rows that did NOT go to the device + counted device-kernel fallbacks,
-# cumulative for the life of the process (chip_smoke.py reads them through
-# /debug/status).  Rows that did go are the batch ring's ``real_rows``
-# (``streaming.ring``); lane respills are the lanes' own
-# ``respilled_events`` (``mesh.lanes``).
+# cumulative for the life of the process (the benchmark reads them through
+# /debug/status: ``device_row_share`` and the ``kernel_fallbacks`` check of
+# ``correct``; chip_smoke.py reads the same).  Rows that did go are the
+# batch ring's ``real_rows`` (``streaming.ring``); lane respills are the
+# lanes' own ``respilled_events`` (``mesh.lanes``).
 _route_lock = threading.Lock()
 _route_rows = {"host_walker": 0, "cpu_re": 0}
 _kernel_first_choice: Optional[str] = None
@@ -221,9 +223,9 @@ _ENGINE_CACHE_MAX = 512
 
 def clear_engine_cache() -> None:
     """Drop every cached engine.  Mesh width (``LOONG_MESH_CHIPS``), lane
-    routing and backend forces are resolved once per engine — tests and
-    the bench chips sweep clear the cache after changing them so the next
-    ``get_engine`` re-resolves against the new environment."""
+    routing and backend forces are resolved once per engine — tests clear
+    the cache after changing them so the next ``get_engine`` re-resolves
+    against the new environment."""
     with _engine_cache_lock:
         _engine_cache.clear()
 
@@ -499,7 +501,7 @@ class RegexEngine:
         (default ``LOONG_STREAM_DEPTH``) stay in flight — the ring advance
         (span return of chunk N-depth+1) overlaps packing/H2D of N+1 and
         device compute of N.  ``depth=1`` forces the synchronous
-        submit→materialise round trip (the bench sweep baseline)."""
+        submit→materialise round trip."""
         offsets = np.asarray(offsets, dtype=np.int64)
         lengths = np.asarray(lengths, dtype=np.int32)
         n = len(offsets)
@@ -636,15 +638,13 @@ class RegexEngine:
 class PendingParse:
     """A parse whose device chunks are in flight.
 
-    loongstream dispatch discipline: `dispatch()` packs each device chunk
-    into a leased batch-ring slot (pre-allocated fixed-geometry buffers —
-    no per-dispatch allocation on the H2D path) and submits it through the
-    DevicePlane, keeping at most ``depth`` chunks in flight: a full window
-    first advances the ring (materialises the OLDEST chunk), so the host
-    packs chunk N+1 while the device executes N and N-depth+1 returns
-    spans.  When the in-flight byte budget would block a submit, the
-    oldest owned future is drained first (never sleep in submit while
-    owning the budget you wait for — see DevicePlane.would_block).
+    The chunks ride one `DeviceStream` window (ops/device_stream.py), which
+    owns the ring discipline: at most ``depth`` chunks in flight, the
+    oldest materialised before the next is packed, this parse's own oldest
+    drained when the byte budget or the lane's share would block, and slot,
+    budget and lane bytes given back on every path.  What is this class's
+    own: which kernel a chunk is submitted on, what becomes of a chunk
+    whose materialisation failed, and where the spans are written.
     `result()` runs the CPU-tier fallback rows (host work, overlapping the
     device), then materialises remaining chunks in order.
 
@@ -653,7 +653,7 @@ class PendingParse:
     chunk a synchronous re-run — never the parse, never the ring order.  A
     Pallas/Mosaic failure at materialisation pins the engine to the XLA
     path and re-runs that chunk synchronously; failures on the XLA kernel
-    itself propagate.  Every path releases the chunk's slot and budget.
+    itself propagate.
 
     loongmesh: a lane-bound worker's chunks dispatch on its home chip
     (``device_plane.chip_lane.<i>`` chaos point, per-chip budget share,
@@ -664,8 +664,7 @@ class PendingParse:
     """
 
     __slots__ = ("engine", "arena", "offsets", "lengths", "ok", "cap_off",
-                 "cap_len", "cpu_idx", "_chunks_pending", "_result", "kern",
-                 "depth")
+                 "cap_len", "cpu_idx", "_window", "_result", "depth")
 
     def __init__(self, engine, arena, offsets, lengths, ok, cap_off, cap_len,
                  cpu_idx, depth=None):
@@ -677,18 +676,17 @@ class PendingParse:
         self.cap_off = cap_off
         self.cap_len = cap_len
         self.cpu_idx = cpu_idx
-        # [(chunk_idx, DeviceBatch, BatchSlot, DeviceFuture, kernel)]
-        self._chunks_pending = []
+        self._window = None             # opened by dispatch()
         self._result = None
-        self.kern = None
-        self.depth = max(1, depth if depth is not None else stream_depth())
+        self.depth = depth
 
     @classmethod
     def ready(cls, result: BatchParseResult) -> "PendingParse":
-        p = cls.__new__(cls)
+        """An already-materialised parse (host tiers, empty input): no
+        device chunk, no window."""
+        p = cls(None, None, None, None, result.ok, result.cap_off,
+                result.cap_len, ())
         p._result = result
-        p._chunks_pending = []
-        p.cpu_idx = ()
         return p
 
     @property
@@ -697,206 +695,109 @@ class PendingParse:
 
     def dispatch(self, device_idx: np.ndarray) -> None:
         from ..device_plane import DevicePlane
-        plane = DevicePlane.instance()
-        ring = batch_ring()
-        tuner = auto_tuner()
+        engine = self.engine
         # loongmesh: a lane-bound worker thread dispatches on its home
         # chip (source → worker → chip affinity); unbound dispatch shards
         # over the full mesh (or runs single-device)
         lane = chip_lanes.current_lane()
-        lane_count = chip_lanes.router().lane_count() if lane is not None \
-            else 0
-        self.kern = self.engine._device_kernel(lane)
-        _note_first_choice(self.kern)
-        max_bucket = LENGTH_BUCKETS[-1]
+        window = self._window = DevicePlane.instance().open_stream(
+            self.depth, program="regex", lane=lane,
+            recover=self._recover, deliver=self._deliver)
+        _note_first_choice(engine._device_kernel(lane))
         try:
             for chunk in _chunks(device_idx, MAX_BATCH):
-                if lane is not None and not lane.breaker.allow_probe():
-                    # lane breaker OPEN (or the half-open probe slot is
-                    # already in flight): this chip is sick — respill its
-                    # shard to host parsing.  Events still parse, in
-                    # order, synchronously (ledger-conserved); the other
-                    # chips' lanes keep running untouched.
-                    lane.note_respill(len(chunk))
-                    self.engine._host_parse_rows(
+                if not window.admit(len(chunk)):
+                    # this chip is sick: its shard parses on the host, in
+                    # order, synchronously (ledger-conserved)
+                    engine._host_parse_rows(
                         self.arena, self.offsets, self.lengths, chunk,
                         self.ok, self.cap_off, self.cap_len)
                     continue
-                # ring advance: a full window materialises its oldest chunk
-                # (span return of N-depth+1) before packing N+1
-                while len(self._chunks_pending) >= self.depth:
-                    self._drain_one()
-                # per-chip budget share: a lane holding more than its
-                # slice of the plane budget drains its own oldest chunk
-                # first — one slow chip backs up its own lane, not the
-                # whole plane (same never-sleep-owning-budget rule)
-                while lane is not None \
-                        and lane.over_share(plane, lane_count) \
-                        and self._chunks_pending:
-                    self._drain_one()
-                # re-read the kernel PER CHUNK: the drain above (or the
-                # budget-wait hook inside submit) may have pinned the
-                # engine to the XLA path mid-dispatch — each pending tuple
-                # must record the kernel its chunk was actually SUBMITTED
-                # on, or the materialise-time fallback check misfires.
+                # re-read the kernel PER CHUNK: the ring advance in admit
+                # (or the budget-wait hook inside submit) may have pinned
+                # the engine to the XLA path mid-dispatch — each chunk
+                # must record the kernel it was actually SUBMITTED on, or
+                # the materialise-time fallback check misfires.
                 # Buffer donation: a kernel offering a donating variant
                 # gets it on this path — each dispatch's inputs are
                 # transient staging copies, so XLA may reuse their HBM for
                 # the outputs instead of allocating per dispatch.
-                sub_kern = self.kern
-                call = getattr(sub_kern, "donated_call", None) or sub_kern
+                kern = engine._device_kernel(lane)
+                call = getattr(kern, "donated_call", None) or kern
                 if lane is not None:
                     # chip-lane chaos: dispatch passes this lane's fault
-                    # point; the bare kernel stays in the pending tuple so
-                    # recovery re-runs never re-fire the injection
+                    # point; the chunk keeps the bare kernel so recovery
+                    # re-runs never re-fire the injection
                     call = lane_gated(lane, call)
-                d_off = self.offsets[chunk]
-                d_len = self.lengths[chunk]
-                L = pick_length_bucket(int(d_len.max()) if len(d_len) else 1) \
-                    or max_bucket
-                lane_idx = lane.index if lane is not None else None
-                B = pad_batch(len(chunk),
-                              min_batch=tuner.min_batch_for(L, lane_idx),
-                              multiple_of=getattr(sub_kern,
-                                                  "batch_multiple", 1))
-                slot = ring.lease(B, L)
-                try:
-                    batch = slot.pack(self.arena, d_off, d_len,
-                                      lane=lane_idx)
-                    fut = plane.submit(h2d_gated(call),
-                                       (batch.rows, batch.lengths),
-                                       batch.rows.nbytes,
-                                       on_wait=self._drain_if_pending)
-                except BaseException:
-                    slot.release()
-                    raise
-                xprof.note_dispatch(fut, "regex", f"{B}x{L}",
-                                    slot.pack_t0, slot.pack_dur)
-                if lane is not None:
-                    lane.note_pack(B, batch.n_real)
-                    lane.note_dispatch(batch.rows.nbytes)
-                self._chunks_pending.append((chunk, batch, slot, fut,
-                                             sub_kern, lane))
+                window.submit_rows(call, self.arena, self.offsets[chunk],
+                                   self.lengths[chunk], tag=chunk,
+                                   kernel=kern)
         except BaseException:
-            # a failed pack/submit must not strand the budget (or the ring
-            # slots, or the lanes' in-flight accounting) the
-            # already-submitted futures hold (round-5 leak): force-release
-            # them — the caller abandons this parse, nobody will result()
-            # them
-            for _, b, slot, fut, _k, ln in self._chunks_pending:
-                fut.release()
-                if ln is not None:
-                    ln.note_done(b.rows.nbytes)
-                    # an abandoned chunk may hold the lane's half-open
-                    # probe slot — release it (no health sample) so the
-                    # lane is not forced to respill until probe_timeout_s
-                    ln.breaker.on_inconclusive()
-                slot.release()
-            self._chunks_pending.clear()
+            # a failed pack/submit must not strand what the chunks already
+            # submitted hold (round-5 leak): the caller abandons this
+            # parse, nobody will result() them
+            window.abandon()
             raise
 
-    def _drain_if_pending(self) -> bool:
-        """Budget-wait hook: materialise our oldest in-flight chunk so the
-        bytes we hold are released while we wait (DevicePlane._acquire's
-        deadlock-freedom rule)."""
-        if not self._chunks_pending:
-            return False
-        self._drain_one()
-        return True
+    def _recover(self, c, exc):
+        """A chunk whose materialisation raised (the window's callback):
+        its spans from another path, or raise."""
+        engine = self.engine
+        if isinstance(exc, ChipLaneFault):
+            # injected SINGLE-CHIP fault (device_plane.chip_lane.<i>): the
+            # window feeds the lane breaker — enough of these trip it OPEN
+            # and later chunks respill pre-dispatch; THIS chunk's shard
+            # parses on the host.  Events conserved, order kept (results
+            # land in the same rows), the other chips' lanes never notice.
+            engine._host_parse_rows(
+                self.arena, self.offsets, self.lengths, c.tag,
+                self.ok, self.cap_off, self.cap_len)
+            return None
+        kern = c.kernel
+        if not isinstance(exc, chaos.ChaosFault):
+            if kern is engine._segment_kernel or \
+                    getattr(engine, "_kernel_override", None) is not None:
+                raise exc
+            # Mosaic/mesh/chip runtime failure must cost throughput,
+            # never liveness: pin this engine off the failed path and
+            # re-run the chunk on the proven XLA kernel.  A lane kernel's
+            # REAL failure also counts against its chip's breaker (the
+            # window's report) — repeated ones trip the lane to host
+            # respill.  Production fault handling, never silent: every
+            # fallback is counted
+            # (``device.routing.kernel_fallbacks_total``), and the
+            # benchmark's ``correct`` and chip_smoke.py fail on a
+            # non-zero count.
+            global _kernel_fallbacks
+            with _route_lock:
+                _kernel_fallbacks += 1
+            from ...utils.logger import get_logger
+            get_logger("regex").exception(
+                "device kernel failed for %r; falling back to XLA path",
+                engine.pattern)
+            engine._device_kernel_failed(kern)
+            # lane dispatches keep their placement (the pin rebuilds a
+            # wrapper around the proven XLA kernel); unplaced dispatches
+            # fall to XLA directly
+            kern = engine._segment_kernel if self._window.lane is None \
+                else engine._device_kernel(self._window.lane)
+        # else an injected async-stage fault (h2d / ring_advance /
+        # submit): it must error only THIS chunk — the slot still holds
+        # the packed rows, so re-run on the same kernel and keep the ring
+        # moving in order
+        # the designed exception path: a synchronous recovery re-run
+        # loonglint: disable=host-bounce
+        return tuple(np.asarray(a)
+                     for a in kern(c.batch.rows, c.batch.lengths))
 
-    def _drain_one(self) -> None:
-        chunk, batch, slot, fut, sub_kern, lane = self._chunks_pending.pop(0)
-        try:
-            try:
-                chaos.faultpoint(FP_RING_ADVANCE)
-                k_ok, k_off, k_len = fut.result()
-                if lane is not None:
-                    # healthy materialisation on this chip: breaker sample
-                    # (re-closes a half-open lane when this was the probe)
-                    lane.breaker.on_success()
-            except ChipLaneFault:
-                # injected SINGLE-CHIP fault (device_plane.chip_lane.<i>):
-                # feed the lane breaker — enough of these trip it OPEN and
-                # later chunks respill pre-dispatch — and respill THIS
-                # chunk's shard to host parsing.  Events conserved, order
-                # kept (results land in the same slots), the other chips'
-                # lanes never notice.
-                fut.release()
-                lane.breaker.on_failure()
-                lane.note_fault()
-                lane.note_respill(int(batch.n_real))
-                self.engine._host_parse_rows(
-                    self.arena, self.offsets, self.lengths, chunk,
-                    self.ok, self.cap_off, self.cap_len)
-                return
-            except chaos.ChaosFault:
-                # injected async-stage fault (h2d / ring_advance / submit):
-                # it must error only THIS chunk — the slot still holds the
-                # packed rows, so re-run synchronously and keep the ring
-                # moving in order.  fut.release() is a no-op if result()
-                # already returned the budget.  The chunk may hold the
-                # lane's half-open probe slot: its outcome MUST reach the
-                # breaker (success on a clean re-run, inconclusive on a
-                # re-run failure) or the slot wedges and the whole lane
-                # respills for probe_timeout_s.
-                fut.release()
-                try:
-                    outs = sub_kern(batch.rows, batch.lengths)
-                except BaseException:
-                    if lane is not None:
-                        lane.breaker.on_inconclusive()
-                    raise
-                if lane is not None:
-                    lane.breaker.on_success()
-                # chaos-fault recovery re-run: the designed exception path
-                # loonglint: disable=host-bounce
-                k_ok, k_off, k_len = (np.asarray(a) for a in outs)
-            except Exception:  # noqa: BLE001
-                if sub_kern is self.engine._segment_kernel or \
-                        getattr(self.engine, "_kernel_override",
-                                None) is not None:
-                    raise
-                # Mosaic/mesh/chip runtime failure must cost throughput,
-                # never liveness: pin this engine off the failed path and
-                # re-run the chunk on the proven XLA kernel.  A lane
-                # kernel's REAL failure also counts against its chip's
-                # breaker — repeated ones trip the lane to host respill.
-                # Production fault handling, never silent: every fallback
-                # is counted (``device.routing.kernel_fallbacks_total``)
-                # and chip_smoke.py fails on a non-zero count.
-                global _kernel_fallbacks
-                with _route_lock:
-                    _kernel_fallbacks += 1
-                from ...utils.logger import get_logger
-                get_logger("regex").exception(
-                    "device kernel failed for %r; falling back to XLA path",
-                    self.engine.pattern)
-                if lane is not None:
-                    lane.breaker.on_failure()
-                    lane.note_fault()
-                self.engine._device_kernel_failed(sub_kern)
-                # lane dispatches keep their placement (the pop above
-                # plus base pinning rebuilds a wrapper around the proven
-                # XLA kernel); unplaced dispatches fall to XLA directly
-                self.kern = self.engine._segment_kernel if lane is None \
-                    else self.engine._device_kernel(lane)
-                # kernel-failure fallback re-run on the proven XLA path
-                # loonglint: disable=host-bounce
-                k_ok, k_off, k_len = (np.asarray(a) for a in
-                                      self.kern(batch.rows, batch.lengths))
-            k_ok = k_ok[: batch.n_real]
-            k_off = k_off[: batch.n_real]
-            k_len = k_len[: batch.n_real]
-            self.ok[chunk] = k_ok
-            # row-relative -> arena-absolute
-            self.cap_off[chunk] = k_off + batch.origins[: batch.n_real, None]
-            self.cap_len[chunk] = k_len
-        finally:
-            if lane is not None:
-                lane.note_done(batch.rows.nbytes)
-            # the slot may be repacked the moment it returns to the ring:
-            # release only after the spans were copied out above
-            slot.release()
+    def _deliver(self, c, outs) -> None:
+        k_ok, k_off, k_len = outs
+        chunk, batch = c.tag, c.batch
+        n = batch.n_real
+        self.ok[chunk] = k_ok[:n]
+        # row-relative -> arena-absolute
+        self.cap_off[chunk] = k_off[:n] + batch.origins[:n, None]
+        self.cap_len[chunk] = k_len[:n]
 
     def result(self) -> BatchParseResult:
         if self._result is not None:
@@ -907,24 +808,11 @@ class PendingParse:
             self.engine._cpu_fallback_rows(
                 self.arena, self.offsets, self.lengths, self.cpu_idx,
                 self.ok, self.cap_off, self.cap_len)
-        try:
-            while self._chunks_pending:
-                self._drain_one()
-        except BaseException:
-            # a failed chunk must not leak the others' in-flight budget —
-            # or their ring slots, or their lanes' in-flight accounting
-            for _, b, slot, fut, _k, ln in self._chunks_pending:
-                try:
-                    fut.result()
-                except Exception:  # noqa: BLE001 — releasing, not consuming
-                    pass
-                if ln is not None:
-                    ln.note_done(b.rows.nbytes)
-                    ln.breaker.on_inconclusive()   # see dispatch cleanup
-                slot.release()
-            self._chunks_pending.clear()
-            raise
+        if self._window is not None:
+            self._window.drain()
         self._result = BatchParseResult(self.ok, self.cap_off, self.cap_len)
-        # drop references so the arena/batches free promptly
-        self.arena = self.offsets = self.lengths = None
+        # drop references so the arena/batches free promptly; the window
+        # holds this object's bound methods, so letting go of it also
+        # undoes the cycle (no wait for the collector)
+        self.arena = self.offsets = self.lengths = self._window = None
         return self._result

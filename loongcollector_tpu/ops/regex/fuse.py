@@ -718,7 +718,7 @@ def note_demotion(pattern: str, reason: str, pipeline: str = "",
 
 
 def fusion_status() -> Dict[str, object]:
-    """The /debug/status `fusion` section and bench.py `extra.fusion`."""
+    """The /debug/status `fusion` section."""
     with _stats_lock:
         return {
             "compiles": _fusion_state["compiles"],

@@ -288,8 +288,8 @@ def leg_attrs(nbytes: int, xid: int) -> dict:
 def note_dispatch(fut, program: str, geometry: str,
                   pack_t0: Optional[float] = None,
                   pack_dur: Optional[float] = None) -> None:
-    """One-call convenience for the dispatch loops (PendingParse,
-    FusedDispatch, DeviceStream): attribute the future's dispatch to a
+    """One-call convenience for the dispatch window
+    (device_stream.DeviceStream.submit): attribute the future's dispatch to a
     program + geometry and attach the pack/H2D leg the caller timed —
     to the timeline as ``h2d`` and, the same reading, to the tracer as a
     ``device.pack`` span under the stage that dispatched.  Both planes

@@ -32,8 +32,7 @@ into the production dispatch path:
   queued and folded — off the hot path — into the process metrics
   (``mesh_matched_total`` / ``mesh_events_total`` / ``mesh_bytes_total``,
   labelled by chip count) plus per-chip row-occupancy accounting, all
-  surfaced in ``/debug/status`` (monitor/exposition.collect_status) and
-  ``bench.py`` ``extra.multichip``.
+  surfaced in ``/debug/status`` (monitor/exposition.collect_status).
 
 ``LOONG_MESH_CHIPS`` caps the mesh width (the bench chips=1/2/4/8 sweep's
 knob); per-chip *lanes* — affinity, breakers, chaos — live in

@@ -233,7 +233,7 @@ class CollectionPipeline:
             capacity = int(global_cfg.get("ProcessQueueCapacity", 20))
             circular = bool(global_cfg.get("CircularProcessQueue", False))
             # loongcolumn: byte watermark next to the group-count bound —
-            # 0 disables (docs/performance.md "Backlog-aware hand-off")
+            # 0 disables (pipeline/queue/bounded_queue.py says why)
             max_bytes = int(global_cfg.get("ProcessQueueMaxBytes",
                                            DEFAULT_MAX_BYTES))
             q = process_queue_manager.create_or_reuse_queue(

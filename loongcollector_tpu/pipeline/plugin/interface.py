@@ -102,7 +102,8 @@ class Processor(Plugin):
     #: the ProcessorInstance wrapper materializes per-event objects at
     #: this plugin's boundary (counted in models.churn_stats()) before
     #: calling it.  Declare it only when BOTH code paths are exercised by
-    #: the columnar-vs-dict equivalence gate (docs/performance.md).
+    #: the columnar-vs-dict equivalence gate
+    #: (scripts/columnar_equivalence.py).
     supports_columnar = False
 
     #: True ⇒ this plugin ONLY understands span columns (no row path at

@@ -30,7 +30,7 @@ LOW_WATERMARK_RATIO = 2 / 3
 # loongcolumn backlog-aware hand-off: the queue is bounded in BYTES as well
 # as groups.  A count-only bound lets large groups (512 KB reader chunks)
 # pile up ~15 MB of backlog, and at a few ms service time per group that IS
-# the 131 ms queue_wait plateau BENCH_r08 recorded — every group waited
+# the 131 ms queue_wait plateau an early run recorded — every group waited
 # capacity x service_time regardless of load.  The byte watermark keeps the
 # standing backlog shallow (the producer feedback-blocks earlier), so
 # queue_wait tracks the actual service rate; the count bound still guards

@@ -21,7 +21,6 @@ reference FSM per row (counted in `parse_fallback_rows_total`).
 
 from __future__ import annotations
 
-import os
 import re as _re
 from typing import Any, Dict, List
 
@@ -216,8 +215,6 @@ class ProcessorParseDelimiter(Processor):
         clean-subset emitter with a counted per-row FSM tier for deviant
         rows.  Returns False only when no structural tier applies (caller
         falls back to the per-row host path wholesale)."""
-        if os.environ.get("LOONG_STRUCT", "1") == "0":
-            return False
         from .. import native as _native
         F = len(self.keys)
         n = len(src.offsets)

@@ -29,19 +29,18 @@ values decode ONCE into a per-group side arena (appended to the source
 buffer in one allocation, never per event), unknown keys install from the
 CSR extras stream.  Rows the index cannot prove well-formed fall back to
 per-row `json.loads` — counted in `parse_fallback_rows_total` and alarmed
-via PARSE_FALLBACK_DEGRADED when sustained (docs/performance.md
+via PARSE_FALLBACK_DEGRADED when sustained (docs/device_plane.md
 "Structural-index parsing").  Values are raw source tokens
 (numbers/bools keep their source spelling); the fallback canonicalises
 via str()/json.dumps — the two differ only in number/whitespace spelling
-of unusual inputs.  ``LOONG_STRUCT=0`` disables the structural plane
-(the pre-loongstruct schema-discovery path; the bench's r09-style
-comparator).
+of unusual inputs.  Where the native library is absent the
+schema-discovery plane (one stable-schema pass, the rest per row) takes
+the group.
 """
 
 from __future__ import annotations
 
 import json
-import os
 import time
 from typing import Any, Dict
 
@@ -50,10 +49,6 @@ import numpy as np
 from ..models import ColumnarLogs, PipelineEventGroup
 from ..pipeline.plugin.interface import PluginContext, Processor
 from .common import RAW_LOG_KEY, extract_source
-
-
-def _struct_enabled() -> bool:
-    return os.environ.get("LOONG_STRUCT", "1") != "0"
 
 
 def _column(field_offs, field_lens, name: str, n: int):
@@ -76,7 +71,6 @@ class ProcessorParseJson(Processor):
         self.keep_source_on_success = False
         self.renamed_source_key = RAW_LOG_KEY
         self._pipeline = ""
-        self._struct = _struct_enabled()
         #: key signature (ops/kernels/json_fields.py) → key names
         self._sig_names: Dict[int, list] = {}
 
@@ -87,7 +81,6 @@ class ProcessorParseJson(Processor):
         self.keep_source_on_success = bool(config.get("KeepingSourceWhenParseSucceed", False))
         self.renamed_source_key = config.get("RenamedSourceKey", RAW_LOG_KEY)
         self._pipeline = getattr(context, "pipeline_name", "") or ""
-        self._struct = _struct_enabled()
         return True
 
     def process(self, group: PipelineEventGroup) -> None:
@@ -106,12 +99,12 @@ class ProcessorParseJson(Processor):
             keys = self._discover_schema(raw, src, todo)
             handled = False
             drift_rows = 0
-            if keys is not None and self._struct:
+            if keys is not None:
                 handled, drift_rows = self._process_struct(
                     group, src, raw, keys, ok, field_offs, field_lens)
             if not handled and keys is not None:
-                # r09-style plane (LOONG_STRUCT=0 / native unavailable):
-                # one stable-schema native pass, everything else per row
+                # the structural plane declined (native unavailable): one
+                # stable-schema native pass, everything else per row
                 from .. import native as _native
                 res = _native.json_extract(raw, src.offsets, src.lengths,
                                            keys)
@@ -141,13 +134,11 @@ class ProcessorParseJson(Processor):
         turns the packed rows into the value spans of their top-level
         members, publishes them as span columns, and mints a named capture
         for every key a later member binds.  Refused — the pipeline keeps
-        the host plane — when the structural plane is off, when a
-        processor ahead of the run may have minted the fields a later
-        member means, or when parsed rows also keep their source (a
-        filter on the renamed source key would then mean the raw line, not
-        a JSON member)."""
-        if not self._struct or ctx.user_stages_ahead \
-                or self.keep_source_on_success:
+        the host plane — when a processor ahead of the run may have minted
+        the fields a later member means, or when parsed rows also keep
+        their source (a filter on the renamed source key would then mean
+        the raw line, not a JSON member)."""
+        if ctx.user_stages_ahead or self.keep_source_on_success:
             return None
         if not ctx.bind_source(self.source_key):
             return None

@@ -73,7 +73,7 @@ BATCH_FLUSH_INTERVAL_S = 1.0
 
 # loongshard default: scale past one worker out of the box, but never spawn
 # more shards than the host can run (the reference default of 1 mirrored the
-# pre-shard engine; docs/performance.md)
+# pre-shard engine)
 DEFAULT_PROCESS_THREADS = max(2, min(4, os.cpu_count() or 2))
 
 flags.DEFINE_FLAG_INT32("process_thread_count",
@@ -526,7 +526,6 @@ class ProcessorRunner:
         # waits — the same agent-wide escalation the reference's
         # thread_count=1 default has, traded here for per-source ordering;
         # per-pipeline dispatch isolation is future work
-        # (docs/performance.md)
         while not inbox.put(item, timeout=1.0):
             if inbox.is_closed():
                 # forced shutdown (stop() closed the inboxes after the

@@ -58,13 +58,13 @@ LOONG_PROCESS_THREADS=4 python -m loongcollector_tpu.analysis \
 echo "== columnar equivalence gate (loongcolumn) =="
 # default pipeline chains through the columnar fast path AND the dict
 # path; any sink-payload byte difference (or any per-event object minted
-# on the columnar side) fails — docs/performance.md "Columnar event path"
+# on the columnar side) fails — docs/observability.md "Columnar event path"
 JAX_PLATFORMS=cpu python scripts/columnar_equivalence.py
 
 echo "== fused-DFA equivalence gate (loongfuse) =="
 # the fused multi-accept automaton must classify EXACTLY like per-pattern
 # `re` over the default grok set + multiline classics — any disagreement
-# means fusion would mis-gate extraction (docs/performance.md)
+# means fusion would mis-gate extraction
 JAX_PLATFORMS=cpu python scripts/fuse_equivalence.py
 
 echo "== fused-pipeline equivalence gate (loongresident) =="
@@ -78,14 +78,14 @@ echo "== structural-index equivalence gate (loongstruct) =="
 # the native/numpy/device structural bitmaps must be bit-identical, the
 # JSON plane must match Python `json` row-for-row, and quote-mode
 # delimiter parsing must reproduce the reference CSV FSM + python csv —
-# any span or byte diff fails (docs/performance.md)
+# any span or byte diff fails
 JAX_PLATFORMS=cpu python scripts/struct_equivalence.py
 
 echo "== aggregation equivalence gate (loongagg) =="
 # the native/numpy/device segment-reduce substrates must agree (numpy
 # bit-identical incl. f64 sums, device exact on selections/counts), and
 # the full rollup aggregator must emit byte-identical groups over the
-# columnar and per-event dict paths — docs/performance.md
+# columnar and per-event dict paths
 JAX_PLATFORMS=cpu python scripts/agg_equivalence.py
 
 echo "== reload-soak smoke (loongtenant) =="

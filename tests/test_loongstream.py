@@ -8,9 +8,12 @@ Covers the tentpole invariants:
     back up under dense traffic; flush deadline follows the
     device-idle-while-backlogged accounting; LOONG_STREAM_TUNER=0 pins
     the static policy;
-  * DeviceStream: strict submit-order results at depth 3, and a fault
-    mid-ring (device_plane.ring_advance / device_plane.h2d) errors ONLY
-    that batch — slot and budget released, no stall, no reorder;
+  * DeviceStream, the one dispatch window: strict submit-order results
+    at depth 3, and a fault mid-ring (device_plane.ring_advance /
+    device_plane.h2d) errors ONLY that batch — slot and budget released,
+    no stall, no reorder; under both of its owners (PendingParse,
+    FusedDispatch) a chunk whose recovery raises still returns slot,
+    budget and lane bytes and frees the lane's half-open probe;
   * engine streaming: byte-identical parse output depth=1 vs depth=3, and
     measured overlap ≥ 2.5× over the synchronous path at a 5 ms
     round-trip (2 ms wire each way + 1 ms serialized execution —
@@ -29,15 +32,19 @@ import time
 import numpy as np
 import pytest
 
-from loongcollector_tpu import chaos, trace
+from loongcollector_tpu import chaos, models, trace
 from loongcollector_tpu.chaos import ChaosPlan, FaultSpec
 from loongcollector_tpu.models import PipelineEventGroup, SourceBuffer
 from loongcollector_tpu.monitor import ledger
 from loongcollector_tpu.monitor.alarms import AlarmManager, AlarmType
+from loongcollector_tpu.ops import chip_lanes
 from loongcollector_tpu.ops import device_stream as ds
+from loongcollector_tpu.ops import fused_pipeline as fused_mod
 from loongcollector_tpu.ops.device_plane import (DevicePlane,
                                                  LatencyInjectedArray,
-                                                 LatencyInjectedKernel)
+                                                 LatencyInjectedKernel,
+                                                 mem_live_bytes,
+                                                 mem_reset_for_testing)
 from loongcollector_tpu.ops.regex import engine as engine_mod
 from loongcollector_tpu.ops.regex.engine import RegexEngine, get_engine
 from loongcollector_tpu.pipeline.pipeline_manager import (
@@ -286,6 +293,12 @@ class _StartsItsCopy(LatencyInjectedArray):
 
 
 class TestDeviceStream:
+    """The window on its own, without an owner's callbacks: `submit`,
+    `advance` and `drain` here are the ones every PendingParse and
+    FusedDispatch chunk goes through (`submit_rows` packs, then calls
+    `submit`), so the order, the overlap and the releases asserted below
+    are production's."""
+
     @pytest.mark.parametrize("prefetching", [False, True],
                              ids=["plain_outputs", "prefetching_outputs"])
     def test_results_in_submit_order_with_overlap(self, prefetching):
@@ -346,6 +359,140 @@ class TestDeviceStream:
                                               np.full(3, t) * 2)
         assert plane.inflight_bytes() == 0, "faulted batch leaked budget"
         assert ring.leased_total() == 0, "faulted batch leaked its slot"
+
+
+class _DiesFromCall:
+    """A device kernel that answers its first calls and raises from the
+    ``n``-th on: the dispatches succeed, the recovery re-run does not."""
+
+    def __init__(self, kernel, n):
+        self.kernel, self.n, self.calls = kernel, n, 0
+
+    def __call__(self, *args):
+        self.calls += 1
+        if self.calls >= self.n:
+            raise RuntimeError("recovery path is down too")
+        return self.kernel(*args)
+
+
+class TestWindowOwners:
+    """The one window under its two owners (PendingParse, FusedDispatch):
+    what a chunk holds returns whatever becomes of the chunk."""
+
+    @pytest.fixture()
+    def lane(self, monkeypatch):
+        monkeypatch.setenv("LOONG_NATIVE_T1", "0")
+        monkeypatch.setenv("LOONG_FUSED", "1")
+        monkeypatch.setenv("LOONG_LANE_TRIP_THRESHOLD", "1")
+        monkeypatch.setenv("LOONG_LANE_COOLDOWN_S", "0.05")
+        monkeypatch.setattr(engine_mod, "MAX_BATCH", 256)
+        monkeypatch.setattr(fused_mod, "MAX_BATCH", 256)
+        DevicePlane.reset_for_testing()
+        ds.reset_for_testing()
+        fused_mod.reset_for_testing()
+        mem_reset_for_testing()
+        columnar_was = models.set_columnar_enabled(True)
+        lane = chip_lanes.reset_for_testing().lane_for_worker(0)
+        chip_lanes.set_thread_lane(lane)
+        yield lane
+        chip_lanes.set_thread_lane(None)
+        models.set_columnar_enabled(columnar_was)
+        chip_lanes.reset_for_testing()
+        DevicePlane.reset_for_testing()
+        ds.reset_for_testing()
+        fused_mod.reset_for_testing()
+
+    def _regex_parse(self, _monkeypatch, n_rows, n_dispatched):
+        eng = RegexEngine(r"(\w+) (\d+)w")
+        eng.set_device_kernel_override(
+            _DiesFromCall(eng._segment_kernel, n_dispatched + 1))
+        arena, offsets, lengths = _arena(b"abc 123w", n_rows)
+        return ("device_plane.ring_advance",
+                lambda: eng.parse_batch_async(arena, offsets, lengths,
+                                              depth=3).result())
+
+    @staticmethod
+    def _fused_program():
+        from loongcollector_tpu.pipeline.pipeline import CollectionPipeline
+        p = CollectionPipeline()
+        assert p.init("window-owner", {
+            "inputs": [],
+            "processors": [
+                {"Type": "processor_parse_regex_tpu",
+                 "Regex": r"(\w+) (\d+)w", "Keys": ["word", "num"]},
+                {"Type": "processor_filter_native",
+                 "Include": {"num": r"1\d*"}}],
+            "flushers": [{"Type": "flusher_stdout"}]})
+        return p._fused_runs[0].program()
+
+    def _fused_parse(self, monkeypatch, n_rows, _n_dispatched):
+        program = self._fused_program()
+
+        def staged_is_down(rows, lengths):
+            raise RuntimeError("recovery path is down too")
+        monkeypatch.setattr(program, "staged_run", staged_is_down)
+        arena, offsets, lengths = _arena(b"abc 123w", n_rows)
+        return ("device_plane.fused_dispatch",
+                lambda: fused_mod.FusedDispatch(
+                    program, arena, offsets, lengths,
+                    depth=3).dispatch().result())
+
+    @pytest.mark.parametrize("lane_state,n_rows,n_dispatched", [
+        # three chunks in flight when the first one's recovery raises
+        # inside dispatch(): the other two are abandoned
+        ("closed", 1024, 3),
+        # the lane's one half-open probe chunk, its recovery raising
+        # inside result()
+        ("half_open", 200, 1)], ids=["three_pending", "half_open_probe"])
+    @pytest.mark.parametrize("owner", ["regex", "fused"])
+    def test_recovery_that_raises_returns_everything(
+            self, owner, lane_state, n_rows, n_dispatched, lane,
+            monkeypatch):
+        plane = DevicePlane.instance()
+        point, parse = getattr(self, f"_{owner}_parse")(
+            monkeypatch, n_rows, n_dispatched)
+        if lane_state == "half_open":
+            lane.breaker.on_failure()             # threshold 1: OPEN
+            time.sleep(0.06)                      # cooldown over: may probe
+        chaos.install(ChaosPlan(3, {point: FaultSpec(
+            prob=1.0, kinds=(chaos.ACTION_ERROR,), max_faults=1)}))
+        try:
+            with pytest.raises(RuntimeError, match="down too"):
+                parse()
+        finally:
+            chaos.uninstall()
+        assert plane.dispatched_total() == n_dispatched
+        assert plane.inflight_bytes() == 0, "a chunk kept its budget"
+        assert ds.batch_ring().leased_total() == 0, "a chunk kept its slot"
+        assert lane.inflight_bytes() == 0, "a chunk kept its lane bytes"
+        assert mem_live_bytes("resident_columns") == 0
+        # the probe slot: a chunk that held it and never reported would
+        # make the lane refuse every probe for probe_timeout_s
+        time.sleep(0.06)
+        assert lane.breaker.allow_probe(), "the lane's probe slot is wedged"
+        lane.breaker.on_inconclusive()
+
+    @pytest.mark.parametrize("owner", ["regex", "fused"])
+    def test_result_lets_go_of_the_window(self, owner, lane):
+        """The window holds its owner's bound methods; an owner that kept
+        the window after result() would be a reference cycle per group,
+        and its arena and buffers would wait for the collector."""
+        import gc
+        import weakref
+        arena, offsets, lengths = _arena(b"abc 123w", 600)
+        if owner == "regex":
+            pending = RegexEngine(r"(\w+) (\d+)w").parse_batch_async(
+                arena, offsets, lengths)
+        else:
+            pending = fused_mod.FusedDispatch(
+                self._fused_program(), arena, offsets, lengths).dispatch()
+        window = weakref.ref(pending._window)
+        gc.disable()
+        try:
+            pending.result()
+            assert window() is None
+        finally:
+            gc.enable()
 
 
 # ---------------------------------------------------------------------------
@@ -410,7 +557,7 @@ class TestEngineStreaming:
         synchronous path pays the full round trip per chunk; depth-3
         streaming overlaps the wire legs of neighbouring batches and is
         bounded by max((2w+x)/3, host pack) per chunk — ≥ 2.5× asserted,
-        ~3-3.5× nominal (the acceptance target recorded by bench.py)."""
+        ~3-3.5× nominal (the acceptance target)."""
         DevicePlane.reset_for_testing(budget_bytes=1 << 26)
         eng = RegexEngine(r"(\w+) (\d+)s")
         assert eng._segment_kernel is not None

@@ -20,6 +20,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 from loongcollector_tpu.analysis import (Finding, ModuleInfo, Program,
                                          load_allowlist, run_analysis)
 from loongcollector_tpu.analysis.checkers import all_checkers, checker_names
@@ -239,6 +241,50 @@ class TestAcquireRelease:
 
     def test_streaming_dispatch_shape_is_clean(self):
         assert scan(self.RING_LEASE_STREAMING, AcquireReleaseChecker()) == []
+
+    # the shape after the dispatch loops moved into the DeviceStream
+    # window: the owner loops over window.submit_rows, and every chunk
+    # already in the window holds budget, slot and lane bytes — the loop
+    # must sit in a try whose handler abandons (or drains) the window
+    WINDOW_LOOP_LEAK = """
+    class PendingParse:
+        def dispatch(self, device_idx):
+            window = self._window
+            for chunk in _chunks(device_idx, MAX_BATCH):
+                if not window.admit(len(chunk)):
+                    continue
+                window.submit_rows(self.call, self.arena,
+                                   self.offsets[chunk],
+                                   self.lengths[chunk], tag=chunk)
+    """
+
+    WINDOW_LOOP_ABANDONS = """
+    class PendingParse:
+        def dispatch(self, device_idx):
+            window = self._window
+            try:
+                for chunk in _chunks(device_idx, MAX_BATCH):
+                    if not window.admit(len(chunk)):
+                        continue
+                    window.submit_rows(self.call, self.arena,
+                                       self.offsets[chunk],
+                                       self.lengths[chunk], tag=chunk)
+            except BaseException:
+                window.abandon()
+                raise
+    """
+
+    @pytest.mark.parametrize("fixture,flagged", [
+        ("WINDOW_LOOP_LEAK", True), ("WINDOW_LOOP_ABANDONS", False)],
+        ids=["bare_loop_flagged", "abandon_is_clean"])
+    def test_window_submit_loop_needs_an_abandon(self, fixture, flagged):
+        findings = scan(getattr(self, fixture), AcquireReleaseChecker())
+        if not flagged:
+            assert findings == []
+            return
+        assert len(findings) == 1
+        assert "chunk put in flight" in findings[0].message
+        assert findings[0].symbol == "PendingParse.dispatch"
 
     def test_unrelated_lease_receiver_ignored(self):
         # `.lease()` on things that aren't rings (a DHCP client, say)
