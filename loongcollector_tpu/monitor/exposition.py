@@ -182,6 +182,7 @@ def collect_status() -> dict:
             with mgr._lock:
                 items = list(mgr._pipelines.items())
             pipelines = {}
+            flush = {}
             for name, p in items:
                 entry: dict = {"queue_key": p.process_queue_key}
                 if pqm is not None:
@@ -189,7 +190,19 @@ def collect_status() -> dict:
                     if q is not None:
                         entry["queue_depth"] = q.size()
                 pipelines[name] = entry
+                for f in p.flushers:
+                    probe = getattr(f.plugin, "flush_status", None)
+                    if probe is not None:
+                        flush[f"{name}/{f.plugin_id or f.plugin.name}"] = \
+                            probe()
             doc["pipelines"] = pipelines
+            if flush:
+                # the write-through sinks that flush on a sender thread of
+                # their own (flusher/flush_sender.py), by "<pipeline>/<plugin
+                # id>": batches handed over, batches the sender wrote,
+                # hand-overs that found the FIFO full and the seconds they
+                # waited, depth now and at most
+                doc["flush"] = flush
             # loongtenant: per-tenant generation / last-reload / device-
             # budget-share rows — the multi-tenant control-plane page
             # (reload latency distributions live in the
@@ -438,7 +451,7 @@ STATUS_SECTIONS = (
     "device", "streaming", "mesh", "fusion", "stage_fusion", "parse",
     "flight", "profiler", "recovery",
     "device_memory", "compile", "xprof",
-    "trace", "file_input", "startup",
+    "trace", "file_input", "flush", "startup",
 )
 
 

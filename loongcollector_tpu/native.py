@@ -106,7 +106,8 @@ def _load() -> Optional[ctypes.CDLL]:
             not hasattr(lib, "lct_t1_exec")
             or not hasattr(lib, "lct_ndjson_serialize")
             or not hasattr(lib, "lct_struct_index")
-            or not hasattr(lib, "lct_group_reduce")):
+            or not hasattr(lib, "lct_group_reduce")
+            or not hasattr(lib, "lct_ndjson_serialize_append")):
         # stale build predating the newest entry point: rebuild + reload
         _build()
         lib = _cdll(so_path)
@@ -146,6 +147,14 @@ def _load() -> Optional[ctypes.CDLL]:
             u8p, ctypes.c_int64, ctypes.c_int32,
             u8p, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,
             u8p, ctypes.c_int64, u8p, ctypes.c_int64]
+    if hasattr(lib, "lct_append_file"):
+        lib.lct_append_file.restype = ctypes.c_int64
+        lib.lct_append_file.argtypes = [ctypes.c_char_p, u8p,
+                                        ctypes.c_int64]
+    if hasattr(lib, "lct_ndjson_serialize_append"):
+        lib.lct_ndjson_serialize_append.restype = ctypes.c_int64
+        lib.lct_ndjson_serialize_append.argtypes = (
+            [ctypes.c_char_p] + lib.lct_ndjson_serialize.argtypes + [i64p])
     if hasattr(lib, "lct_struct_index"):
         lib.lct_struct_index.restype = None
         lib.lct_struct_index.argtypes = [
@@ -523,6 +532,39 @@ NDJSON_TS_EPOCH = 1
 NDJSON_TS_ISO8601 = 2
 
 
+def _ndjson_args(arena: np.ndarray, timestamps: np.ndarray,
+                 key_frags: tuple, field_offs: np.ndarray,
+                 field_lens: np.ndarray, prefix: bytes,
+                 prefix_members: bool, ts_frag: bytes, ts_mode: int,
+                 ts_first: bool, suffix: bytes, event_major: bool):
+    """(lct_ndjson_serialize's arguments before the output buffer, the
+    arrays they point into — to be kept alive over the call —, the output
+    capacity the call needs)."""
+    arena = np.ascontiguousarray(arena)
+    timestamps = np.ascontiguousarray(timestamps, dtype=np.int64)
+    field_offs = np.ascontiguousarray(field_offs, dtype=np.int32)
+    field_lens = np.ascontiguousarray(field_lens, dtype=np.int32)
+    frags_blob, frag_lens = _key_struct(key_frags)
+    F = len(key_frags)
+    n = len(timestamps)
+    sf, si = (1, F) if event_major else (n, 1)
+    prefix_b = np.frombuffer(prefix or b"\0", dtype=np.uint8)
+    ts_b = np.frombuffer(ts_frag or b"\0", dtype=np.uint8)
+    suffix_b = np.frombuffer(suffix or b"\0", dtype=np.uint8)
+    # worst case: every value byte expands 6x (\u00XX), plus per-row
+    # framing — mirrors the C row bound so -1 can only mean "unsupported"
+    cap = int(n * (len(prefix) + len(ts_frag) + 48 + int(frag_lens.sum())
+                   + 4 * F + len(suffix) + 2) + 6 * len(arena) + 64)
+    alive = (arena, timestamps, field_offs, field_lens, frags_blob,
+             frag_lens, prefix_b, ts_b, suffix_b)
+    return (_u8(arena), len(arena), _i64(timestamps), n, F,
+            _u8(frags_blob), _i32(frag_lens), _i32(field_offs),
+            _i32(field_lens), sf, si,
+            _u8(prefix_b), len(prefix), 1 if prefix_members else 0,
+            _u8(ts_b), len(ts_frag), ts_mode, 1 if ts_first else 0,
+            _u8(suffix_b), len(suffix)), alive, cap
+
+
 def ndjson_serialize(arena: np.ndarray, timestamps: np.ndarray,
                      key_frags: tuple, field_offs: np.ndarray,
                      field_lens: np.ndarray, prefix: bytes,
@@ -541,32 +583,71 @@ def ndjson_serialize(arena: np.ndarray, timestamps: np.ndarray,
     if lib is None or not hasattr(lib, "lct_ndjson_serialize") \
             or len(key_frags) > 64:
         return None
-    arena = np.ascontiguousarray(arena)
-    timestamps = np.ascontiguousarray(timestamps, dtype=np.int64)
-    field_offs = np.ascontiguousarray(field_offs, dtype=np.int32)
-    field_lens = np.ascontiguousarray(field_lens, dtype=np.int32)
-    frags_blob, frag_lens = _key_struct(key_frags)
-    F = len(key_frags)
-    n = len(timestamps)
-    sf, si = (1, F) if event_major else (n, 1)
-    prefix_b = np.frombuffer(prefix or b"\0", dtype=np.uint8)
-    ts_b = np.frombuffer(ts_frag or b"\0", dtype=np.uint8)
-    suffix_b = np.frombuffer(suffix or b"\0", dtype=np.uint8)
-    # worst case: every value byte expands 6x (\u00XX), plus per-row
-    # framing — mirrors the C row bound so -1 can only mean "unsupported"
-    cap = int(n * (len(prefix) + len(ts_frag) + 48 + int(frag_lens.sum())
-                   + 4 * F + len(suffix) + 2) + 6 * len(arena) + 64)
+    args, _alive, cap = _ndjson_args(
+        arena, timestamps, key_frags, field_offs, field_lens, prefix,
+        prefix_members, ts_frag, ts_mode, ts_first, suffix, event_major)
     out = np.empty(cap, dtype=np.uint8)
-    written = lib.lct_ndjson_serialize(
-        _u8(arena), len(arena), _i64(timestamps), n, F,
-        _u8(frags_blob), _i32(frag_lens), _i32(field_offs),
-        _i32(field_lens), sf, si,
-        _u8(prefix_b), len(prefix), 1 if prefix_members else 0,
-        _u8(ts_b), len(ts_frag), ts_mode, 1 if ts_first else 0,
-        _u8(suffix_b), len(suffix), _u8(out), cap)
+    written = lib.lct_ndjson_serialize(*args, _u8(out), cap)
     if written < 0:
         return None
     return memoryview(out)[:written]
+
+
+def ndjson_serialize_append(path: str, scratch: Optional[np.ndarray],
+                            arena: np.ndarray, timestamps: np.ndarray,
+                            key_frags: tuple, field_offs: np.ndarray,
+                            field_lens: np.ndarray, prefix: bytes,
+                            prefix_members: bool, ts_frag: bytes,
+                            ts_mode: int, ts_first: bool,
+                            suffix: bytes = b"\n"
+                            ) -> Tuple[Optional[Tuple[int, float, float]],
+                                       Optional[np.ndarray]]:
+    """`ndjson_serialize` and `append_file` of its rows in ONE native call
+    (the file sink's flush of a one-group batch: the sender thread lets go
+    of the interpreter lock once, not once for the assembly and three
+    times for the write).  The native side first ORs the arena's bytes and
+    declines an arena that holds one >= 0x80 — the caller then takes the
+    path that checks span by span.  ``scratch`` is the caller's own output
+    buffer from its last call (None at first); the one to keep comes back.
+
+    Returns ((bytes appended, seconds assembling, seconds writing),
+    scratch); (None, scratch) when declined or unavailable; raises OSError
+    when the write failed."""
+    lib = get_lib()
+    if lib is None or not hasattr(lib, "lct_ndjson_serialize_append") \
+            or len(key_frags) > 64:
+        return None, scratch
+    args, _alive, cap = _ndjson_args(
+        arena, timestamps, key_frags, field_offs, field_lens, prefix,
+        prefix_members, ts_frag, ts_mode, ts_first, suffix, False)
+    if scratch is None or len(scratch) < cap:
+        scratch = np.empty(cap, dtype=np.uint8)
+    phase_ns = np.zeros(2, dtype=np.int64)
+    rc = lib.lct_ndjson_serialize_append(
+        os.fsencode(path), *args, _u8(scratch), len(scratch),
+        _i64(phase_ns))
+    if rc <= -1000:
+        raise OSError(-rc - 1000, os.strerror(-rc - 1000), path)
+    if rc < 0:
+        return None, scratch
+    return (int(rc), phase_ns[0] / 1e9, phase_ns[1] / 1e9), scratch
+
+
+def append_file(path: str, data) -> bool:
+    """Append ``data`` (bytes or a memoryview) to the file at ``path``,
+    created if missing: open, write all of it, close — in one native call,
+    so the interpreter lock is let go of once for the three system calls
+    and not three times (the file sink's write, flusher/file.py).  False
+    when the library is unavailable (the caller writes through Python);
+    raises OSError when a call failed."""
+    lib = get_lib()
+    if lib is None or not hasattr(lib, "lct_append_file"):
+        return False
+    buf = np.frombuffer(data, dtype=np.uint8)
+    rc = lib.lct_append_file(os.fsencode(path), _u8(buf), len(buf))
+    if rc < 0:
+        raise OSError(-rc, os.strerror(-rc), path)
+    return True
 
 
 def _codec(fn_c, fn_bound, data: bytes) -> Optional[bytes]:

@@ -228,31 +228,39 @@ class Flusher(Plugin):
 
     def _serialize_and_write(self, groups: List[PipelineEventGroup],
                              serialize_fn, write_fn) -> bool:
-        """The batcher's flush of a write-through sink (file, stdout):
+        """The flush of a write-through sink (file, stdout):
         ``write_fn(serialize_fn(groups))`` under the terminal accounting
-        above, each half under a span of its own — ``flusher.serialize``
-        and ``flusher.write`` — children of ``flusher.send`` when the size
-        trigger fires on the worker, rootless when the batcher's timeout
-        thread fires."""
-        def run():
-            tracer = trace.active_tracer()
-            if tracer is None:
-                write_fn(serialize_fn(groups))
-                return
-            attrs = {"flusher": self.name, "groups": len(groups),
-                     "events": sum(len(g) for g in groups)}
-            none = contextlib.nullcontext()
-            sp = tracer.child_or_sampled("flusher", "flusher.serialize",
-                                         attrs)
-            with sp or none:
-                data = serialize_fn(groups)
-                attrs["nbytes"] = len(data)
-                if sp is not None:
-                    sp.set_attr("nbytes", len(data))
-            with tracer.child_or_sampled("flusher", "flusher.write",
-                                         attrs) or none:
-                write_fn(data)
-        return self._ledger_terminal_write(groups, run)
+        above."""
+        return self._ledger_terminal_write(
+            groups, lambda: self._serialize_then_write(
+                groups, serialize_fn, write_fn))
+
+    def _serialize_then_write(self, groups: List[PipelineEventGroup],
+                              serialize_fn, write_fn) -> None:
+        """``write_fn(serialize_fn(groups))``, each half under a span of
+        its own — ``flusher.serialize`` and ``flusher.write``.
+        flusher_file runs it on its sender thread
+        (flusher/flush_sender.py), where the two spans are always
+        rootless; flusher_stdout runs it inline in the batcher's flush,
+        where they are children of ``flusher.send`` when the count trigger
+        fires on the worker and rootless from the batcher's timeout
+        thread."""
+        tracer = trace.active_tracer()
+        if tracer is None:
+            write_fn(serialize_fn(groups))
+            return
+        attrs = {"flusher": self.name, "groups": len(groups),
+                 "events": sum(len(g) for g in groups)}
+        none = contextlib.nullcontext()
+        sp = tracer.child_or_sampled("flusher", "flusher.serialize", attrs)
+        with sp or none:
+            data = serialize_fn(groups)
+            attrs["nbytes"] = len(data)
+            if sp is not None:
+                sp.set_attr("nbytes", len(data))
+        with tracer.child_or_sampled("flusher", "flusher.write",
+                                     attrs) or none:
+            write_fn(data)
 
     def __init__(self) -> None:
         super().__init__()

@@ -127,16 +127,11 @@ def _spans_are_ascii(group: PipelineEventGroup, offs: np.ndarray,
     return bool((np.searchsorted(high, o) == np.searchsorted(high, e)).all())
 
 
-def native_group_rows(group: PipelineEventGroup,
-                      ts_key: Optional[str],
-                      ts_mode: int = TS_EPOCH,
-                      ts_first: bool = False,
-                      suffix: bytes = b"\n",
-                      head: bytes = b"",
-                      ) -> Optional[memoryview]:
-    """One group's NDJSON rows via the native assembler; None ⇒ the caller
-    must run the canonical dict path for this group.  ``head`` is prepended
-    to every row before the JSON object (ES bulk action lines)."""
+def _native_args(group: PipelineEventGroup, ts_key: Optional[str],
+                 ts_mode: int, ts_first: bool, head: bytes,
+                 check_ascii: bool):
+    """The arguments of native.ndjson_serialize from the arena on, for a
+    group the native assembler may take; None ⇒ the canonical dict path."""
     layout = _columnar_layout(group)
     if layout is None:
         return None
@@ -150,7 +145,7 @@ def native_group_rows(group: PipelineEventGroup,
         # a field overwrites the same-named tag IN PLACE in the dict path;
         # the flat fast layout cannot reproduce that ordering
         return None
-    if not _spans_are_ascii(group, offs, lens):
+    if check_ascii and not _spans_are_ascii(group, offs, lens):
         return None
     prefix = head + tag_prefix(tags)
     ts_frag = b""
@@ -158,13 +153,40 @@ def native_group_rows(group: PipelineEventGroup,
         ts_frag = (json.dumps(ts_key, ensure_ascii=False) + ": ").encode()
     else:
         ts_mode = TS_NONE
-    return native.ndjson_serialize(
-        np.frombuffer(group.source_buffer.raw, dtype=np.uint8,
-                      count=len(group.source_buffer.raw)),
-        np.asarray(tss, dtype=np.int64),
-        tuple(_field_frag(n) for n in names),
-        offs, lens, prefix, bool(tags), ts_frag, ts_mode, ts_first,
-        suffix=suffix)
+    return (np.frombuffer(group.source_buffer.raw, dtype=np.uint8,
+                          count=len(group.source_buffer.raw)),
+            np.asarray(tss, dtype=np.int64),
+            tuple(_field_frag(n) for n in names),
+            offs, lens, prefix, bool(tags), ts_frag, ts_mode, ts_first)
+
+
+def native_group_rows(group: PipelineEventGroup,
+                      ts_key: Optional[str],
+                      ts_mode: int = TS_EPOCH,
+                      ts_first: bool = False,
+                      suffix: bytes = b"\n",
+                      head: bytes = b"",
+                      ) -> Optional[memoryview]:
+    """One group's NDJSON rows via the native assembler; None ⇒ the caller
+    must run the canonical dict path for this group.  ``head`` is prepended
+    to every row before the JSON object (ES bulk action lines)."""
+    args = _native_args(group, ts_key, ts_mode, ts_first, head, True)
+    if args is None:
+        return None
+    return native.ndjson_serialize(*args, suffix=suffix)
+
+
+def native_group_append(group: PipelineEventGroup, path: str, scratch,
+                        ts_key: Optional[str], ts_mode: int = TS_EPOCH,
+                        ts_first: bool = False):
+    """`native_group_rows` appended to the file at ``path`` in the same
+    native call (native.ndjson_serialize_append, whose results these are);
+    the arena's bytes are checked there, all of them, not span by span —
+    a group it declines may still be one `native_group_rows` takes."""
+    args = _native_args(group, ts_key, ts_mode, ts_first, b"", False)
+    if args is None:
+        return None, scratch
+    return native.ndjson_serialize_append(path, scratch, *args)
 
 
 def ndjson_payload(groups: List[PipelineEventGroup],

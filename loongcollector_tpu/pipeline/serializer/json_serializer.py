@@ -20,26 +20,51 @@ from ...models import (EventType, LogEvent, MetricEvent, PipelineEventGroup,
 
 from ...models.events import metric_name_str as _name_str
 
-from .batch_json import TS_EPOCH, native_group_rows
+from .batch_json import TS_EPOCH, native_group_append, native_group_rows
 
 class JsonSerializer:
     name = "json"
+    _scratch = None     # append_group's output buffer
 
     def serialize_view(self, groups: List[PipelineEventGroup]):
-        """Serializer-interface hook: may return a memoryview when a
-        zero-copy path exists (see SLSEventGroupSerializer); here it is
-        just serialize()."""
-        return self.serialize(groups)
+        """Serializer-interface hook: the same bytes as serialize(), as a
+        memoryview over the native assembler's own buffer when the batch
+        came out as one part (a batch of one columnar group, the file
+        sink's 512 KiB case) — no join, so no second copy of the payload
+        made under the interpreter lock."""
+        parts = self._parts(groups)
+        return parts[0] if len(parts) == 1 else b"".join(parts)
+
+    def append_group(self, group: PipelineEventGroup, path: str):
+        """serialize([group]) appended to the file at ``path`` in one
+        native call: (bytes, seconds assembling, seconds writing), or None
+        when the group is not one the native assembler takes whole (the
+        caller then serializes and writes as for any batch).  Keeps its
+        output buffer between calls: one caller at a time — the file
+        sink's sender thread."""
+        if not self._columnar(group):
+            return None
+        done, self._scratch = native_group_append(
+            group, path, self._scratch, "__time__", ts_mode=TS_EPOCH,
+            ts_first=True)
+        return done
+
+    @staticmethod
+    def _columnar(group: PipelineEventGroup) -> bool:
+        # the raw-tail case (no parsed fields, just content spans) is
+        # columnar too — falling through would materialize every line
+        # into a Python event (loonglint hot-path-materialize)
+        cols = group.columns
+        return (cols is not None and not group._events
+                and bool(cols.fields or not cols.content_consumed))
 
     def serialize(self, groups: List[PipelineEventGroup]) -> bytes:
+        return b"".join(self._parts(groups))
+
+    def _parts(self, groups: List[PipelineEventGroup]) -> List:
         parts: List = []
         for group in groups:
-            cols = group.columns
-            # the raw-tail case (no parsed fields, just content spans) is
-            # columnar too — falling through would materialize every line
-            # into a Python event (loonglint hot-path-materialize)
-            columnar = (cols is not None and not group._events
-                        and (cols.fields or not cols.content_consumed))
+            columnar = self._columnar(group)
             if columnar:
                 # native zero-copy assembly; None ⇒ dict fallback (event
                 # groups, non-ASCII spans, key collisions)
@@ -58,7 +83,7 @@ class JsonSerializer:
                 self._serialize_events(group, tags, out)
             if out:
                 parts.append(("\n".join(out) + "\n").encode("utf-8"))
-        return b"".join(parts) if parts else b""
+        return parts
 
     def _serialize_events(self, group: PipelineEventGroup, tags: dict,
                           out: List[str]) -> None:

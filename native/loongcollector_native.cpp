@@ -11,9 +11,13 @@
 //
 // Build: make -C native   (g++ -O3 -shared -fPIC)
 
+#include <cerrno>
 #include <cstdint>
 #include <cstring>
+#include <ctime>
+#include <fcntl.h>
 #include <new>
+#include <unistd.h>
 #if defined(__x86_64__)
 #include <immintrin.h>
 #endif
@@ -460,6 +464,77 @@ int64_t lct_ndjson_serialize(
         p += suffix_len;
     }
     return p - out;
+}
+
+// ---------------------------------------------------------------------------
+// The file sink's write: append `len` bytes to the file at `path` (created
+// 0666 less the umask if missing, as open(path, "ab") does) — open, write
+// all of it, close.  One call, so one release of the interpreter lock for
+// the three system calls.  Returns len, or -errno of the call that failed.
+// ---------------------------------------------------------------------------
+int64_t lct_append_file(const char* path, const uint8_t* data, int64_t len) {
+    int fd;
+    do {
+        fd = open(path, O_WRONLY | O_APPEND | O_CREAT | O_CLOEXEC, 0666);
+    } while (fd < 0 && errno == EINTR);
+    if (fd < 0) return -static_cast<int64_t>(errno);
+    int64_t done = 0;
+    while (done < len) {
+        ssize_t w = write(fd, data + done, static_cast<size_t>(len - done));
+        if (w < 0) {
+            if (errno == EINTR) continue;
+            int64_t e = errno;
+            close(fd);
+            return -e;
+        }
+        done += w;
+    }
+    if (close(fd) != 0) return -static_cast<int64_t>(errno);
+    return done;
+}
+
+// ---------------------------------------------------------------------------
+// The file sink's flush of one columnar group in one call, so that the
+// sink's sender thread lets go of the interpreter lock once per batch:
+// an OR over the arena (a byte >= 0x80 anywhere sends the caller to the
+// path that checks span by span: -2), lct_ndjson_serialize into `out`,
+// lct_append_file of what it wrote.  phase_ns[0], [1]: nanoseconds spent
+// assembling and writing (the spans flusher.serialize / flusher.write).
+// Returns the bytes appended; -1 as lct_ndjson_serialize; -2 above;
+// -(1000 + errno) when a system call of the write failed.
+// ---------------------------------------------------------------------------
+int64_t lct_ndjson_serialize_append(
+        const char* path,
+        const uint8_t* arena, int64_t arena_len, const int64_t* timestamps,
+        int64_t n, int64_t F,
+        const uint8_t* frags_blob, const int32_t* frag_lens,
+        const int32_t* field_offs, const int32_t* field_lens,
+        int64_t sf, int64_t si,
+        const uint8_t* prefix, int64_t prefix_len, int32_t prefix_members,
+        const uint8_t* ts_frag, int64_t ts_frag_len,
+        int32_t ts_mode, int32_t ts_first,
+        const uint8_t* suffix, int64_t suffix_len,
+        uint8_t* out, int64_t out_cap, int64_t* phase_ns) {
+    auto now_ns = []() -> int64_t {
+        struct timespec ts;
+        clock_gettime(CLOCK_MONOTONIC, &ts);
+        return static_cast<int64_t>(ts.tv_sec) * 1000000000LL + ts.tv_nsec;
+    };
+    int64_t t0 = now_ns();
+    uint8_t any = 0;
+    for (int64_t i = 0; i < arena_len; ++i) any |= arena[i];
+    if (any & 0x80) return -2;
+    int64_t written = lct_ndjson_serialize(
+        arena, arena_len, timestamps, n, F, frags_blob, frag_lens,
+        field_offs, field_lens, sf, si, prefix, prefix_len, prefix_members,
+        ts_frag, ts_frag_len, ts_mode, ts_first, suffix, suffix_len,
+        out, out_cap);
+    if (written < 0) return -1;
+    int64_t t1 = now_ns();
+    int64_t rc = lct_append_file(path, out, written);
+    phase_ns[0] = t1 - t0;
+    phase_ns[1] = now_ns() - t1;
+    return rc < 0 ? rc - 1000 : written;
 }
 
 }  // extern "C"

@@ -132,6 +132,7 @@ def make_sites(tmp_dir: str):
             groups.append(reader.read())
         for g in groups:
             sink.send(g)
+        flusher.flush_all()            # the sender thread's spans too
         for _ in range(n):
             t_pack = (time.perf_counter()
                       if xprof.is_active() or trace.is_active() else None)

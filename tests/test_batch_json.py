@@ -68,6 +68,60 @@ class TestJsonSerializerGolden:
         assert fast == slow
         assert fast.count(b"\n") == len(LINES)
 
+    @pytest.mark.parametrize("groups", [
+        pytest.param(lambda: [_columnar_group(LINES, TAGS)], id="one_group"),
+        pytest.param(lambda: [_columnar_group(LINES, TAGS),
+                              _columnar_group(LINES[:1])], id="two_groups"),
+        pytest.param(lambda: [_columnar_group([b"caf\xc3\xa9-1 \xff"])],
+                     id="dict_path"),
+        pytest.param(lambda: [], id="none"),
+    ])
+    def test_serialize_view_is_serialize_without_the_join(self, groups):
+        ser = JsonSerializer()
+        view = ser.serialize_view(groups())
+        assert bytes(view) == ser.serialize(groups())
+        # one native part comes back as the assembler's own buffer
+        assert isinstance(view, memoryview) == (len(groups()) == 1
+                                                and b"caf" not in bytes(view))
+
+    def test_append_group_writes_what_serialize_gives(self, tmp_path):
+        ser, path = JsonSerializer(), str(tmp_path / "sink.jsonl")
+        want = ser.serialize([_columnar_group(LINES, TAGS)])
+        for _ in range(2):
+            nbytes, ser_s, write_s = ser.append_group(
+                _columnar_group(LINES, TAGS), path)
+            assert nbytes == len(want) and ser_s > 0 and write_s > 0
+        scratch = ser._scratch
+        assert (tmp_path / "sink.jsonl").read_bytes() == want * 2
+        # a larger group grows the kept buffer, a smaller one reuses it
+        big = [b"w-%d %s" % (i, b"x" * 300) for i in range(400)]
+        assert ser.append_group(_columnar_group(big), path) is not None
+        assert len(ser._scratch) > len(scratch)
+        scratch = ser._scratch
+        assert ser.append_group(_columnar_group(LINES), path) is not None
+        assert ser._scratch is scratch
+        assert (tmp_path / "sink.jsonl").read_bytes() == (
+            want * 2 + ser.serialize([_columnar_group(big)])
+            + ser.serialize([_columnar_group(LINES)]))
+
+    @pytest.mark.parametrize("group", [
+        # a high byte anywhere in the arena: the span-by-span check and
+        # CPython's codec belong to the general path
+        pytest.param(lambda: _columnar_group([b"caf\xc3\xa9-1 /x"]),
+                     id="high_byte"),
+        # a field named like the timestamp key, a field named like a tag
+        pytest.param(lambda: _columnar_group(
+            LINES, keys=("__time__", "num", "rest")), id="ts_key_collides"),
+        pytest.param(lambda: _columnar_group(
+            LINES, tags=((b"word", b"t"),)), id="tag_collides"),
+        pytest.param(lambda: PipelineEventGroup(SourceBuffer(64)),
+                     id="not_columnar"),
+    ])
+    def test_append_group_declines_and_writes_nothing(self, tmp_path, group):
+        path = tmp_path / "sink.jsonl"
+        assert JsonSerializer().append_group(group(), str(path)) is None
+        assert not path.exists()
+
     def test_literal_golden(self):
         ser = JsonSerializer()
         out = bytes(ser.serialize([_columnar_group(LINES[:1], TAGS)]))

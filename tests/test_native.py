@@ -166,6 +166,38 @@ class TestNativeJsonExtract:
         assert ok[1]
 
 
+class TestAppendFile:
+    """The file sink's write as one native call: open(path, "ab"), write
+    all, close."""
+
+    @pytest.mark.parametrize("wrap", [bytes, memoryview, bytearray],
+                             ids=["bytes", "memoryview", "bytearray"])
+    def test_creates_then_appends(self, tmp_path, wrap):
+        path = tmp_path / "sink.jsonl"
+        assert native.append_file(str(path), wrap(b"one\n"))
+        assert native.append_file(str(path), wrap(b"two\n"))
+        assert native.append_file(str(path), wrap(b""))
+        assert path.read_bytes() == b"one\ntwo\n"
+
+    def test_a_numpy_view_is_written_whole(self, tmp_path):
+        payload = np.frombuffer(b"x" * (3 << 20), dtype=np.uint8)
+        assert native.append_file(str(tmp_path / "big"),
+                                  memoryview(payload)[: (3 << 20) - 7])
+        assert (tmp_path / "big").stat().st_size == (3 << 20) - 7
+
+    @pytest.mark.parametrize("target,exc", [
+        ("", IsADirectoryError), ("missing/dir/sink", FileNotFoundError)])
+    def test_a_failed_call_raises_its_errno(self, tmp_path, target, exc):
+        with pytest.raises(exc) as err:
+            native.append_file(str(tmp_path / target), b"x")
+        assert err.value.filename == str(tmp_path / target)
+
+    def test_no_library_no_write(self, tmp_path, monkeypatch):
+        monkeypatch.setattr(native, "get_lib", lambda: None)
+        assert native.append_file(str(tmp_path / "sink"), b"x") is False
+        assert not (tmp_path / "sink").exists()
+
+
 class TestLoadFailure:
     def test_a_failed_load_is_kept_and_not_retried(self, monkeypatch):
         """One build/load attempt per process: a failure raises on every

@@ -833,7 +833,11 @@ def _flusher(tmp_path, **config):
 
 class TestSinkSpans:
     @pytest.mark.parametrize("name", ["flusher.serialize", "flusher.write"])
-    def test_size_triggered_flush_nests_under_send(self, tmp_path, name):
+    def test_size_triggered_flush_is_rootless_on_the_sender(self, tmp_path,
+                                                            name):
+        # the worker's share of a size-triggered flush is the hand-over
+        # (`flusher.enqueue` under `flusher.send`); serialize and write
+        # run on the sink's sender thread, under no span of the worker's
         f, inst = _flusher(tmp_path, MinSizeBytes=1)
         t = trace.enable()
         try:
@@ -841,8 +845,13 @@ class TestSinkSpans:
         finally:
             f.stop()
         (send,) = [s for s in t.finished_spans() if s.name == "flusher.send"]
+        (enq,) = [s for s in t.finished_spans()
+                  if s.name == "flusher.enqueue"]
+        assert enq.parent_id == send.span_id
+        assert enq.attrs == {"flusher": "flusher_file", "groups": 1,
+                             "events": 1}
         (sp,) = [s for s in t.finished_spans() if s.name == name]
-        assert sp.parent_id == send.span_id
+        assert sp.parent_id is None
         assert sp.attrs["groups"] == 1 and sp.attrs["events"] == 1
         assert sp.attrs["nbytes"] == os.path.getsize(tmp_path / "sink.jsonl")
 
