@@ -359,6 +359,20 @@ def collect_status() -> dict:
     except Exception:  # noqa: BLE001
         pass
     try:
+        # multiline assembly (processor/split_multiline.py): lines in,
+        # records out, device against host classification and the fate of
+        # held open records, by pipeline — absent until a pipeline with a
+        # Multiline block has seen a group
+        import sys as _sys
+        _ml = _sys.modules.get(
+            "loongcollector_tpu.processor.split_multiline")
+        if _ml is not None:
+            ml_doc = _ml.status()
+            if ml_doc:
+                doc["multiline"] = ml_doc
+    except Exception:  # noqa: BLE001
+        pass
+    try:
         from ..prof import flight as _flight
         rec = _flight.recorder()
         doc["flight"] = {"events": len(rec),
@@ -451,7 +465,7 @@ STATUS_SECTIONS = (
     "device", "streaming", "mesh", "fusion", "stage_fusion", "parse",
     "flight", "profiler", "recovery",
     "device_memory", "compile", "xprof",
-    "trace", "file_input", "flush", "startup",
+    "trace", "file_input", "flush", "startup", "multiline",
 )
 
 

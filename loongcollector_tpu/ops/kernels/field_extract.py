@@ -493,6 +493,19 @@ def build_extract_fn(program: SegmentProgram):
     return extract
 
 
+def build_match_fn(program: SegmentProgram):
+    """Returns jit-able f(rows u8 [B,L], lengths i32 [B]) -> (match i32 [B],):
+    the same walk as ``build_extract_fn`` with the capture spans left
+    behind — one result word a row is all that leaves the device."""
+    core = build_extract_core(program)
+
+    def match(rows: jnp.ndarray, lengths: jnp.ndarray):
+        ok, _off, _length = core(rows, lengths.astype(jnp.int32)[:, None])
+        return (ok[:, 0].astype(jnp.int32),)
+
+    return match
+
+
 _donation_cached = None
 
 
@@ -537,3 +550,23 @@ class ExtractKernel:
     @property
     def num_caps(self) -> int:
         return self.program.num_caps
+
+
+class MatchKernel:
+    """The full-match gate of one compiled program as a program of its own
+    (``jit_loong_line_classify`` on the profiler's ``XLA Modules`` line):
+    the multiline start-pattern classify, which sees physical lines where
+    the record extract sees merged records, and must be told from it in a
+    device trace.  XLA on every backend — the walk is the one the Pallas
+    extract falls back to, and with no capture columns to write there is
+    nothing for a hand-tiled kernel to save."""
+
+    family = "line_classify"
+
+    def __init__(self, program: SegmentProgram):
+        from ..compile_watch import watched_jit
+        self.program = program
+        self._fn = watched_jit(build_match_fn(program), self.family)
+
+    def __call__(self, rows, lengths):
+        return self._fn(rows, lengths)

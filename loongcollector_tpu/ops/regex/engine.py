@@ -30,7 +30,7 @@ from ..chip_lanes import ChipLaneFault, lane_gated
 from ..device_batch import (LENGTH_BUCKETS, MAX_BATCH, pack_rows,
                             pick_length_bucket)
 from ..kernels.dfa_scan import DFAMatchKernel
-from ..kernels.field_extract import ExtractKernel
+from ..kernels.field_extract import ExtractKernel, MatchKernel
 from .dfa import DFAUnsupported, compile_dfa
 from .program import (Alt, Optional_, PatternTier, Tier1Unsupported,
                       compile_tier1)
@@ -135,7 +135,13 @@ def _run_dispatch_probe() -> dict:
     row-reduction out, result materialised back to the host.  (A
     `jnp.zeros` input lives on-device already and hides the transfer.)
     Two payload sizes fit the affine cost t(n) = lat + n/bw, separating
-    fixed dispatch latency from effective host<->device bandwidth."""
+    fixed dispatch latency from effective host<->device bandwidth.  Each
+    size is read five times and the LEAST reading kept: a round trip has a
+    floor and no ceiling, a reading above the floor is somebody else's work
+    (the probe runs while the agent's threads start), and the one decision
+    it feeds holds for the whole process — the middle of three readings
+    put the crossover anywhere from 380 to 590 KB on one chip host, on
+    either side of the 512 KiB group (PERF.md section 7, PR 31)."""
     import jax
     import jax.numpy as jnp
     # not a kernel family: a once-per-process latency probe whose
@@ -149,11 +155,11 @@ def _run_dispatch_probe() -> dict:
         rows = np.zeros((B, L), np.uint8)
         np.asarray(g(rows))                 # compile + warm path
         samples = []
-        for _ in range(3):
+        for _ in range(5):
             t0 = time.perf_counter()
             np.asarray(g(rows))
             samples.append(time.perf_counter() - t0)
-        times.append(sorted(samples)[1])
+        times.append(min(samples))
     n0, n1 = (B * L for B, L in sizes)
     t0_, t1_ = times
     bw = (n1 - n0) / max(t1_ - t0_, 1e-9)
@@ -297,6 +303,7 @@ class RegexEngine:
         self._use_pallas: Optional[bool] = None
         self._sharded = None                # None=unresolved, False=off
         self._lane_kernels = {}             # chip index -> _LanePlacedKernel
+        self._match_kernels = {}            # None / chip index -> match gate
         self._native_exec = None            # host C++ walker, built lazily
         self._native_tried = False
         self._dfa_kernel: Optional[DFAMatchKernel] = None
@@ -426,6 +433,23 @@ class RegexEngine:
             return self._pallas_kernel
         return self._segment_kernel
 
+    def _match_kernel(self, lane=None):
+        """The SEGMENT-tier program as a full-match gate with one result
+        word a row (``MatchKernel``, a jit family of its own), placed on
+        the lane's chip for a lane-bound dispatch.  An unbound dispatch
+        runs it on the default device: a gate over physical lines has no
+        use for the mesh."""
+        if getattr(self, "_kernel_override", None) is not None:
+            return self._kernel_override
+        key = lane.index if lane is not None else None
+        k = self._match_kernels.get(key)
+        if k is None:
+            k = MatchKernel(self._segment_kernel.program)
+            if lane is not None:
+                k = _LanePlacedKernel(k, lane)
+            self._match_kernels[key] = k
+        return k
+
     def _host_walker(self):
         """The native C++ scalar walker for this program (degraded tier);
         None when the library is absent or the program exceeds its limits."""
@@ -483,9 +507,20 @@ class RegexEngine:
         """Full-match + captures for N events over a shared arena."""
         return self.parse_batch_async(arena, offsets, lengths).result()
 
-    def parse_batch_async(self, arena: np.ndarray, offsets: np.ndarray,
+    def match_batch_async(self, arena: np.ndarray, offsets: np.ndarray,
                           lengths: np.ndarray,
                           depth: Optional[int] = None) -> "PendingParse":
+        """``match_batch`` for a SEGMENT-tier pattern without the wait:
+        routed, chunked and windowed exactly as ``parse_batch_async``, on
+        the match gate (``_match_kernel``) instead of the extract kernel.
+        ``result().ok`` is the booleans; the capture columns are unset."""
+        return self.parse_batch_async(arena, offsets, lengths, depth,
+                                      pending_cls=PendingMatch)
+
+    def parse_batch_async(self, arena: np.ndarray, offsets: np.ndarray,
+                          lengths: np.ndarray,
+                          depth: Optional[int] = None,
+                          pending_cls=None) -> "PendingParse":
         """Dispatch the parse; `result()` on the returned handle materialises.
 
         The async device data plane (SURVEY §7 step 4): each device chunk is
@@ -544,8 +579,9 @@ class RegexEngine:
             cpu_idx = np.arange(n)
             device_idx = np.array([], dtype=np.int64)
 
-        pending = PendingParse(self, arena, offsets, lengths,
-                               ok, cap_off, cap_len, cpu_idx, depth=depth)
+        pending = (pending_cls or PendingParse)(
+            self, arena, offsets, lengths, ok, cap_off, cap_len, cpu_idx,
+            depth=depth)
         if len(device_idx):
             pending.dispatch(device_idx)
         return pending
@@ -666,6 +702,9 @@ class PendingParse:
     __slots__ = ("engine", "arena", "offsets", "lengths", "ok", "cap_off",
                  "cap_len", "cpu_idx", "_window", "_result", "depth")
 
+    #: the window's timeline tag (xprof dispatch decomposition)
+    program = "regex"
+
     def __init__(self, engine, arena, offsets, lengths, ok, cap_off, cap_len,
                  cpu_idx, depth=None):
         self.engine = engine
@@ -701,8 +740,10 @@ class PendingParse:
         # over the full mesh (or runs single-device)
         lane = chip_lanes.current_lane()
         window = self._window = DevicePlane.instance().open_stream(
-            self.depth, program="regex", lane=lane,
+            self.depth, program=self.program, lane=lane,
             recover=self._recover, deliver=self._deliver)
+        # the extract path's first choice is what routing reports, whoever
+        # dispatches first (the match gate has one kernel, nothing to choose)
         _note_first_choice(engine._device_kernel(lane))
         try:
             for chunk in _chunks(device_idx, MAX_BATCH):
@@ -722,7 +763,7 @@ class PendingParse:
                 # gets it on this path — each dispatch's inputs are
                 # transient staging copies, so XLA may reuse their HBM for
                 # the outputs instead of allocating per dispatch.
-                kern = engine._device_kernel(lane)
+                kern = self._kernel_for(lane)
                 call = getattr(kern, "donated_call", None) or kern
                 if lane is not None:
                     # chip-lane chaos: dispatch passes this lane's fault
@@ -738,6 +779,10 @@ class PendingParse:
             # parse, nobody will result() them
             window.abandon()
             raise
+
+    def _kernel_for(self, lane):
+        """The kernel a chunk is submitted on (read per chunk)."""
+        return self.engine._device_kernel(lane)
 
     def _recover(self, c, exc):
         """A chunk whose materialisation raised (the window's callback):
@@ -816,3 +861,42 @@ class PendingParse:
         # undoes the cycle (no wait for the collector)
         self.arena = self.offsets = self.lengths = self._window = None
         return self._result
+
+
+class PendingMatch(PendingParse):
+    """A full-match gate whose device chunks are in flight: the
+    ``PendingParse`` window, routing and host tiers, with the match gate
+    (``RegexEngine._match_kernel``) as the kernel and one result word a
+    row to deliver.  The gate is plain XLA, so there is no other device
+    path to fall back to: an injected async-stage fault re-runs the chunk
+    on the same kernel, a chip-lane fault parses the shard on the host,
+    anything else propagates."""
+
+    __slots__ = ("calls",)
+
+    program = "line_classify"
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        #: (rows, width) of every chunk delivered: the shapes of the calls
+        self.calls = []
+
+    def _kernel_for(self, lane):
+        return self.engine._match_kernel(lane)
+
+    def _recover(self, c, exc):
+        if isinstance(exc, ChipLaneFault):
+            self.engine._host_parse_rows(
+                self.arena, self.offsets, self.lengths, c.tag,
+                self.ok, self.cap_off, self.cap_len)
+            return None
+        if not isinstance(exc, chaos.ChaosFault):
+            raise exc
+        # the designed exception path: a synchronous recovery re-run
+        # loonglint: disable=host-bounce
+        return tuple(np.asarray(a)
+                     for a in c.kernel(c.batch.rows, c.batch.lengths))
+
+    def _deliver(self, c, outs) -> None:
+        self.ok[c.tag] = outs[0][:c.batch.n_real] != 0
+        self.calls.append(c.batch.rows.shape)

@@ -334,8 +334,8 @@ class CollectionPipeline:
 
     def process(self, groups: List[PipelineEventGroup]) -> None:
         finish = self.process_begin(groups)
-        if finish is not None:
-            finish()
+        while finish is not None:
+            finish = finish()
 
     def process_begin(self, groups: List[PipelineEventGroup]):
         """Run the processor chain up to and including the first
@@ -344,9 +344,12 @@ class CollectionPipeline:
 
         Returns None when the chain ran to completion synchronously;
         otherwise a zero-arg continuation that materialises the device work
-        and runs the remaining processors — call it exactly once.  While the
-        continuation is outstanding the group counts as in-process for the
-        stop/drain barrier (wait_all_items_in_process_finished)."""
+        and walks the chain on.  The continuation returns None when the
+        chain is done, or itself again when a later stage left device work
+        in flight in its turn (a multiline classify ahead of a regex
+        extract): call it until it returns None, each continuation exactly
+        once.  While one is outstanding the group counts as in-process for
+        the stop/drain barrier (wait_all_items_in_process_finished)."""
         with self._in_process_zero:
             self._in_process_cnt += 1
         if ledger.is_on():
@@ -363,20 +366,28 @@ class CollectionPipeline:
             return None
 
         def finish():
+            nonlocal cont
             try:
-                cont()
-            finally:
+                cont = cont()
+            except BaseException:
                 self._exit_process()
+                raise
+            if cont is None:
+                self._exit_process()
+                return None
+            return finish
         return finish
 
     def _walk_chain(self, groups: List[PipelineEventGroup], i: int,
                     allow_async: bool):
         """Index-walk the processor chain from ``i``.  A fused run
         (loongresident) executes as ONE async stage; with ``allow_async``
-        the first stage that leaves device work in flight returns a
-        continuation (the runner's overlap window), which finishes that
-        stage and walks the REST of the chain inline — exactly the old
-        single-async-stage contract, now fusion-aware on both legs."""
+        a stage that leaves device work in flight returns a continuation
+        (the runner's overlap window), which finishes that stage and walks
+        on from the next — so a chain that holds a second device stage
+        hands back a second continuation, and one that holds none runs its
+        rest inline as it always did.  ``allow_async=False`` runs every
+        stage to completion here."""
         chain = self.inner_processors + self.processors
         while i < len(chain):
             run = self._fused_by_head.get(i)
@@ -387,8 +398,8 @@ class CollectionPipeline:
                     if allow_async:
                         def finish_run(run=run, tokens=tokens, nxt=nxt):
                             run.complete(groups, tokens)
-                            self._walk_chain(groups, nxt,
-                                             allow_async=False)
+                            return self._walk_chain(groups, nxt,
+                                                    allow_async=True)
                         return finish_run
                     run.complete(groups, tokens)
                 i = nxt
@@ -412,7 +423,8 @@ class CollectionPipeline:
 
                 def finish(inst=inst, tokens=tokens, rest_idx=rest_idx):
                     inst.process_complete(groups, tokens)
-                    self._walk_chain(groups, rest_idx, allow_async=False)
+                    return self._walk_chain(groups, rest_idx,
+                                            allow_async=True)
                 return finish
             inst.process_complete(groups, tokens)
             i += 1

@@ -39,6 +39,17 @@ feedback-block the inputs.  Every worker registers a budget-relief hook
 bound to ITS lane, so a worker waiting for budget always completes the
 oldest overlapped group it owns (no-deadlock invariant, per lane; FIFO, so
 relief never reorders sends).
+
+A group may hold device work at more than one stage of its chain (PR 31:
+the multiline classify, then the record extract).  Its continuation then
+hands back a continuation: a ring step (``_step_oldest``) materialises the
+stage in flight and walks the chain on — to the send, and the group leaves
+the ring, or to the next device stage, and it keeps its place.  The ring
+stays in pop order and only its head is ever sent, so send order is pop
+order whatever the stages; when a group leaves from a later stage, the one
+behind it moves up a stage in the same turn (the staircase in
+``_advance_ring``), so every stage has a turn in flight before anyone waits
+for it.  A chain with one device stage walks as it always did.
 """
 
 from __future__ import annotations
@@ -136,16 +147,39 @@ def group_source_id(group: PipelineEventGroup) -> Optional[bytes]:
     return None
 
 
+class _LaneEntry:
+    """One group in a lane ring: its pending tuple (``finish`` is the
+    continuation of the stage in flight, None once the chain is done and
+    only the send is owed), when it entered, how many stages it has
+    advanced, and whether a step holds it right now."""
+
+    __slots__ = ("pending", "at", "stage", "out", "gone")
+
+    def __init__(self, pending, at: float):
+        self.pending = pending
+        self.at = at
+        self.stage = 0
+        self.out = False
+        self.gone = False
+
+
 class WorkerLane:
     """One worker's overlapped-dispatch ring (its device lane).
 
     loongstream: up to ``depth - 1`` groups' device work stays in flight
     per worker (``LOONG_STREAM_DEPTH``, default 3 ⇒ two pending groups
-    while a third packs/dispatches).  The ring is strict FIFO — ``take()``
-    removes and returns the OLDEST pending entry atomically, so the worker
-    loop and the DevicePlane budget-relief hook can race to complete it
-    and exactly one side wins, and completion (send) order always matches
-    dispatch (pop) order: per-source ordering survives any depth.
+    while a third packs/dispatches).  The ring is strict FIFO in pop
+    order.  A step checks the oldest free entry out (``check_out``), runs
+    it, and checks it back in: gone (sent, or dropped on an error), or in
+    its place with its next continuation.  The worker loop and the
+    DevicePlane budget-relief hook both step — the hook from inside a
+    step's own dispatch — so an entry is held by one step at a time, and
+    an entry stepped while an older one is still out must not be sent:
+    it parks with its chain done until it is the head.  Completion (send)
+    order therefore always matches dispatch (pop) order: per-source
+    ordering survives any depth and any number of device stages.
+    ``take()`` is the one-stage form of the same thing — remove and return
+    the OLDEST pending atomically.
     ``oldest_age()`` drives the auto-tuner's flush deadline — a pending
     group never rides the ring past it, bounding batch latency when the
     queue trickles."""
@@ -159,7 +193,7 @@ class WorkerLane:
         self.depth = depth if depth is not None else stream_depth()
         self.capacity = max(1, self.depth - 1)
         self._lock = threading.Lock()
-        self._pending: deque = deque()   # [(pending, enqueued_at)]
+        self._pending: deque = deque()   # [_LaneEntry], pop order
         # loongprof: overlap accounting — how long this lane held a group
         # whose device work was in flight, over the lane's lifetime
         self._t0 = time.perf_counter()
@@ -174,18 +208,56 @@ class WorkerLane:
             assert len(self._pending) < self.capacity, "lane ring full"
             if not self._pending:
                 self._held_since = now
-            self._pending.append((pending, now))
+            self._pending.append(_LaneEntry(pending, now))
+
+    def _remove(self, ent: _LaneEntry) -> None:
+        # caller holds the lock
+        self._pending.remove(ent)
+        ent.gone = True
+        if not self._pending:
+            self._held_s += time.perf_counter() - self._held_since
 
     def take(self):
         """Remove and return the OLDEST pending entry (FIFO — the ring
         advance), or None."""
         with self._lock:
-            if not self._pending:
+            if not self._pending or self._pending[0].out:
                 return None
-            p, _t = self._pending.popleft()
-            if not self._pending:
-                self._held_s += time.perf_counter() - self._held_since
-            return p
+            ent = self._pending[0]
+            self._remove(ent)
+            return ent.pending
+
+    def check_out(self):
+        """``(entry, hold_send)`` for the oldest entry a step may run, or
+        None: entries an outer step holds are passed over, and what sits
+        behind one may advance but not be sent (``hold_send``) — a parked
+        entry behind one has nothing left to do until it is the head."""
+        with self._lock:
+            behind = False
+            for ent in self._pending:
+                if ent.out:
+                    behind = True
+                    continue
+                if behind and ent.pending[2] is None:
+                    continue
+                ent.out = True
+                return ent, behind
+            return None
+
+    def check_in(self, ent: _LaneEntry, pending) -> None:
+        """End of a step: ``pending`` is what stays in the entry's place
+        (its next stage in flight, or parked), None when the group left."""
+        with self._lock:
+            ent.out = False
+            if pending is None:
+                self._remove(ent)
+            else:
+                ent.pending = pending
+                ent.stage += 1
+
+    def oldest_stage(self) -> Optional[int]:
+        with self._lock:
+            return self._pending[0].stage if self._pending else None
 
     def busy(self) -> bool:
         with self._lock:
@@ -205,7 +277,7 @@ class WorkerLane:
         with self._lock:
             if not self._pending:
                 return None
-            return time.perf_counter() - self._pending[0][1]
+            return time.perf_counter() - self._pending[0].at
 
     def overlap_ratio(self) -> float:
         """Fraction of this lane's lifetime spent with device work in
@@ -552,11 +624,7 @@ class ProcessorRunner:
         read from TLS at call time) so the hook always completes the owning
         worker's group even if relief plumbing ever runs off-thread."""
         def _relieve() -> bool:
-            p = lane.take()
-            if p is None:
-                return False
-            self._complete(p)
-            return True
+            return self._step_oldest(lane) is not None
         return _relieve
 
     def _advance_ring(self, lane: WorkerLane) -> None:
@@ -567,10 +635,21 @@ class ProcessorRunner:
         auto-tuner's flush deadline (latency backstop for trickle
         traffic)."""
         while lane.full():
-            self._complete_oldest(lane)
+            ent = self._step_oldest(lane)
+            if ent is None:
+                break
+            if ent.gone and ent.stage:
+                # the staircase: the group that left had advanced through
+                # ``stage`` device stages; the one behind it moves up one
+                # now, so that its next stage has this turn in flight and
+                # is not waited for the moment it was dispatched.  A chain
+                # with one device stage never comes here.
+                behind = lane.oldest_stage()
+                if behind is not None and behind < ent.stage:
+                    self._step_oldest(lane)
         age = lane.oldest_age()
         if age is not None and age > auto_tuner().flush_deadline_s():
-            self._complete_oldest(lane)
+            self._step_oldest(lane)
 
     def _run_single(self, worker_id: int) -> None:
         """thread_count == 1: the reference shape — pop the queue manager
@@ -591,7 +670,7 @@ class ProcessorRunner:
                     max_groups=self.run_max_groups)
                 if run is None:
                     had_item = False
-                    self._complete_oldest(lane)
+                    self._step_oldest(lane)
                     continue
                 if had_item or len(run[1]) > 1:
                     # sustained backlog on the single worker (consecutive
@@ -652,7 +731,7 @@ class ProcessorRunner:
                     timeout=0.0 if lane.busy() else 0.2,
                     max_groups=self.run_max_groups)
                 if run is None:
-                    self._complete_oldest(lane)
+                    self._step_oldest(lane)
                     if inbox.drained():
                         break
                     continue
@@ -809,24 +888,36 @@ class ProcessorRunner:
                 tracer.pop_current(sp)
             sp.end(status)
 
-    def _complete_oldest(self, lane: WorkerLane) -> None:
-        """Advance the lane ring one step: materialise + send its oldest
-        pending group (no-op when empty)."""
-        p = lane.take()
-        if p is not None:
-            self._complete(p)
+    def _step_oldest(self, lane: WorkerLane):
+        """Advance the lane ring one step: the oldest pending group's
+        stage in flight materialises and its chain walks on — to the send
+        (it leaves the ring) or to its next device stage (it keeps its
+        place).  Returns the entry stepped (``gone`` says which), None when
+        the ring had nothing to step."""
+        got = lane.check_out()
+        if got is None:
+            return None
+        ent, hold_send = got
+        kept = None
+        try:
+            kept = self._complete(ent.pending, hold_send)
+        finally:
+            lane.check_in(ent, kept)
+        return ent
 
     def _complete_lane(self, lane: WorkerLane) -> None:
         """Drain the WHOLE lane ring in FIFO order — required before any
         inline (host-tier) send of a possibly-same-source group, and on
         worker exit."""
-        while True:
-            p = lane.take()
-            if p is None:
-                return
-            self._complete(p)
+        while self._step_oldest(lane) is not None:
+            pass
 
-    def _complete(self, pending) -> None:
+    def _complete(self, pending, hold_send: bool = False):
+        """One step of a pending group.  Returns the pending that keeps its
+        place in the ring — with the next stage's continuation, or parked
+        (continuation None) when the chain is done but ``hold_send`` says
+        an older group has yet to be sent — or None once the group has
+        been sent (or dropped on an error)."""
         pipeline, groups, finish, sp, t0, lane_tag = pending
         tracer = trace.active_tracer()
         if sp is not None and tracer is not None:
@@ -849,13 +940,24 @@ class ProcessorRunner:
         prev_tenant = current_tenant()
         set_thread_tenant(pipeline.name or None)
         try:
-            try:
-                finish()
-            except Exception:  # noqa: BLE001
-                log.exception("pipeline %s processing failed", pipeline.name)
-                self._ledger_error_drop(pipeline, groups)
-                self._finish_group(sp, t0, "error")
-                return
+            if finish is not None:
+                try:
+                    # a continuation hands back the next one; anything
+                    # else it returns means the chain is done
+                    nxt = finish()
+                    finish = nxt if callable(nxt) else None
+                except Exception:  # noqa: BLE001
+                    log.exception("pipeline %s processing failed",
+                                  pipeline.name)
+                    self._ledger_error_drop(pipeline, groups)
+                    self._finish_group(sp, t0, "error")
+                    return None
+            if finish is not None or hold_send:
+                # still in the ring: detach its span from this thread, as
+                # _dispatch_one does, so the next group does not nest
+                if sp is not None and tracer is not None:
+                    tracer.pop_current(sp)
+                return pipeline, groups, finish, sp, t0, lane_tag
             if ledger.is_on():
                 # device work resolved: the group's spans are host-resident
                 # again — the submit→materialize gap is the ring occupancy
@@ -863,6 +965,7 @@ class ProcessorRunner:
                               sum(len(g) for g in groups), tag=lane_tag)
             self._send(pipeline, groups)
             self._finish_group(sp, t0, "ok")
+            return None
         finally:
             if led:
                 self._note_in_hand(-1)
@@ -882,5 +985,5 @@ class ProcessorRunner:
 
     def _process_one(self, key: int, group: PipelineEventGroup) -> None:
         pending = self._dispatch_one(key, group)
-        if pending is not None:
-            self._complete(pending)
+        while pending is not None:
+            pending = self._complete(pending)

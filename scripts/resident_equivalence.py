@@ -137,8 +137,8 @@ def run_family(name, lines, processors, fused: bool):
     plane = DevicePlane.instance()
     g = make_group(lines)
     fin = p.process_begin([g])
-    if fin is not None:
-        fin()
+    while fin is not None:
+        fin = fin()
     engaged = bool(p._fused_runs) and fused and plane.dispatched_total() \
         and any(r.program().dispatch_count for r in p._fused_runs)
     return digest(g), bool(p._fused_runs), engaged

@@ -105,17 +105,31 @@ class TestMultilineShutdownScenario:
                         "Multiline": {"StartPattern": r"\d{4}-.*"}}],
             "flushers": [{"Type": "flusher_file", "FilePath": str(sink)}],
         }))
-        logf.write_text("2024-01-02 ERROR boom\n  at Foo\n  at Bar\n")
+        # a closed record ahead of the open one: its arrival in the sink is
+        # the evidence that the agent is up and has read the file as far as
+        # the open record — a sleep says neither (``import jax`` alone can
+        # outlast one on a loaded host, and a SIGTERM before the reader has
+        # the file finds nothing to drain)
+        logf.write_text("2024-01-01 INFO up\n"
+                        "2024-01-02 ERROR boom\n  at Foo\n  at Bar\n")
         proc = _spawn(scenario / "conf", scenario / "data")
         try:
-            # the record is OPEN (no closing start line): nothing may ship
-            # before the flush timeout; SIGTERM drain must deliver it whole
-            time.sleep(3.0)
+            assert _wait_for(lambda: sink.exists() and sink.read_text()
+                             .count("\n") >= 1, timeout=90), \
+                "the closed record never reached the sink"
+            first = sink.read_text().splitlines()
+            # the second record is OPEN (no closing start line): it may not
+            # ship before the flush timeout; the SIGTERM drain must deliver
+            # it whole, and the process must be gone when _stop returns
+            assert len(first) == 1, first
         finally:
             out = _stop(proc)
-        assert sink.exists(), out[-1500:]
-        rec = json.loads(sink.read_text().splitlines()[0])
-        assert rec["content"] == "2024-01-02 ERROR boom\n  at Foo\n  at Bar"
+        assert proc.poll() is not None
+        recs = [json.loads(ln)["content"]
+                for ln in sink.read_text().splitlines()]
+        assert recs == ["2024-01-01 INFO up",
+                        "2024-01-02 ERROR boom\n  at Foo\n  at Bar"], \
+            (recs, out[-1500:])
 
 
 class TestHTTPIngestScenario:

@@ -136,8 +136,8 @@ def snapshot(group):
 def process_one(pipeline, lines):
     g = make_group(lines)
     fin = pipeline.process_begin([g])
-    if fin is not None:
-        fin()
+    while fin is not None:
+        fin = fin()
     return g
 
 
@@ -269,8 +269,8 @@ class TestSingleDispatch:
         ev = g.add_log_event(1700000002)
         ev.set_content(b"content", sb.copy_string(b"abc 123"))
         fin = p.process_begin([g])
-        if fin is not None:
-            fin()
+        while fin is not None:
+            fin = fin()
         # per-stage path applied the same semantics on the row group
         evs = g.events
         assert len(evs) == 1
