@@ -18,10 +18,11 @@ bodies:
   the next iteration dispatches again, so the pull bounces per
   iteration.
 
-A "device dispatch" is a call of ``donated_call`` / ``staged`` or of any
-callable whose name mentions ``kernel`` (``self._dfa_kernel(...)``,
-``sub_kern(...)`` …).  A single dispatch followed by one materialise is
-the NORMAL end-of-pipeline shape and is never flagged.
+A "device dispatch" is a call of ``donated_call`` / ``packed_call`` /
+``staged`` or of any callable whose name mentions ``kernel``
+(``self._dfa_kernel(...)``, ``sub_kern(...)`` …).  A single dispatch
+followed by one materialise is the NORMAL end-of-pipeline shape and is
+never flagged.
 
 Escape: ``# loonglint: disable=host-bounce`` with a justification — the
 designed fallback tiers carry it (the per-stage demotion path a faulted
@@ -44,7 +45,7 @@ _OPS_PREFIX = "loongcollector_tpu/ops/"
 _PROC_PREFIX = "loongcollector_tpu/processor/"
 
 _PULL_TAILS = {"asarray", "device_get", "block_until_ready", "result"}
-_DISPATCH_TAILS = {"donated_call", "staged"}
+_DISPATCH_TAILS = {"donated_call", "packed_call", "staged"}
 _DISPATCH_NAMES = {"kern", "sub_kern"}
 
 

@@ -327,13 +327,13 @@ def _budget_from_env() -> int:
         return _DEFAULT_BUDGET
 
 
-def _start_copy_back(outputs: Sequence) -> bool:
+def _start_copy_back(outputs: Sequence) -> int:
     """Start the device→host copy of every output that can start one (a
     `jax.Array`, sharded or not): the call only enqueues the transfer
     behind the program and returns, so it lands while the worker packs
     and dispatches the next groups.  Outputs without the method (numpy
-    from a host kernel, the latency-injection fakes) are skipped.  True
-    when every output started its copy.  A start that raises is dropped
+    from a host kernel, the latency-injection fakes) are skipped.
+    Returns how many copies started.  A start that raises is dropped
     here: `result()` surfaces the buffer's error at the consume point."""
     started = 0
     try:
@@ -343,8 +343,8 @@ def _start_copy_back(outputs: Sequence) -> bool:
                 start()
                 started += 1
     except Exception:  # noqa: BLE001 — fail at consume, not at submit
-        return False
-    return bool(outputs) and started == len(outputs)
+        pass
+    return started
 
 
 class DeviceFuture:
@@ -511,6 +511,8 @@ class DevicePlane:
         self._inflight = 0
         self._dispatched = 0
         self._prefetched = 0   # dispatches whose copy back began at submit
+        self._h2d_arrays = 0   # arrays handed to the dispatched calls
+        self._d2h_arrays = 0   # outputs whose copy back was started
         self._lock = threading.Lock()
         self._freed = threading.Condition(self._lock)
         self._closed = False
@@ -623,6 +625,10 @@ class DevicePlane:
                 "submit_queue_depth": self._waiters,
                 "dispatched_total": self._dispatched,
                 "d2h_prefetched_total": self._prefetched,
+                # what crosses per dispatch, in arrays: each one is a
+                # transfer (or a copy start and an np.asarray) of its own
+                "h2d_arrays_total": self._h2d_arrays,
+                "d2h_arrays_total": self._d2h_arrays,
                 "elapsed_s": elapsed,
             }
 
@@ -786,8 +792,11 @@ class DevicePlane:
                 outputs = kernel(*args)
                 if not isinstance(outputs, (tuple, list)):
                     outputs = (outputs,)
-                if _start_copy_back(outputs):
-                    with self._lock:
+                started = _start_copy_back(outputs)
+                with self._lock:
+                    self._h2d_arrays += len(args)
+                    self._d2h_arrays += started
+                    if outputs and started == len(outputs):
                         self._prefetched += 1
             finally:
                 if timed:

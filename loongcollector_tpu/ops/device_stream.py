@@ -51,6 +51,7 @@ from .. import trace
 from . import chip_lanes, xprof
 from .device_batch import (LENGTH_BUCKETS, MIN_BATCH, pack_rows, pad_batch,
                            pick_length_bucket)
+from .packed_io import packed_rows
 
 FP_RING_ADVANCE = chaos.register_point("device_plane.ring_advance")
 FP_H2D = chaos.register_point("device_plane.h2d")
@@ -170,21 +171,29 @@ class _GeometryStats:
 class BatchSlot:
     """One pre-allocated fixed-geometry batch buffer, leased from the ring.
 
+    What crosses to the device is ONE contiguous array, ``packed``:
+    ``B + ceil(4·B / L)`` rows of ``L`` bytes, the first ``B`` the rows
+    and the tail the ``B`` lengths as little-endian int32
+    (ops/packed_io.py).  ``rows`` and ``lengths`` are numpy views over
+    it, so a pack, a recovery re-run on ``(rows, lengths)`` and the byte
+    accounting see two arrays; ``origins`` never crosses and stays apart.
+
     ``pack()`` fills the slot's arrays from the arena (zero-copy reuse of
     the same host pages every generation) and returns the DeviceBatch view;
     ``release()`` returns the slot to its pool — exactly once, after the
     dispatch that used it has materialised (the kernel may alias the
     buffers until then)."""
 
-    __slots__ = ("_ring", "B", "L", "rows", "lengths", "origins", "_leased",
-                 "pack_t0", "pack_dur")
+    __slots__ = ("_ring", "B", "L", "packed", "rows", "lengths", "origins",
+                 "_leased", "pack_t0", "pack_dur")
 
     def __init__(self, ring: "BatchRing", B: int, L: int):
         self._ring = ring
         self.B = B
         self.L = L
-        self.rows = np.zeros((B, L), dtype=np.uint8)
-        self.lengths = np.zeros(B, dtype=np.int32)
+        self.packed = np.zeros((packed_rows(B, L), L), dtype=np.uint8)
+        self.rows = self.packed[:B]
+        self.lengths = self.packed[B:].reshape(-1)[:4 * B].view("<i4")
         self.origins = np.zeros(B, dtype=np.int32)
         self._leased = False
         # last pack()'s stopwatch (perf_counter start, dur s) — the
@@ -561,15 +570,18 @@ class Chunk:
     ``t_advance`` is stamped when its materialisation starts."""
 
     __slots__ = ("tag", "batch", "slot", "fut", "kernel", "nbytes",
-                 "t_advance")
+                 "unpack", "t_advance")
 
-    def __init__(self, tag, batch, slot, fut, kernel, nbytes):
+    def __init__(self, tag, batch, slot, fut, kernel, nbytes, unpack=None):
         self.tag = tag
         self.batch = batch
         self.slot = slot
         self.fut = fut
         self.kernel = kernel
         self.nbytes = nbytes
+        #: set for a chunk submitted on a packed entry: splits its one
+        #: output array back into the kernel's tuple (ops/packed_io.py)
+        self.unpack = unpack
         self.t_advance = 0.0
 
 
@@ -592,14 +604,18 @@ class DeviceStream:
       its own oldest — one slow chip backs up its own lane only.
     * ``submit_rows`` — geometry (length bucket, tuner floor, the kernel's
       batch multiple), ``ring.lease`` / ``slot.pack`` / ``plane.submit``
-      of the ``h2d_gated`` call.  When the budget would block, the
-      ``on_wait`` hook materialises this window's own oldest chunk — never
-      sleep in submit while owning the budget you wait for.  The slot
-      returns if pack or submit raises.
+      of the ``h2d_gated`` call: the kernel's packed entry on the slot's
+      one array where the callable offers one (``packed_call`` /
+      ``unpack``, ops/packed_io.py — one transfer in, one copy back),
+      else the callable on ``(rows, lengths)``.  When the budget would
+      block, the ``on_wait`` hook materialises this window's own oldest
+      chunk — never sleep in submit while owning the budget you wait for.
+      The slot returns if pack or submit raises.
     * ``advance`` — in submit order; each chunk's copy back started at its
       dispatch (``DevicePlane.submit``), so the advance finds the outputs
-      on the host.  One ``finally`` returns slot, budget and lane bytes,
-      whatever the chunk's fate.
+      on the host, and splits a packed chunk's one array back into the
+      kernel's tuple before ``deliver``.  One ``finally`` returns slot,
+      budget and lane bytes, whatever the chunk's fate.
     * ``abandon`` — the cleanup of a dispatch or a drain that failed:
       every pending chunk gives back budget, lane bytes, a held half-open
       probe (no health sample) and its slot.  The round-5 budget leak was
@@ -694,16 +710,24 @@ class DeviceStream:
                     lengths: np.ndarray, tag=None, kernel=None) -> Chunk:
         """Pack the rows into a ring slot and dispatch ``call`` on them.
         ``kernel`` is the bare kernel behind ``call`` (what a recovery
-        re-runs, and whose ``batch_multiple`` sizes the slot)."""
+        re-runs, and whose ``batch_multiple`` sizes the slot).  A
+        ``call`` that offers a packed entry is dispatched through it:
+        a gated, lane-placed, sharded or overriding callable offers
+        none and gets ``(rows, lengths)``."""
         slot, batch = self.pack(arena, offsets, lengths,
                                 getattr(kernel, "batch_multiple", 1))
-        return self.submit(call, (batch.rows, batch.lengths),
-                           batch.rows.nbytes, tag=tag, slot=slot,
-                           batch=batch, bare=kernel)
+        packed = getattr(call, "packed_call", None)
+        if packed is None:
+            return self.submit(call, (batch.rows, batch.lengths),
+                               batch.rows.nbytes, tag=tag, slot=slot,
+                               batch=batch, bare=kernel)
+        return self.submit(packed, (slot.packed,), batch.rows.nbytes,
+                           tag=tag, slot=slot, batch=batch,
+                           bare=kernel or call, unpack=call.unpack)
 
     def submit(self, kernel, args, nbytes: int, tag=None,
                slot: Optional[BatchSlot] = None, batch=None,
-               bare=None) -> Chunk:
+               bare=None, unpack=None) -> Chunk:
         """Dispatch under the plane budget, advancing first if the window
         is full.  When ``slot`` is given the stream owns its release (at
         materialisation, success or error — including a failure in the
@@ -717,7 +741,7 @@ class DeviceStream:
             if slot is not None:
                 slot.release()
             raise
-        chunk = Chunk(tag, batch, slot, fut, bare or kernel, nbytes)
+        chunk = Chunk(tag, batch, slot, fut, bare or kernel, nbytes, unpack)
         self._window.append(chunk)
         if slot is not None:
             geometry, t0, dur = f"{slot.B}x{slot.L}", slot.pack_t0, \
@@ -757,6 +781,8 @@ class DeviceStream:
             try:
                 chaos.faultpoint(self._advance_point)
                 out = chunk.fut.result()
+                if chunk.unpack is not None:
+                    out = chunk.unpack(out[0])
             except Exception as e:  # noqa: BLE001 — the owner's recovery
                 chunk.fut.release()
                 out = self._recovered(chunk, e)

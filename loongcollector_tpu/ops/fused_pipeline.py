@@ -24,8 +24,8 @@ device-capable stages:
   ``build_dfa_match_fn`` / ``build_dfa_span_match_fn`` /
   ``build_json_fields_fn``): inputs packed
   once, inter-stage columns stay in HBM, every stage's outputs
-  materialise together in one D2H.  ``donated_call`` mirrors the
-  loongstream donated-buffer contract.
+  materialise together in one D2H.  ``packed_call`` is the streaming
+  path's entry (ops/packed_io.py): one array in, one array out.
 
 * **FusedDispatch** — the dispatch handle riding the EXISTING machinery:
   batch-ring slots (no allocator churn), the DevicePlane byte budget with
@@ -79,6 +79,24 @@ ENV_CACHE = "LOONG_FUSED_CACHE"
 #: flat-output width per stage kind
 _STAGE_WIDTH = {"extract": 3, "scan": 1, "struct_index": 4, "keep": 1,
                 "json_fields": 6}
+
+
+def _stage_columns(spec) -> Optional[Tuple]:
+    """The forms (ops/packed_io.Columns) of one stage's outputs, or None
+    for a stage with an output that is no per-row 32-bit column
+    (``struct_index``'s packed words are as wide as the row)."""
+    from .packed_io import span_columns
+    if spec.kind == "extract":
+        return span_columns(spec.payload.num_caps)
+    if spec.kind == "json_fields":
+        return span_columns(spec.payload.num_caps) \
+            + (("i32", None), ("i32", None), ("i32", 2))
+    if spec.kind == "scan":
+        return (("u32", None),)
+    if spec.kind == "keep":
+        return (("bool", None),)
+    return None
+
 
 #: stage kinds that publish span columns: their first three outputs are
 #: ``(ok[B], off[B, C], len[B, C])`` with C = ``payload.num_caps``, and a
@@ -248,15 +266,28 @@ class FusedProgramKernel:
     shapes through the device_batch buckets and the tuner's per-program
     floors so each geometry compiles once.  ``dispatch_count`` counts
     fused dispatches — the single-dispatch-per-batch-slot acceptance
-    assertion reads it directly."""
+    assertion reads it directly.
+
+    Two jitted variants per geometry: ``(rows, lengths)`` → flat tuple
+    (``__call__``: lane-placed dispatches, a kernel override, the tests)
+    and the packed entry the streaming path and ``warm()`` run
+    (``packed_call`` / ``unpack``, ops/packed_io.py).  A program with a
+    ``struct_index`` stage has only the first: ``packed_call`` is None."""
 
     def __init__(self, specs: Sequence[StageSpec], signature: str):
         from .compile_watch import watched_jit
+        from .packed_io import packed_entry
         self.specs = list(specs)
         self.signature = signature
-        self._fn = watched_jit(build_fused_fn(self.specs), "fused_program")
-        self._fn_donated = None
-        self._donated_lock = threading.Lock()
+        fused = build_fused_fn(self.specs)
+        self._fn = watched_jit(fused, "fused_program")
+        self._fn_packed = self.packed_call = self.unpack = None
+        forms = [_stage_columns(spec) for spec in self.specs]
+        if None not in forms:
+            self._fn_packed, self.unpack = packed_entry(
+                fused, [f for stage in forms for f in stage],
+                "fused_program")
+            self.packed_call = self._packed_call
         self._lane_kernels: Dict[int, object] = {}
         self._kernel_override = None
         self.dispatch_count = 0
@@ -279,25 +310,9 @@ class FusedProgramKernel:
         self.dispatch_count += 1
         return self._fn(rows, lengths)
 
-    def donated_call(self, rows, lengths):
-        """Streaming-path variant: the
-        batch-ring staging buffers are transient, so their device copies
-        are donated and XLA reuses that HBM for the outputs."""
-        from .kernels.field_extract import donation_supported
-        if not donation_supported():
-            return self.__call__(rows, lengths)
+    def _packed_call(self, packed):
         self.dispatch_count += 1
-        return self._donated_fn()(rows, lengths)
-
-    def _donated_fn(self):
-        if self._fn_donated is None:
-            with self._donated_lock:
-                if self._fn_donated is None:
-                    from .compile_watch import watched_jit
-                    self._fn_donated = watched_jit(
-                        build_fused_fn(self.specs), "fused_program",
-                        donate_argnums=(0, 1))
-        return self._fn_donated
+        return self._fn_packed(packed)
 
     def set_kernel_override(self, kern) -> None:
         """Test/bench hook (mirrors RegexEngine.set_device_kernel_override):
@@ -372,16 +387,18 @@ class FusedProgramKernel:
     def warm(self) -> int:
         """AOT-compile the persisted geometries (restart warm start): the
         first data batch of a known shape then hits a ready executable.
-        Warms the DONATED variant where donation is real — that is the
+        Warms the packed entry where the program has one — that is the
         jit the steady-state dispatch path actually runs — else the
-        plain one.  Returns the number of geometries compiled."""
-        from .kernels.field_extract import donation_supported
-        fn = self._donated_fn() if donation_supported() else self._fn
+        tuple one.  Returns the number of geometries compiled."""
+        from .packed_io import packed_rows
         n = 0
         for B, L in sorted(self.geometries):
-            rows = np.zeros((B, L), dtype=np.uint8)
-            lens = np.zeros(B, dtype=np.int32)
-            fn(rows, lens)
+            if self._fn_packed is not None:
+                self._fn_packed(
+                    np.zeros((packed_rows(B, L), L), dtype=np.uint8))
+            else:
+                self._fn(np.zeros((B, L), dtype=np.uint8),
+                         np.zeros(B, dtype=np.int32))
             n += 1
         return n
 
@@ -759,10 +776,10 @@ class FusedDispatch:
                         _p.dispatch_count += 1
                         return _o(r, l)
                 elif lane is None:
-                    call = program.donated_call
+                    # the window takes the program's packed entry
+                    call = program
                 else:
-                    call = lane_gated(lane,
-                                      program.for_lane(lane).donated_call)
+                    call = lane_gated(lane, program.for_lane(lane))
                 c = window.submit_rows(call, self.arena, self.offsets[chunk],
                                        self.lengths[chunk], tag=chunk)
                 program.note_geometry(c.slot.B, c.slot.L)

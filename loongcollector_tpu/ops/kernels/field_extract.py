@@ -540,8 +540,14 @@ class ExtractKernel:
 
     def __init__(self, program: SegmentProgram):
         from ..compile_watch import watched_jit
+        from ..packed_io import packed_entry, span_columns
         self.program = program
-        self._fn = watched_jit(build_extract_fn(program), self.family)
+        extract = build_extract_fn(program)
+        self._fn = watched_jit(extract, self.family)
+        #: the streaming path's entry (ops/packed_io.py): one u8 array in,
+        #: one int32 [B, 1 + 2C] array out, ``unpack`` splits it back
+        self.packed_call, self.unpack = packed_entry(
+            extract, span_columns(program.num_caps), self.family)
 
     def __call__(self, rows, lengths) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         ok, off, length = self._fn(rows, lengths)
@@ -565,8 +571,13 @@ class MatchKernel:
 
     def __init__(self, program: SegmentProgram):
         from ..compile_watch import watched_jit
+        from ..packed_io import packed_entry
         self.program = program
-        self._fn = watched_jit(build_match_fn(program), self.family)
+        match = build_match_fn(program)
+        self._fn = watched_jit(match, self.family)
+        #: the streaming path's entry (ops/packed_io.py), ``[B, 1]`` out
+        self.packed_call, self.unpack = packed_entry(
+            match, (("i32", None),), self.family)
 
     def __call__(self, rows, lengths):
         return self._fn(rows, lengths)

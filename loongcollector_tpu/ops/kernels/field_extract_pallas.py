@@ -71,7 +71,7 @@ def _pick_block_rows(B: int, L: int, n_masks: int) -> int:
 
 def build_extract_fn_pallas(program: SegmentProgram,
                             interpret: bool = False):
-    """Returns f(rows u8 [B,L], lengths i32 [B]) ->
+    """Returns jit-able f(rows u8 [B,L], lengths i32 [B]) ->
     (ok bool [B], cap_off i32 [B,C], cap_len i32 [B,C]).
 
     Compiled Mosaic by default; ``interpret=True`` is for the CPU tests
@@ -117,9 +117,7 @@ def build_extract_fn_pallas(program: SegmentProgram,
         )(rows, lengths.astype(jnp.int32)[:, None])
         return ok2[:, 0] != 0, off, length
 
-    from ..compile_watch import watched_jit
-    return watched_jit(extract, PallasExtractKernel.family,
-                       static_argnums=())
+    return extract
 
 
 class PallasExtractKernel:
@@ -128,8 +126,16 @@ class PallasExtractKernel:
     family = "extract_pallas"
 
     def __init__(self, program: SegmentProgram, interpret: bool = False):
+        from ..compile_watch import watched_jit
+        from ..packed_io import packed_entry, span_columns
         self.program = program
-        self._fn = build_extract_fn_pallas(program, interpret=interpret)
+        extract = build_extract_fn_pallas(program, interpret=interpret)
+        self._fn = watched_jit(extract, self.family)
+        #: the streaming path's entry (ops/packed_io.py): the slice, the
+        #: bitcast and the concatenate are XLA operations around the same
+        #: Pallas call in the one module, ``unpack`` splits the result
+        self.packed_call, self.unpack = packed_entry(
+            extract, span_columns(program.num_caps), self.family)
 
     def __call__(self, rows, lengths
                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:

@@ -264,9 +264,9 @@ class _LanePlacedKernel:
     """A single-device kernel pinned to one chip lane (loongmesh): inputs
     are device_put onto the lane's chip, so the jitted step executes on
     that chip's stream — distinct workers drive distinct chips with no
-    collectives on the batch path.  Exposes the same ``donated_call``
-    protocol as the base kernels (the placed copies are transient staging
-    buffers, safe to donate)."""
+    collectives on the batch path.  Its own ``device_put`` of each input:
+    a placed kernel keeps ``(rows, lengths)`` → tuple and offers no
+    packed entry (ops/packed_io.py)."""
 
     __slots__ = ("base", "lane")
 
@@ -282,12 +282,6 @@ class _LanePlacedKernel:
     def __call__(self, rows, lengths):
         rows_d, lens_d = self._place(rows, lengths)
         return self.base(rows_d, lens_d)
-
-    def donated_call(self, rows, lengths):
-        rows_d, lens_d = self._place(rows, lengths)
-        don = getattr(self.base, "donated_call", None)
-        return don(rows_d, lens_d) if don is not None \
-            else self.base(rows_d, lens_d)
 
 
 class RegexEngine:
@@ -760,9 +754,10 @@ class PendingParse:
                 # must record the kernel it was actually SUBMITTED on, or
                 # the materialise-time fallback check misfires.
                 # Buffer donation: a kernel offering a donating variant
-                # gets it on this path — each dispatch's inputs are
-                # transient staging copies, so XLA may reuse their HBM for
-                # the outputs instead of allocating per dispatch.
+                # (the sharded kernel) gets it on this path — each
+                # dispatch's inputs are transient staging copies.  A bare
+                # single-device kernel rides as itself, and the window
+                # takes its packed entry (ops/packed_io.py).
                 kern = self._kernel_for(lane)
                 call = getattr(kern, "donated_call", None) or kern
                 if lane is not None:

@@ -14,6 +14,8 @@ Covers the tentpole invariants:
     no stall, no reorder; under both of its owners (PendingParse,
     FusedDispatch) a chunk whose recovery raises still returns slot,
     budget and lane bytes and frees the lane's half-open probe;
+    `submit_rows` rides the kernel's packed entry where it offers one
+    (one array each way a dispatch) and hands back the same tuple;
   * engine streaming: byte-identical parse output depth=1 vs depth=3, and
     measured overlap ≥ 2.5× over the synchronous path at a 5 ms
     round-trip (2 ms wire each way + 1 ms serialized execution —
@@ -360,6 +362,45 @@ class TestDeviceStream:
         assert plane.inflight_bytes() == 0, "faulted batch leaked budget"
         assert ring.leased_total() == 0, "faulted batch leaked its slot"
 
+    @pytest.mark.parametrize("entry", ["packed", "tuple"])
+    def test_submit_rows_fault_leaves_the_other_chunks_their_tuple(
+            self, entry):
+        """`submit_rows` on a real kernel: the packed entry where the
+        callable offers one, `(rows, lengths)` where it does not.  With
+        no owner to recover it, the faulted chunk comes back as its
+        error; every other chunk as the kernel's tuple, split back from
+        the one array a packed dispatch returns."""
+        from loongcollector_tpu.ops.kernels.field_extract import \
+            ExtractKernel
+        from loongcollector_tpu.ops.regex.program import compile_tier1
+        plane = DevicePlane.reset_for_testing(budget_bytes=1 << 22)
+        kern = ExtractKernel(compile_tier1(r"(\w+) (\d+)z"))
+        call = kern if entry == "packed" \
+            else (lambda rows, lengths: kern(rows, lengths))
+        chaos.install(ChaosPlan(7, {"device_plane.ring_advance": FaultSpec(
+            prob=1.0, kinds=(chaos.ACTION_ERROR,), after_hits=1,
+            max_faults=1)}))
+        stream = plane.open_stream(depth=3)
+        for i in range(4):
+            stream.submit_rows(call, *_arena(b"abc 123z", 5), tag=i,
+                               kernel=kern)
+        results = stream.drain()
+        chaos.uninstall()
+        assert [t for t, _ in results] == list(range(4))
+        assert [t for t, out in results
+                if isinstance(out, BaseException)] == [1]
+        for t, out in results:
+            if t != 1:
+                ok, off, length = out
+                assert np.asarray(ok)[:5].all()
+                np.testing.assert_array_equal(np.asarray(off)[0], [0, 4])
+                np.testing.assert_array_equal(np.asarray(length)[0], [3, 3])
+        u = plane.utilization()
+        assert (u["h2d_arrays_total"], u["d2h_arrays_total"]) \
+            == ((4, 4) if entry == "packed" else (8, 12))
+        assert plane.inflight_bytes() == 0
+        assert ds.batch_ring().leased_total() == 0
+
 
 class _DiesFromCall:
     """A device kernel that answers its first calls and raises from the
@@ -500,13 +541,22 @@ class TestWindowOwners:
 
 
 class TestEngineStreaming:
-    def test_byte_identical_depth1_vs_depth3(self, device_tier):
-        DevicePlane.reset_for_testing()
+    @pytest.mark.parametrize("entry", ["tuple_override", "packed"])
+    def test_byte_identical_depth1_vs_depth3(self, device_tier, entry,
+                                             monkeypatch):
+        """Under a kernel override the window hands ``(rows, lengths)``
+        over and takes a tuple; on the bare single-device kernel it takes
+        the packed entry (ops/packed_io.py): one array each way a
+        dispatch, the same bytes out at either depth."""
+        # conftest's eight virtual devices would shard an unbound parse
+        monkeypatch.setenv("LOONG_SHARDED", "0")
+        plane = DevicePlane.reset_for_testing()
         eng = RegexEngine(r"(\w+) (\d+)z")
         assert eng._segment_kernel is not None
-        eng.set_device_kernel_override(
-            LatencyInjectedKernel(eng._segment_kernel, 0.001,
-                                  serialize=True, wire_s=0.0005))
+        if entry == "tuple_override":
+            eng.set_device_kernel_override(
+                LatencyInjectedKernel(eng._segment_kernel, 0.001,
+                                      serialize=True, wire_s=0.0005))
         try:
             arena, offsets, lengths = _arena(b"abc 123z", 1024)  # 4 chunks
             sync = eng.parse_batch_async(arena, offsets, lengths,
@@ -518,6 +568,12 @@ class TestEngineStreaming:
             np.testing.assert_array_equal(sync.cap_off, stream.cap_off)
             np.testing.assert_array_equal(sync.cap_len, stream.cap_len)
             assert ds.batch_ring().leased_total() == 0
+            u = plane.utilization()
+            assert u["dispatched_total"] == 8
+            assert u["h2d_arrays_total"] \
+                == (8 if entry == "packed" else 16)
+            # the fake's outputs are numpy: no copy back to start
+            assert u["d2h_arrays_total"] == (8 if entry == "packed" else 0)
         finally:
             eng.set_device_kernel_override(None)
 

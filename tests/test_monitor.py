@@ -50,6 +50,9 @@ class TestAlarms:
 
 class TestSelfMonitor:
     def test_metrics_and_alarms_to_groups(self):
+        # start from a clean singleton: an earlier file on this worker may
+        # have left an alarm of its own (a chaos storm's demotions)
+        AlarmManager.instance().flush()
         pqm = ProcessQueueManager()
         pqm.create_or_reuse_queue(101)
         pqm.create_or_reuse_queue(102)
