@@ -373,6 +373,19 @@ def collect_status() -> dict:
     except Exception:  # noqa: BLE001
         pass
     try:
+        # processor_grok (processor/grok.py): rows through the stage, rows
+        # each member of Match took and where its extract ran, rows that
+        # met Python's re, by pipeline — absent until such a pipeline has
+        # seen a group
+        import sys as _sys
+        _gk = _sys.modules.get("loongcollector_tpu.processor.grok")
+        if _gk is not None:
+            gk_doc = _gk.status()
+            if gk_doc:
+                doc["grok"] = gk_doc
+    except Exception:  # noqa: BLE001
+        pass
+    try:
         from ..prof import flight as _flight
         rec = _flight.recorder()
         doc["flight"] = {"events": len(rec),
@@ -465,7 +478,7 @@ STATUS_SECTIONS = (
     "device", "streaming", "mesh", "fusion", "stage_fusion", "parse",
     "flight", "profiler", "recovery",
     "device_memory", "compile", "xprof",
-    "trace", "file_input", "flush", "startup", "multiline",
+    "trace", "file_input", "flush", "startup", "multiline", "grok",
 )
 
 

@@ -27,6 +27,7 @@ test kernel below.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -305,6 +306,22 @@ def set_budget_relief(fn: Optional[Callable[[], bool]]) -> None:
     the no-deadlock invariant: a thread waiting for budget never holds
     unmaterialised futures it cannot release itself."""
     _tls.relief = fn
+
+
+@contextlib.contextmanager
+def budget_relief_first(fn: Callable[[], bool]):
+    """While the body runs, ``fn`` is this thread's budget releaser, asked
+    before the registered one: a caller that keeps several handles of its
+    own in flight at once (processor_grok: a handle a member of ``Match``)
+    can give back what the earlier ones hold while a later one waits for
+    budget — the no-deadlock invariant of `set_budget_relief`, kept for
+    what the runner's hook cannot see."""
+    outer = getattr(_tls, "relief", None)
+    _tls.relief = lambda: bool(fn()) or (outer is not None and bool(outer()))
+    try:
+        yield
+    finally:
+        _tls.relief = outer
 
 
 _first_dispatch_marked = False

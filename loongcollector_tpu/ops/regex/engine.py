@@ -857,6 +857,14 @@ class PendingParse:
         self.arena = self.offsets = self.lengths = self._window = None
         return self._result
 
+    def abandon(self) -> None:
+        """The caller gives this parse up with chunks still in flight (its
+        own failure after the dispatch: nobody will ``result()`` them):
+        slots, budget and lane bytes return unmaterialised."""
+        window, self._window = self._window, None
+        if window is not None:
+            window.abandon()
+
 
 class PendingMatch(PendingParse):
     """A full-match gate whose device chunks are in flight: the

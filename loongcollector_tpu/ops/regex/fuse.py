@@ -1440,22 +1440,19 @@ class FusedSetExec:
                  lengths: np.ndarray,
                  force: Optional[str] = None) -> np.ndarray:
         """uint32 accept-tag bitmask per row; bit b = fused member b
-        full-matches.  `force` pins the route ("host"/"device") for tests
-        and the bench sweep."""
+        full-matches.  The host's byte-table scanner decides unless
+        `force="device"` asks for the dense device scan: that one is a
+        synchronous call outside the dispatch window, 9.7 ms a 2048 x 256
+        batch on the chip against the scanner's 0.27 (PERF.md section 7,
+        ROADMAP A3), kept for the tests that hold the two to each other.
+        The device form of the automaton earns its place as the scan stage
+        of a fused pipeline program, which rides that program's window."""
         offsets = np.asarray(offsets, dtype=np.int64)
         lengths = np.asarray(lengths, dtype=np.int32)
         n = len(offsets)
         if n == 0:
             return np.zeros(0, dtype=np.uint32)
-        use_device = force == "device"
-        if force is None and self.fdfa.device_ok:
-            from .engine import (_device_min_bytes, _native_host_mode,
-                                 _pallas_enabled)
-            if not _native_host_mode() and _pallas_enabled() is None \
-                    and os.environ.get("LOONG_NATIVE_T1") != "0" \
-                    and int(lengths.sum()) >= _device_min_bytes():
-                use_device = True
-        if not use_device:
+        if force != "device":
             return self.scanner.scan(arena, offsets, lengths)
         from ..device_batch import (LENGTH_BUCKETS, MAX_BATCH, pack_rows,
                                     pick_length_bucket)

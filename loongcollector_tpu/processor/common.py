@@ -7,15 +7,28 @@ for per-event groups the sources are packed into a scratch arena first.
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from typing import List, Optional, Tuple
 
 import numpy as np
 
+from .. import trace
 from ..models import ColumnarLogs, LogEvent, PipelineEventGroup, RawEvent
 
 DEFAULT_CONTENT_KEY = b"content"
 RAW_LOG_KEY = "rawLog"
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def stage_span(name: str):
+    """``with stage_span(name):`` — a span inside a processor's stage,
+    current for the body (what it calls nests under it); nothing while
+    tracing is off or the stage unsampled."""
+    tracer = trace.active_tracer()
+    sp = tracer.start_stage("processor", name) if tracer is not None else None
+    return sp if sp is not None else _NO_SPAN
 
 
 @dataclass

@@ -1,36 +1,138 @@
 """processor_grok — grok pattern field extraction.
 
 Reference: plugins/processor/grok/ (Go) — pattern library + %{NAME:field}
-expansion; multiple Match patterns are tried IN ORDER per event until one
-fully matches.  Expansion feeds the tiered RegexEngine, so kernel-friendly
-grok runs on the Tier-1 device kernel; each fallback pattern runs as its own
-device batch over the still-unmatched subset.
+expansion; ``Match`` is an ORDERED list: the patterns are tried from the top
+and the first that fully matches a row gives the row its fields.  Expansion
+feeds the tiered RegexEngine, one engine a member.
+
+Two legs, the protocol of processor_parse_regex_tpu and the multiline split.
+``process_dispatch`` classifies every row once — one scan of the list's
+fused full-match automaton on the host's byte-table scanner: a row's member
+is the lowest set bit of its tag, so the order is settled before anything is
+extracted — and starts each member's extract over its own rows with
+``parse_batch_async``, in ``Match`` order.  A SEGMENT-tier subset above the
+routing crossover rides a ``PendingParse`` through the one dispatch window
+and stays in flight; a subset under it runs on the native walker there and
+then, while the device works.  ``process_complete`` materialises the handles
+and writes the members' spans into one matrix of the union of their keys, in
+``Match`` order: a field of a member absent from a row stays absent (length
+-1), a row no member matches keeps its source under ``rawLog``.
+``process`` is the two legs in a row.
+
+A member the automaton could not hold (demoted: its mask is None), and every
+member of a list that does not fuse at all, probes what is still unmatched
+when its turn comes; what is left for the members behind it is what it did
+not take, so unless it is the last of the list it is waited for inside the
+dispatch leg.  A ``Match`` of one pattern is a list of one with no classify.
+
+Python's ``re`` meets a row only where nothing else can: a CPU-tier member,
+a row over the largest length bucket, a row whose engine and automaton
+disagree.  Every such loop runs under a ``grok.re_rows`` span and is counted
+(``re_rows_total``), so per-row work under the interpreter lock is never
+invisible.
+
+The classify runs where ``FusedSetExec.classify`` puts it, on the host's
+scanner (PERF.md section 7, ROADMAP A3).  A ``device_ok`` list still joins a
+fused pipeline program as its scan stage (``fused_stage_spec``), which rides
+that program's window.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Tuple
+import threading
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from ..models import PipelineEventGroup
+from ..ops.device_plane import budget_relief_first
 from ..ops.regex.engine import RegexEngine, get_engine
 from ..ops.regex.grok import GrokError, expand
 from ..pipeline.plugin.interface import PluginContext, Processor
-from .common import RAW_LOG_KEY, extract_source
+from .common import RAW_LOG_KEY, extract_source, stage_span
+
+# /debug/status ``grok``: per pipeline, cumulative for the process
+COUNTERS = ("rows_total", "device_rows_total", "walker_rows_total",
+            "re_rows_total", "unmatched_rows_total", "dispatches_total")
+_stats_lock = threading.Lock()
+_stats: Dict[str, dict] = {}
+
+
+def _note(pipeline: str, member_rows=(), **deltas: int) -> None:
+    with _stats_lock:
+        row = _stats.get(pipeline)
+        if row is None:
+            row = _stats[pipeline] = dict.fromkeys(COUNTERS, 0)
+            row["member_rows_total"] = []
+        for k, v in deltas.items():
+            row[k] += v
+        taken = row["member_rows_total"]
+        taken.extend([0] * (len(member_rows) - len(taken)))
+        for i, v in enumerate(member_rows):
+            taken[i] += v
+
+
+def status() -> Dict[str, dict]:
+    """The ``grok`` section of /debug/status: by pipeline, rows through the
+    stage (``rows_total``), where the row's member extracted it — the device
+    through the dispatch window, the native walker under the routing
+    crossover, Python ``re`` (``device_rows_total`` + ``walker_rows_total``
+    + ``re_rows_total`` + ``unmatched_rows_total`` = ``rows_total``) —, rows
+    no member matched (``rawLog``), rows each member of ``Match`` took by its
+    position (``member_rows_total``), and groups through the dispatch leg
+    (``dispatches_total``)."""
+    with _stats_lock:
+        return {k: dict(v, member_rows_total=list(v["member_rows_total"]))
+                for k, v in _stats.items()}
+
+
+def reset_for_testing() -> None:
+    with _stats_lock:
+        _stats.clear()
+
+
+def _run(positions: List[int]):
+    """``positions`` as a slice where they are a run (a member's named
+    groups, and the columns it was first to name), else as an index array.
+    A slice moves whole row pieces at once: on the chip's host it takes
+    0.49 s per GB out of ``grok.apply`` and gives the grok cell 1.7-4.0 % in
+    three same-seed pairs (PERF.md section 6, PR 34, call F)."""
+    if positions and positions == list(range(positions[0],
+                                             positions[-1] + 1)):
+        return slice(positions[0], positions[-1] + 1)
+    return np.array(positions, dtype=np.intp)
+
+
+class _Part:
+    """One member's extract over its rows of one group."""
+
+    __slots__ = ("member", "rows", "pending", "on_walker")
+
+    def __init__(self, member: int, rows: np.ndarray, pending):
+        self.member = member
+        self.rows = rows
+        self.pending = pending
+        # finished at dispatch: routing kept the subset on the host walker
+        self.on_walker = pending.done
 
 
 class ProcessorGrok(Processor):
     name = "processor_grok"
     supports_columnar = True
+    supports_async_dispatch = True
 
     def __init__(self) -> None:
         super().__init__()
         self.source_key = b"content"
         self.keep_source_on_fail = True
         self.renamed_source_key = RAW_LOG_KEY
+        self._pipeline = ""
         self._engines: List[Tuple[RegexEngine, List[str]]] = []
         self._fused_set = None
+        #: the union of the members' keys in order of first appearance, and
+        #: per member (its named captures, their columns in the union)
+        self._keys: List[str] = []
+        self._columns: List[Tuple[np.ndarray, np.ndarray]] = []
 
     def init(self, config: Dict[str, Any], context: PluginContext) -> bool:
         super().init(config, context)
@@ -53,6 +155,14 @@ class ProcessorGrok(Processor):
             # only NAMED groups become fields (grok semantics)
             keys = [engine.group_names.get(i, "") for i in range(engine.num_caps)]
             self._engines.append((engine, keys))
+        column_of: Dict[str, int] = {}
+        for _engine, keys in self._engines:
+            named = [g for g, key in enumerate(keys) if key]
+            self._columns.append((
+                _run(named),
+                _run([column_of.setdefault(keys[g], len(column_of))
+                      for g in named])))
+        self._keys = list(column_of)
         # loongfuse: with several Match patterns, one fused scan classifies
         # them all — each event runs ONLY its first-matching pattern's
         # extract program instead of trying every engine in order.  A lone
@@ -63,25 +173,189 @@ class ProcessorGrok(Processor):
             self._fused_set = try_build_set(
                 [e.pattern for e, _ in self._engines],
                 names=[f"match{i}" for i in range(len(self._engines))])
+        self._pipeline = getattr(context, "pipeline_name", "") or ""
         return True
 
     def process(self, group: PipelineEventGroup) -> None:
-        src = extract_source(group, self.source_key)
-        if src is None:
-            return
-        n = len(src.offsets)
-        if n == 0:
-            return
-        if src.columnar:
-            member_masks = None
-            if self._fused_set is not None:
-                tags = self._fused_set.classify(
-                    src.arena, src.offsets.astype(np.int64), src.lengths)
-                member_masks = self._fused_set.member_masks(tags)
-            self._apply_columnar(group, src, member_masks)
-            return
+        self.process_complete(group, self.process_dispatch(group))
 
-        self._process_rows(group)
+    def process_dispatch(self, group: PipelineEventGroup):
+        """Classify the group's rows and start every member's extract.  The
+        token holds the handles while a device subset is in flight; None
+        when the whole stage ran here."""
+        src = extract_source(group, self.source_key)
+        if src is None or not len(src.offsets):
+            return None
+        if not src.columnar:
+            with stage_span("grok.re_rows"):
+                self._process_rows(group)
+            return None
+        masks: List[Optional[np.ndarray]] = [None] * len(self._engines)
+        if self._fused_set is not None:
+            with stage_span("grok.classify"):
+                masks = self._fused_set.member_masks(
+                    self._fused_set.classify(src.arena, src.offsets,
+                                             src.lengths))
+        return self._dispatch_members(group, src, masks)
+
+    def _dispatch_members(self, group, src, masks):
+        parts: List[_Part] = []
+        try:
+            with stage_span("grok.members.dispatch"), \
+                    budget_relief_first(lambda: self._relieve(parts)):
+                self._place_members(src, masks, parts)
+        except BaseException:
+            self._abandon(parts)
+            raise
+        _note(self._pipeline, dispatches_total=1)
+        token = src, parts
+        if all(part.pending.done for part in parts):
+            self.process_complete(group, token)
+            return None
+        return token
+
+    @staticmethod
+    def _abandon(parts: List[_Part]) -> None:
+        """A leg failed with members' chunks in flight: nobody will ask for
+        them, so slots, budget and lane bytes go back now."""
+        for part in parts:
+            part.pending.abandon()
+
+    def _relieve(self, parts: List[_Part]) -> bool:
+        """Budget relief while a later member waits to dispatch: this
+        group's oldest handle still in flight is materialised (its result
+        is kept for the complete leg), which gives its bytes back."""
+        for part in parts:
+            if not part.pending.done:
+                self._result(part)
+                return True
+        return False
+
+    def _place_members(self, src, masks, parts: List[_Part]) -> None:
+        remaining = src.present.copy()
+        last = len(self._engines) - 1
+        for i, (engine, _keys) in enumerate(self._engines):
+            if masks[i] is not None:
+                # the tags settle the order: the row is this member's
+                idx = np.flatnonzero(remaining & masks[i])
+                remaining[idx] = False
+            else:
+                idx = np.flatnonzero(remaining)
+            if not len(idx):
+                continue
+            part = _Part(i, idx, engine.parse_batch_async(
+                src.arena, src.offsets[idx], src.lengths[idx]))
+            parts.append(part)
+            if masks[i] is None and i < last:
+                # what is left for the members behind a probing member is
+                # what it did not take: it is waited for here
+                remaining[idx[self._result(part).ok]] = False
+
+    @staticmethod
+    def _result(part: _Part):
+        """The part's spans; a handle with rows for Python ``re`` (a CPU-
+        tier member, rows over the largest bucket) runs that loop inside
+        ``result()``, under the span that says so."""
+        pending = part.pending
+        if pending.done or not len(pending.cpu_idx):
+            return pending.result()
+        with stage_span("grok.re_rows"):
+            return pending.result()
+
+    def process_complete(self, group: PipelineEventGroup, token) -> None:
+        if token is None:
+            return
+        src, parts = token
+        try:
+            results = [self._result(part) for part in parts]
+        except BaseException:
+            self._abandon(parts)
+            raise
+        with stage_span("grok.apply"):
+            self._apply(group, src, parts, results)
+
+    def _apply(self, group, src, parts: List[_Part], results) -> None:
+        """The members' spans into one matrix of the union keys, in Match
+        order; rawLog for the rows no member took."""
+        n = len(src.offsets)
+        cols = group.columns
+        off_mat = np.zeros((n, len(self._keys)), dtype=np.int32)
+        len_mat = np.full((n, len(self._keys)), -1, dtype=np.int32)
+        matched = np.zeros(n, dtype=bool)
+        taken = [0] * len(self._engines)
+        tiers = {"device": 0, "walker": 0, "re": 0}
+
+        def install(member, rows, cap_off, cap_len):
+            caps, columns = self._columns[member]
+            # a run of columns takes whole row pieces; scattered ones (a
+            # key shared with a member further up) go cell by cell
+            at = rows if isinstance(columns, slice) else rows[:, None]
+            off_mat[at, columns] = cap_off[:, caps]
+            len_mat[at, columns] = cap_len[:, caps]
+            matched[rows] = True
+            taken[member] += len(rows)
+
+        for part, res in zip(parts, results):
+            ok = res.ok
+            n_ok = int(ok.sum())
+            if n_ok == len(ok):
+                install(part.member, part.rows, res.cap_off, res.cap_len)
+            else:
+                install(part.member, part.rows[ok], res.cap_off[ok],
+                        res.cap_len[ok])
+            on_re = 0
+            if part.on_walker:
+                tiers["walker"] += n_ok
+            else:
+                cpu_idx = part.pending.cpu_idx
+                on_re = int(ok[cpu_idx].sum()) if len(cpu_idx) else 0
+                tiers["re"] += on_re
+                tiers["device"] += n_ok - on_re
+            left = part.rows[~ok]
+            member_mask_known = self._fused_set is not None \
+                and part.member in self._fused_set.bit_of
+            if len(left) and member_mask_known:
+                # the classify gave these rows to this member and its
+                # engine did not take them: the automaton and an engine
+                # disagree, and ``re`` decides them from this member on
+                tiers["re"] += self._decide_by_re(src, left, part.member,
+                                                  install)
+        if self._keys:
+            cols.set_fields_matrix(self._keys, off_mat, len_mat)
+        fail = ~matched & src.present
+        n_fail = int(fail.sum())
+        if self.keep_source_on_fail and n_fail:
+            cols.set_field(self.renamed_source_key,
+                           src.offsets.astype(np.int32),
+                           np.where(fail, src.lengths, -1).astype(np.int32))
+        cols.parse_ok = matched
+        if src.from_content:
+            cols.content_consumed = True
+        _note(self._pipeline, taken, rows_total=n,
+              device_rows_total=tiers["device"],
+              walker_rows_total=tiers["walker"], re_rows_total=tiers["re"],
+              unmatched_rows_total=n - int(matched.sum()))
+
+    def _decide_by_re(self, src, rows, first: int, install) -> int:
+        """``re`` decides ``rows``, member by member from ``first`` on;
+        how many it matched."""
+        decided = 0
+        with stage_span("grok.re_rows"):
+            for j in range(first, len(self._engines)):
+                if not len(rows):
+                    break
+                engine = self._engines[j][0]
+                C = max(engine.num_caps, 1)
+                ok = np.zeros(len(rows), dtype=bool)
+                off = np.zeros((len(rows), C), dtype=np.int32)
+                ln = np.full((len(rows), C), -1, dtype=np.int32)
+                engine._cpu_fallback_rows(
+                    src.arena, src.offsets[rows], src.lengths[rows],
+                    range(len(rows)), ok, off, ln)
+                install(j, rows[ok], off[ok], ln[ok])
+                decided += int(ok.sum())
+                rows = rows[~ok]
+        return decided
 
     def fused_stage_spec(self, ctx):
         """loongresident: the multi-pattern classify scan joins a fused
@@ -109,76 +383,34 @@ class ProcessorGrok(Processor):
         from .common import subset_source
         tags = np.asarray(out[0]).astype(np.uint32)[rowmap]
         masks = self._fused_set.member_masks(tags)
-        self._apply_columnar(group, subset_source(src, rowmap), masks)
+        self.process_complete(group, self._dispatch_members(
+            group, subset_source(src, rowmap), masks))
         return rowmap
 
-    def _apply_columnar(self, group, src, member_masks) -> None:
-        n = len(src.offsets)
-        cols = group.columns
-        remaining = src.present.copy()
-        matched = np.zeros(n, dtype=bool)
-        field_offs: Dict[str, np.ndarray] = {}
-        field_lens: Dict[str, np.ndarray] = {}
-        for pat_i, (engine, keys) in enumerate(self._engines):
-            if not remaining.any():
-                break
-            if member_masks is not None \
-                    and member_masks[pat_i] is not None:
-                # fused member: the scan already classified it — run
-                # its extract program only on its matching rows.
-                # Demoted members (mask None) keep the per-pattern
-                # probe over everything still unmatched.
-                idx = np.nonzero(remaining & member_masks[pat_i])[0]
-                if not len(idx):
-                    continue
-            else:
-                idx = np.nonzero(remaining)[0]
-            res = engine.parse_batch(src.arena, src.offsets[idx],
-                                     src.lengths[idx])
-            hit = idx[res.ok]
-            if not len(hit):
-                continue
-            for g, key in enumerate(keys):
-                if not key:
-                    continue
-                if key not in field_offs:
-                    field_offs[key] = np.zeros(n, dtype=np.int32)
-                    field_lens[key] = np.full(n, -1, dtype=np.int32)
-                field_offs[key][hit] = res.cap_off[res.ok, g]
-                field_lens[key][hit] = res.cap_len[res.ok, g]
-            matched[hit] = True
-            remaining[hit] = False
-        for key in field_offs:
-            cols.set_field(key, field_offs[key], field_lens[key])
-        if self.keep_source_on_fail:
-            fail = (~matched) & src.present
-            if fail.any():
-                cols.set_field(self.renamed_source_key,
-                               src.offsets.astype(np.int32),
-                               np.where(fail, src.lengths, -1).astype(np.int32))
-        cols.parse_ok = matched
-        if src.from_content:
-            cols.content_consumed = True
-
     def _process_rows(self, group: PipelineEventGroup) -> None:
-        # row path — shared reference keep/discard ordering
+        # row path — shared reference keep/discard ordering; every row of
+        # it is ``re``'s and is counted so
         from .common import finish_row_keep
         sb = group.source_buffer
         renamed = self.renamed_source_key.encode()
-        for i, ev in enumerate(group.events):
+        rows = 0
+        taken = [0] * len(self._engines)
+        for ev in group.events:
             if not hasattr(ev, "get_content"):
                 continue
             raw = ev.get_content(self.source_key)
             if raw is None:
                 continue
+            rows += 1
             data = raw.to_bytes()
             hit = False
             overwritten = False
-            for engine, keys in self._engines:
+            for j, (engine, keys) in enumerate(self._engines):
                 m = engine._re.fullmatch(data)
                 if m is None:
                     continue
                 hit = True
+                taken[j] += 1
                 for g, key in enumerate(keys):
                     if key and m.group(g + 1) is not None:
                         kb = key.encode()
@@ -188,3 +420,6 @@ class ProcessorGrok(Processor):
                 break
             finish_row_keep(ev, raw, hit, self.source_key, overwritten,
                             self.keep_source_on_fail, False, renamed)
+        _note(self._pipeline, taken, rows_total=rows,
+              re_rows_total=sum(taken),
+              unmatched_rows_total=rows - sum(taken))

@@ -35,18 +35,17 @@ read chunks still yields ONE event (round-2 VERDICT item 3).
 
 from __future__ import annotations
 
-import contextlib
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from .. import trace
 from ..models import ColumnarLogs, EventGroupMetaKey, PipelineEventGroup
 from ..ops.regex.engine import RegexEngine, get_engine
 from ..ops.regex.program import PatternTier
 from ..pipeline.plugin.interface import PluginContext, Processor
+from .common import stage_span
 
 CARRY_CAP_BYTES = 1 << 20   # give up stitching records larger than this
 CARRY_FLUSH_S = 5.0         # idle carries flush via the pipeline timeout tick
@@ -96,17 +95,6 @@ def reset_for_testing() -> None:
     with _stats_lock:
         _stats.clear()
         _calls.clear()
-
-
-_NO_SPAN = contextlib.nullcontext()
-
-
-def _stage(name: str):
-    """``with _stage(name):`` — the span current for the body (what it calls
-    nests under it), nothing while tracing is off or the stage unsampled."""
-    tracer = trace.active_tracer()
-    sp = tracer.start_stage("processor", name) if tracer is not None else None
-    return sp if sp is not None else _NO_SPAN
 
 
 class ProcessorSplitMultilineLogString(Processor):
@@ -198,7 +186,7 @@ class ProcessorSplitMultilineLogString(Processor):
         lens = cols.lengths
 
         if self._async_start:
-            with _stage("multiline.classify.dispatch"):
+            with stage_span("multiline.classify.dispatch"):
                 pending = self.start.match_batch_async(arena, offs, lens)
             on_host = n if pending.done else len(pending.cpu_idx)
             _note(self._pipeline, lines_total=n, host_lines_total=on_host,
@@ -210,7 +198,7 @@ class ProcessorSplitMultilineLogString(Processor):
             return None
 
         masks: Dict[str, Optional[np.ndarray]] = {}
-        with _stage("multiline.classify"):
+        with stage_span("multiline.classify"):
             if self._fused_set is not None:
                 member = self._fused_set.member_masks(
                     self._fused_set.classify(arena, offs, lens))
@@ -228,14 +216,14 @@ class ProcessorSplitMultilineLogString(Processor):
         if token is None:
             return
         cols, arena, offs, lens, pending = token
-        with _stage("multiline.classify.complete"):
+        with stage_span("multiline.classify.complete"):
             is_start = pending.result().ok
         _note_calls(self._pipeline, pending.calls)
         self._merge(group, cols, arena, offs, lens, {"start": is_start})
 
     def _merge(self, group, cols, arena, offs, lens, masks) -> None:
         """Block walk, carry stitching and emit over classified lines."""
-        with _stage("multiline.merge"):
+        with stage_span("multiline.merge"):
             self._classify_blocks(group, cols, arena, offs, lens, masks)
         _note(self._pipeline, records_total=len(group.columns))
 
