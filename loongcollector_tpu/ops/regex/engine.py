@@ -86,6 +86,26 @@ def _note_rows(tier: str, n: int) -> None:
         _route_rows[tier] += n
 
 
+def _note_kernel_fallback(msg: str, *args) -> None:
+    """A REAL device-kernel failure answered by another path: counted
+    (``device.routing.kernel_fallbacks_total`` — the benchmark's ``correct``
+    and chip_smoke.py fail on a non-zero count) and logged with its
+    traceback.  Production fault handling, never silent."""
+    global _kernel_fallbacks
+    with _route_lock:
+        _kernel_fallbacks += 1
+    from ...utils.logger import get_logger
+    get_logger("regex").exception(msg, *args)
+
+
+def _rerun(kern, c) -> tuple:
+    """A chunk's synchronous recovery re-run on ``kern``: the slot still
+    holds the packed rows."""
+    # the designed exception path
+    # loonglint: disable=host-bounce
+    return tuple(np.asarray(a) for a in kern(c.batch.rows, c.batch.lengths))
+
+
 def _note_first_choice(kern) -> None:
     """Record the device kernel the engine selected before any runtime
     fallback, by the ``watched_jit`` family it compiles under."""
@@ -203,9 +223,47 @@ def _native_host_mode() -> bool:
     return _host_backend_cached
 
 
-def _chunks(idx: np.ndarray, size: int):
+def routes_to_host(lengths: np.ndarray, has_host_tier) -> bool:
+    """The routing rule of every SEGMENT-tier dispatch, applied to the byte
+    sum of the rows that would cross: a CPU backend keeps them on the host;
+    on an accelerator small batches still lose to the fixed dispatch round
+    trip and go to the host tier (``has_host_tier()``: is there one) where
+    the sum is under the crossover the probe measured.  Explicit
+    LOONG_PALLAS / LOONG_NATIVE_T1 forces win."""
+    if _native_host_mode():
+        return True
+    if _pallas_enabled() is None \
+            and os.environ.get("LOONG_NATIVE_T1") != "0":
+        return bool(has_host_tier()) \
+            and int(lengths.sum()) < _device_min_bytes()
+    return False
+
+
+def pallas_by_default() -> bool:
+    """The single-device kernel choice: an explicit LOONG_PALLAS force,
+    else Pallas on a real TPU and XLA elsewhere."""
+    forced = _pallas_enabled()
+    if forced is not None:
+        return forced
+    import jax
+    return jax.default_backend() == "tpu"
+
+
+def _chunks(idx, size: int):
+    """``idx`` in pieces of at most ``size`` rows.  An int stands for "rows
+    0 .. idx-1, in order" and yields slices: a chunk of them is a view to
+    pack from and to deliver into, with no gather and no scatter."""
+    if isinstance(idx, int):
+        for i in range(0, idx, size):
+            yield slice(i, min(i + size, idx))
+        return
     for i in range(0, len(idx), size):
         yield idx[i : i + size]
+
+
+def _n_rows(chunk) -> int:
+    return chunk.stop - chunk.start if isinstance(chunk, slice) \
+        else len(chunk)
 
 
 class BatchParseResult:
@@ -413,12 +471,7 @@ class RegexEngine:
         """Pallas-vs-XLA choice for one device (shared by the default
         path and every lane-placed wrapper)."""
         if self._use_pallas is None:
-            forced = _pallas_enabled()
-            if forced is not None:
-                self._use_pallas = forced
-            else:
-                import jax
-                self._use_pallas = jax.default_backend() == "tpu"
+            self._use_pallas = pallas_by_default()
         if self._use_pallas:
             if self._pallas_kernel is None:
                 from ..kernels.field_extract_pallas import PallasExtractKernel
@@ -536,16 +589,8 @@ class RegexEngine:
         n = len(offsets)
         C = max(self.num_caps, 1)
         if n and self.tier is PatternTier.SEGMENT:
-            use_host = _native_host_mode()
-            if not use_host and _pallas_enabled() is None \
-                    and os.environ.get("LOONG_NATIVE_T1") != "0":
-                # accelerator backend: small batches still lose to the fixed
-                # dispatch round trip — route them to the native walker
-                # (explicit LOONG_PALLAS / LOONG_NATIVE_T1 forces win)
-                nat = self._host_walker()
-                use_host = (nat is not None
-                            and int(lengths.sum()) < _device_min_bytes())
-            if use_host:
+            if routes_to_host(lengths,
+                              lambda: self._host_walker() is not None):
                 fx = self._fused_exec()
                 if fx is not None:
                     k_ok, k_off, k_len = fx.parse(arena, offsets, lengths)
@@ -728,7 +773,6 @@ class PendingParse:
 
     def dispatch(self, device_idx: np.ndarray) -> None:
         from ..device_plane import DevicePlane
-        engine = self.engine
         # loongmesh: a lane-bound worker thread dispatches on its home
         # chip (source → worker → chip affinity); unbound dispatch shards
         # over the full mesh (or runs single-device)
@@ -738,15 +782,13 @@ class PendingParse:
             recover=self._recover, deliver=self._deliver)
         # the extract path's first choice is what routing reports, whoever
         # dispatches first (the match gate has one kernel, nothing to choose)
-        _note_first_choice(engine._device_kernel(lane))
+        _note_first_choice(self._first_choice(lane))
         try:
             for chunk in _chunks(device_idx, MAX_BATCH):
-                if not window.admit(len(chunk)):
+                if not window.admit(_n_rows(chunk)):
                     # this chip is sick: its shard parses on the host, in
                     # order, synchronously (ledger-conserved)
-                    engine._host_parse_rows(
-                        self.arena, self.offsets, self.lengths, chunk,
-                        self.ok, self.cap_off, self.cap_len)
+                    self._host_rows(chunk)
                     continue
                 # re-read the kernel PER CHUNK: the ring advance in admit
                 # (or the budget-wait hook inside submit) may have pinned
@@ -779,6 +821,17 @@ class PendingParse:
         """The kernel a chunk is submitted on (read per chunk)."""
         return self.engine._device_kernel(lane)
 
+    def _first_choice(self, lane):
+        """The kernel ``routing.kernel_first_choice`` names."""
+        return self.engine._device_kernel(lane)
+
+    def _host_rows(self, chunk) -> None:
+        """The rows of a chunk that cannot ride the device, settled on the
+        host tiers into the same result rows."""
+        self.engine._host_parse_rows(
+            self.arena, self.offsets, self.lengths, chunk,
+            self.ok, self.cap_off, self.cap_len)
+
     def _recover(self, c, exc):
         """A chunk whose materialisation raised (the window's callback):
         its spans from another path, or raise."""
@@ -789,9 +842,7 @@ class PendingParse:
             # and later chunks respill pre-dispatch; THIS chunk's shard
             # parses on the host.  Events conserved, order kept (results
             # land in the same rows), the other chips' lanes never notice.
-            engine._host_parse_rows(
-                self.arena, self.offsets, self.lengths, c.tag,
-                self.ok, self.cap_off, self.cap_len)
+            self._host_rows(c.tag)
             return None
         kern = c.kernel
         if not isinstance(exc, chaos.ChaosFault):
@@ -803,16 +854,8 @@ class PendingParse:
             # re-run the chunk on the proven XLA kernel.  A lane kernel's
             # REAL failure also counts against its chip's breaker (the
             # window's report) — repeated ones trip the lane to host
-            # respill.  Production fault handling, never silent: every
-            # fallback is counted
-            # (``device.routing.kernel_fallbacks_total``), and the
-            # benchmark's ``correct`` and chip_smoke.py fail on a
-            # non-zero count.
-            global _kernel_fallbacks
-            with _route_lock:
-                _kernel_fallbacks += 1
-            from ...utils.logger import get_logger
-            get_logger("regex").exception(
+            # respill.
+            _note_kernel_fallback(
                 "device kernel failed for %r; falling back to XLA path",
                 engine.pattern)
             engine._device_kernel_failed(kern)
@@ -825,10 +868,7 @@ class PendingParse:
         # submit): it must error only THIS chunk — the slot still holds
         # the packed rows, so re-run on the same kernel and keep the ring
         # moving in order
-        # the designed exception path: a synchronous recovery re-run
-        # loonglint: disable=host-bounce
-        return tuple(np.asarray(a)
-                     for a in kern(c.batch.rows, c.batch.lengths))
+        return _rerun(kern, c)
 
     def _deliver(self, c, outs) -> None:
         k_ok, k_off, k_len = outs
@@ -889,17 +929,115 @@ class PendingMatch(PendingParse):
 
     def _recover(self, c, exc):
         if isinstance(exc, ChipLaneFault):
-            self.engine._host_parse_rows(
-                self.arena, self.offsets, self.lengths, c.tag,
-                self.ok, self.cap_off, self.cap_len)
+            self._host_rows(c.tag)
             return None
         if not isinstance(exc, chaos.ChaosFault):
             raise exc
-        # the designed exception path: a synchronous recovery re-run
-        # loonglint: disable=host-bounce
-        return tuple(np.asarray(a)
-                     for a in c.kernel(c.batch.rows, c.batch.lengths))
+        return _rerun(c.kernel, c)
 
     def _deliver(self, c, outs) -> None:
         self.ok[c.tag] = outs[0][:c.batch.n_real] != 0
         self.calls.append(c.batch.rows.shape)
+
+
+class PendingMatchList(PendingParse):
+    """An ordered ``Match`` list whose device chunks are in flight: every
+    member's extract and the first-match choice are ONE program
+    (``ops/kernels/match_list.py`` ``MatchListKernel``), so a chunk
+    delivers each row's member (-1: none took it) and its spans in the
+    columns of the union of the members' keys.  The ``PendingParse``
+    window, chunking and lane placement; ``result().ok`` is the int32
+    member index, ``cap_off`` / ``cap_len`` are ``[N, K]``.
+
+    No engine stands behind it and it has no host tier of its own: the
+    caller applies the routing rule (``routes_to_host``) to the whole
+    group before it builds one, and keeps rows over the largest length
+    bucket to itself.  The rows of a chunk that cannot ride — a sick chip
+    lane, a chip-lane fault, a real failure of the program — are named in
+    ``host_rows`` for the caller's own host path; a real failure is also
+    counted (``device.routing.kernel_fallbacks_total``) and sets
+    ``failed``, on which the caller gives the program up.  An injected
+    async-stage fault re-runs the chunk on the same program.
+
+    Every small numpy call costs the worker a hand-over of the
+    interpreter lock in the agent (0.05-0.1 ms each beside the reader and
+    the sender: PERF.md section 6, PR 35, call 4), so the common case —
+    every row of the group rides, in one chunk — makes none it can avoid:
+    ``dispatch()`` without an index array chunks by slices, and the one
+    chunk's copy back IS the result (no buffers to fill, no scatter; the
+    member and length columns stay read-only views of it)."""
+
+    __slots__ = ("kernel", "host_rows", "failed", "rode")
+
+    program = "grok_match_list"
+
+    def __init__(self, kernel, arena, offsets, lengths, depth=None):
+        super().__init__(None, arena, offsets, lengths, None, None, None,
+                         (), depth=depth)
+        self.kernel = kernel
+        #: the chunks (index arrays or slices) left to the caller's host
+        #: path
+        self.host_rows = []
+        self.failed = False
+        #: rows handed to the window
+        self.rode = 0
+
+    def dispatch(self, device_idx: Optional[np.ndarray] = None) -> None:
+        """``device_idx`` None: every row rides."""
+        n = len(self.offsets)
+        self.rode = n if device_idx is None else len(device_idx)
+        super().dispatch(n if device_idx is None else device_idx)
+
+    def _kernel_for(self, lane):
+        if lane is None:
+            return self.kernel
+        return _LanePlacedKernel(self.kernel, lane)
+
+    def _first_choice(self, lane):
+        return self.kernel
+
+    def _host_rows(self, chunk) -> None:
+        self.host_rows.append(chunk)
+
+    def _recover(self, c, exc):
+        if isinstance(exc, ChipLaneFault):
+            self._host_rows(c.tag)
+            return None
+        if not isinstance(exc, chaos.ChaosFault):
+            # as the extract's Mosaic failure: throughput, never liveness
+            _note_kernel_fallback("the Match list program failed; its rows "
+                                  "take the per-member path")
+            self.failed = True
+            self._host_rows(c.tag)
+            return None
+        return _rerun(c.kernel, c)
+
+    def _buffers(self) -> None:
+        n, K = len(self.offsets), self.kernel.num_keys
+        self.ok = np.full(n, -1, dtype=np.int32)
+        self.cap_off = np.zeros((n, K), dtype=np.int32)
+        self.cap_len = np.full((n, K), -1, dtype=np.int32)
+
+    def _deliver(self, c, outs) -> None:
+        member, k_off, k_len = outs
+        chunk, batch = c.tag, c.batch
+        n = batch.n_real
+        if self.ok is None:
+            if isinstance(chunk, slice) and n == len(self.offsets):
+                # the whole group in one chunk
+                self.ok, self.cap_len = member[:n], k_len[:n]
+                self.cap_off = k_off[:n] + batch.origins[:n, None]
+                return
+            self._buffers()
+        self.ok[chunk] = member[:n]
+        # row-relative -> arena-absolute
+        self.cap_off[chunk] = k_off[:n] + batch.origins[:n, None]
+        self.cap_len[chunk] = k_len[:n]
+
+    def result(self) -> BatchParseResult:
+        if self._result is None:
+            if self._window is not None:
+                self._window.drain()
+            if self.ok is None:
+                self._buffers()         # no chunk delivered anything
+        return super().result()

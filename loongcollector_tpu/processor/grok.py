@@ -5,11 +5,32 @@ expansion; ``Match`` is an ORDERED list: the patterns are tried from the top
 and the first that fully matches a row gives the row its fields.  Expansion
 feeds the tiered RegexEngine, one engine a member.
 
-Two legs, the protocol of processor_parse_regex_tpu and the multiline split.
-``process_dispatch`` classifies every row once — one scan of the list's
-fused full-match automaton on the host's byte-table scanner: a row's member
-is the lowest set bit of its tag, so the order is settled before anything is
-extracted — and starts each member's extract over its own rows with
+Two legs, the protocol of processor_parse_regex_tpu and the multiline split,
+and two paths through them.
+
+**The list program.**  A list whose members are all on the SEGMENT tier is
+one device program a group (``ops/kernels/match_list.py``): the group's rows
+are packed once into one ring slot and submitted once through the one
+dispatch window (``PendingMatchList``), every member's extract runs over the
+same rows inside the one module, and the first-match choice is made there —
+a row's member is the lowest member whose full-match flag is set.
+``process_complete`` installs the one pair of span matrices that comes back.
+No host classify, no per-member subsets, no second opinion by ``re``.  It is
+chosen from what the code can observe: at ``init`` that every member has a
+SEGMENT-tier device kernel, per group that the routing rule every regex
+dispatch uses (``routes_to_host``), applied to the whole group's byte sum,
+sends the rows to the device.  Rows over the largest length bucket meet
+``re`` member by member, beside the dispatch.  A chunk the program cannot
+serve (a sick chip lane, a real failure) sends its group down the
+per-member path, and a real failure pins the processor there, counted in
+``device.routing.kernel_fallbacks_total``.
+
+**The per-member path** serves every other list and group, and a fused
+chain's scan stage.  ``process_dispatch`` classifies every row once — one
+scan of the list's fused full-match automaton on the host's byte-table
+scanner: a row's member is the lowest set bit of its tag, so the order is
+settled before anything is extracted — and starts each member's extract over
+its own rows with
 ``parse_batch_async``, in ``Match`` order.  A SEGMENT-tier subset above the
 routing crossover rides a ``PendingParse`` through the one dispatch window
 and stays in flight; a subset under it runs on the native walker there and
@@ -45,17 +66,25 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from ..models import PipelineEventGroup
+from ..ops import chip_lanes
+from ..ops.device_batch import LENGTH_BUCKETS
 from ..ops.device_plane import budget_relief_first
-from ..ops.regex.engine import RegexEngine, get_engine
+from ..ops.regex.engine import (PendingMatchList, RegexEngine, get_engine,
+                                pallas_by_default, routes_to_host)
 from ..ops.regex.grok import GrokError, expand
 from ..pipeline.plugin.interface import PluginContext, Processor
 from .common import RAW_LOG_KEY, extract_source, stage_span
 
 # /debug/status ``grok``: per pipeline, cumulative for the process
-COUNTERS = ("rows_total", "device_rows_total", "walker_rows_total",
-            "re_rows_total", "unmatched_rows_total", "dispatches_total")
+COUNTERS = ("rows_total", "device_rows_total", "list_program_rows_total",
+            "walker_rows_total", "re_rows_total", "unmatched_rows_total",
+            "dispatches_total")
 _stats_lock = threading.Lock()
 _stats: Dict[str, dict] = {}
+# the list programs of the process, by (the members' patterns, Pallas or
+# XLA): a pipeline reload reuses the compiled program, as get_engine does
+_list_kernels_lock = threading.Lock()
+_list_kernels: Dict[tuple, Any] = {}
 
 
 def _note(pipeline: str, member_rows=(), **deltas: int) -> None:
@@ -78,7 +107,10 @@ def status() -> Dict[str, dict]:
     through the dispatch window, the native walker under the routing
     crossover, Python ``re`` (``device_rows_total`` + ``walker_rows_total``
     + ``re_rows_total`` + ``unmatched_rows_total`` = ``rows_total``) —, rows
-    no member matched (``rawLog``), rows each member of ``Match`` took by its
+    no member matched (``rawLog``), rows that rode the one-program dispatch
+    of the whole list (``list_program_rows_total``: no term of that sum —
+    those a member took are ``device_rows_total``'s, the others
+    ``unmatched_rows_total``'s), rows each member of ``Match`` took by its
     position (``member_rows_total``), and groups through the dispatch leg
     (``dispatches_total``)."""
     with _stats_lock:
@@ -89,6 +121,8 @@ def status() -> Dict[str, dict]:
 def reset_for_testing() -> None:
     with _stats_lock:
         _stats.clear()
+    with _list_kernels_lock:
+        _list_kernels.clear()
 
 
 def _run(positions: List[int]):
@@ -133,6 +167,13 @@ class ProcessorGrok(Processor):
         #: per member (its named captures, their columns in the union)
         self._keys: List[str] = []
         self._columns: List[Tuple[np.ndarray, np.ndarray]] = []
+        #: the same map as plain lists, for the list program's trace
+        self._placement: List[Tuple[List[int], List[int]]] = []
+        #: the whole list as one device program: possible at all (every
+        #: member on the SEGMENT tier; False for good after a real failure
+        #: of the program), and the kernel object, built at first use
+        self._list_ok = False
+        self._list_kernel = None
 
     def init(self, config: Dict[str, Any], context: PluginContext) -> bool:
         super().init(config, context)
@@ -158,11 +199,13 @@ class ProcessorGrok(Processor):
         column_of: Dict[str, int] = {}
         for _engine, keys in self._engines:
             named = [g for g, key in enumerate(keys) if key]
-            self._columns.append((
-                _run(named),
-                _run([column_of.setdefault(keys[g], len(column_of))
-                      for g in named])))
+            columns = [column_of.setdefault(keys[g], len(column_of))
+                       for g in named]
+            self._placement.append((named, columns))
+            self._columns.append((_run(named), _run(columns)))
         self._keys = list(column_of)
+        self._list_ok = len(self._engines) > 1 and bool(self._keys) and all(
+            e._segment_kernel is not None for e, _ in self._engines)
         # loongfuse: with several Match patterns, one fused scan classifies
         # them all — each event runs ONLY its first-matching pattern's
         # extract program instead of trying every engine in order.  A lone
@@ -190,6 +233,14 @@ class ProcessorGrok(Processor):
             with stage_span("grok.re_rows"):
                 self._process_rows(group)
             return None
+        route = self._list_route(src) if self._list_ok else None
+        if route is not None:
+            return self._dispatch_list(group, src, *route)
+        return self._dispatch_classified(group, src)
+
+    def _dispatch_classified(self, group, src):
+        """The per-member path's dispatch leg: the host classify, then
+        every member's extract over its own rows."""
         masks: List[Optional[np.ndarray]] = [None] * len(self._engines)
         if self._fused_set is not None:
             with stage_span("grok.classify"):
@@ -197,6 +248,114 @@ class ProcessorGrok(Processor):
                     self._fused_set.classify(src.arena, src.offsets,
                                              src.lengths))
         return self._dispatch_members(group, src, masks)
+
+    # -- the list program -------------------------------------------------
+
+    def _list_program(self, lane):
+        """The kernel object of the whole list (one a process and list, as
+        engines are), or None where the members' own dispatches would not
+        be single-device ones (an unbound dispatch on a multi-chip host
+        shards over the mesh)."""
+        engines = [e for e, _ in self._engines]
+        if lane is None and engines[0]._maybe_sharded() is not None:
+            return None
+        if self._list_kernel is None:
+            key = tuple(e.pattern for e in engines), pallas_by_default()
+            with _list_kernels_lock:
+                kernel = _list_kernels.get(key)
+                if kernel is None:
+                    from ..ops.kernels.match_list import MatchListKernel
+                    kernel = _list_kernels[key] = MatchListKernel(
+                        [e._segment_kernel.program for e in engines],
+                        self._placement, len(self._keys), key[1])
+            self._list_kernel = kernel
+        return self._list_kernel
+
+    def _list_route(self, src):
+        """``(kernel, rows)`` where the list program serves this group —
+        the rows it takes are those present and within the largest length
+        bucket, None for "every row" (the common case: no mask, no index
+        array) — or None where the per-member path does: the routing rule
+        keeps the rows' byte sum on the host, or the dispatch would not be
+        a single-device one."""
+        lengths = src.lengths
+        idx = None
+        if int(lengths.max()) > LENGTH_BUCKETS[-1] \
+                or not (src.from_content or int(lengths.min()) >= 0):
+            idx = np.flatnonzero(src.present
+                                 & (lengths <= LENGTH_BUCKETS[-1]))
+            lengths = lengths[idx]
+        if routes_to_host(lengths, lambda: True):
+            return None
+        kernel = self._list_program(chip_lanes.current_lane())
+        return None if kernel is None else (kernel, idx)
+
+    def _dispatch_list(self, group, src, kernel, idx):
+        """One pack, one submit for the whole group."""
+        pending = PendingMatchList(kernel, src.arena, src.offsets,
+                                   src.lengths)
+        if idx is not None and not len(idx):
+            self._complete_list(group, src, pending)
+            return None
+        with stage_span("grok.members.dispatch"):
+            pending.dispatch(idx)
+        return src, pending
+
+    def _complete_list(self, group, src, pending: PendingMatchList) -> None:
+        try:
+            res = pending.result()
+        except BaseException:
+            pending.abandon()
+            raise
+        if pending.failed:
+            self._list_ok = False
+        if pending.host_rows:
+            # a chunk the program could not serve: the whole group takes
+            # the per-member path, which has its own host tiers
+            self.process_complete(group,
+                                  self._dispatch_classified(group, src))
+            return
+        with stage_span("grok.apply"):
+            self._apply_list(group, src, res.ok, res.cap_off, res.cap_len,
+                             pending.rode)
+
+    def _apply_list(self, group, src, member, off_mat, len_mat,
+                    n_rode: int) -> None:
+        """The list program's one pair of matrices into the group; rows
+        over the largest bucket first meet ``re`` member by member.  Few
+        numpy calls where every row rode (``n_rode`` is the group's rows):
+        each costs the worker a hand-over of the interpreter lock."""
+        cols = group.columns
+        n = len(member)
+        n_present, n_re = n, 0
+        if n_rode < n:
+            n_present = int(np.count_nonzero(src.present))
+            over = np.flatnonzero(src.present
+                                  & (src.lengths > LENGTH_BUCKETS[-1]))
+            if len(over):
+                def install(j, rows, cap_off, cap_len):
+                    self._place(off_mat, len_mat, j, rows, cap_off, cap_len)
+                    member[rows] = j
+                n_re = self._decide_by_re(src, over, 0, install)
+        matched = member >= 0
+        cols.set_fields_matrix(self._keys, off_mat, len_mat)
+        # 0: no member; 1 + i: member i of ``Match``
+        counts = np.bincount(member + 1, minlength=len(self._engines) + 1)
+        n_matched = n - int(counts[0])
+        if self.keep_source_on_fail and n_matched < n_present:
+            fail = ~matched if n_present == n else ~matched & src.present
+            cols.set_field(self.renamed_source_key,
+                           src.offsets.astype(np.int32),
+                           np.where(fail, src.lengths, np.int32(-1)))
+        cols.parse_ok = matched
+        if src.from_content:
+            cols.content_consumed = True
+        _note(self._pipeline, counts[1:].tolist(), rows_total=n,
+              dispatches_total=1, device_rows_total=n_matched - n_re,
+              list_program_rows_total=n_rode, re_rows_total=n_re,
+              unmatched_rows_total=n - n_matched)
+
+    # -- the per-member path ----------------------------------------------
 
     def _dispatch_members(self, group, src, masks):
         parts: List[_Part] = []
@@ -266,6 +425,9 @@ class ProcessorGrok(Processor):
         if token is None:
             return
         src, parts = token
+        if isinstance(parts, PendingMatchList):
+            self._complete_list(group, src, parts)
+            return
         try:
             results = [self._result(part) for part in parts]
         except BaseException:
@@ -273,6 +435,17 @@ class ProcessorGrok(Processor):
             raise
         with stage_span("grok.apply"):
             self._apply(group, src, parts, results)
+
+    def _place(self, off_mat, len_mat, member: int, rows, cap_off,
+               cap_len) -> None:
+        """``member``'s spans of ``rows`` into the union's columns (the
+        column map both paths share)."""
+        caps, columns = self._columns[member]
+        # a run of columns takes whole row pieces; scattered ones (a key
+        # shared with a member further up) go cell by cell
+        at = rows if isinstance(columns, slice) else rows[:, None]
+        off_mat[at, columns] = cap_off[:, caps]
+        len_mat[at, columns] = cap_len[:, caps]
 
     def _apply(self, group, src, parts: List[_Part], results) -> None:
         """The members' spans into one matrix of the union keys, in Match
@@ -286,12 +459,7 @@ class ProcessorGrok(Processor):
         tiers = {"device": 0, "walker": 0, "re": 0}
 
         def install(member, rows, cap_off, cap_len):
-            caps, columns = self._columns[member]
-            # a run of columns takes whole row pieces; scattered ones (a
-            # key shared with a member further up) go cell by cell
-            at = rows if isinstance(columns, slice) else rows[:, None]
-            off_mat[at, columns] = cap_off[:, caps]
-            len_mat[at, columns] = cap_len[:, caps]
+            self._place(off_mat, len_mat, member, rows, cap_off, cap_len)
             matched[rows] = True
             taken[member] += len(rows)
 

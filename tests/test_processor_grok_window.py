@@ -109,6 +109,17 @@ def _processor(match=None, name="grok-window"):
     return p
 
 
+def _per_member(p):
+    """The test-only seam onto the per-member path (PR 35): the cell's
+    all-SEGMENT list rides ONE program a group on the device route
+    (tests/test_processor_grok_list_program.py), and the tests below exist
+    for what a group with several handles in flight needs — the path every
+    list with a member no device tier holds still takes."""
+    assert p._list_ok, "the cell's list is served by the list program"
+    p._list_ok = False
+    return p
+
+
 def _group(data: bytes):
     sb = models.SourceBuffer(len(data) + 64)
     g = models.PipelineEventGroup(sb)
@@ -307,7 +318,7 @@ def test_a_budget_too_small_for_two_handles_does_not_deadlock(monkeypatch):
     _device_route(monkeypatch)
     plane = DevicePlane.reset_for_testing(budget_bytes=40 * 1024)
     _src, lines = _lines(17, 400)
-    p = _processor()
+    p = _per_member(_processor())
     g = _group(b"".join(lines))
     token = p.process_dispatch(g)
     p.process_complete(g, token)
@@ -318,7 +329,7 @@ def test_a_budget_too_small_for_two_handles_does_not_deadlock(monkeypatch):
 def test_a_failed_member_gives_the_others_chunks_back(monkeypatch):
     _device_route(monkeypatch)
     _src, lines = _lines(19, 200)
-    p = _processor()
+    p = _per_member(_processor())
     g = _group(b"".join(lines))
     engine = p._engines[2][0]
 
@@ -396,6 +407,9 @@ def test_spans_nest_under_the_stage_and_status_has_the_section(tmp_path,
                     "FilePaths": [str(tmp_path / "access.log")]}],
         "processors": [{"Type": "processor_grok", "Match": MATCH}],
         "flushers": [{"Type": "flusher_file", "FilePath": str(sink)}]})
+    (grok,) = [inst.plugin for inst in p.processors
+               if isinstance(inst.plugin, ProcessorGrok)]
+    _per_member(grok)
     g = _group(b"".join(lines))
     tracer = trace.enable()
     try:
@@ -451,7 +465,7 @@ def test_a_row_engine_and_automaton_disagree_on_is_decided_by_re(monkeypatch):
     the record is still the reference's, and the counters say it happened."""
     _src, lines = _lines(37, 120)
     ref = _reference()
-    p = _processor(name="grok-disagree")
+    p = _per_member(_processor(name="grok-disagree"))
     engine = p._engines[0][0]
     real = engine.parse_batch_async
 
