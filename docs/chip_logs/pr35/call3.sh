@@ -1,4 +1,6 @@
 #!/bin/bash
+# (PR 36) diag_patch.py, diag_patch2.py and diag_read.py are gone: the tracer now records cpu_s and tid on every span itself
+# (loongcollector_tpu/trace/tracer.py; perfbench/benchlib/threads.py reads them), so this script is a record, not a recipe.
 # call 3: traced runs of the claimed cell — the change from the committed files, the change from a copy whose
 # BENCHMARK.json also lists the cell on the pinned lists (full_lists.py: io_arrays_per_dispatch and the extract
 # pair), the parent; the thread-time reading of the worker's account from a throw-away copy (diag_patch.py);

@@ -1,4 +1,6 @@
 #!/bin/bash
+# (PR 36) diag_patch.py, diag_patch2.py and diag_read.py are gone: the tracer now records cpu_s and tid on every span itself
+# (loongcollector_tpu/trace/tracer.py; perfbench/benchlib/threads.py reads them), so this script is a record, not a recipe.
 # call 4: where the stage spans' self time goes on the list program — one traced run of a throw-away copy
 # with thread ids, thread CPU time (diag_patch.py) and finer spans inside the two legs (diag_patch2.py).
 cd /root/repo

@@ -1,4 +1,6 @@
 #!/bin/bash
+# (PR 36) diag_patch.py, diag_patch2.py and diag_read.py are gone: the tracer now records cpu_s and tid on every span itself
+# (loongcollector_tpu/trace/tracer.py; perfbench/benchlib/threads.py reads them), so this script is a record, not a recipe.
 # call 5: the final tree after the lean path (no index arrays, no buffers, few numpy calls where every row of a
 # group rides; git archive $(git write-tree) under .chip_tmp/change) against the parent (1a0e1c9) in the
 # claimed cell: six same-seed pairs of 45 s on six new seeds, sides alternating; traced runs (the committed

@@ -89,19 +89,24 @@ class FlusherFile(Flusher):
         self._ledger_terminal_write(groups, run)
 
     def _append_one(self, group: PipelineEventGroup) -> bool:
-        t0 = time.perf_counter()
+        tracer = trace.active_tracer()
+        if tracer is not None:
+            t0 = time.perf_counter()
+            cpu0 = time.thread_time()
         done = self.serializer.append_group(group, self.file_path)
         if done is None:
             return False
-        tracer = trace.active_tracer()
         if tracer is not None:
             # the two halves as the native call timed them, under the
-            # names and attributes _serialize_then_write gives them
+            # names and attributes _serialize_then_write gives them.  The
+            # call is one stretch of this thread's CPU clock: the pair's
+            # CPU seconds are on flusher.serialize, flusher.write has None
+            cpu_s = time.thread_time() - cpu0
             nbytes, serialize_s, write_s = done
             attrs = {"flusher": self.name, "groups": 1, "events": len(group),
                      "nbytes": nbytes}
             tracer.record_timed("flusher", "flusher.serialize", t0,
-                                serialize_s, attrs)
+                                serialize_s, attrs, cpu_s)
             tracer.record_timed("flusher", "flusher.write",
                                 t0 + serialize_s, write_s, attrs)
         return True

@@ -17,7 +17,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
-from ... import recovery
+from ... import recovery, trace
 from ...monitor.alarms import AlarmLevel, AlarmManager, AlarmType
 from ...runner import ack_watermark
 from ...utils import flags
@@ -299,6 +299,40 @@ class FileServer:
             self._listener = None
 
     def _round(self) -> bool:
+        """One round of the event thread.  Traced, a round that read a
+        group is an ``input.file.round`` span, current for its body: the
+        reads (``input.file.read``), each group's ``input.file.push`` and
+        ``input.file.checkpoint`` and the round's ``input.file.discover``
+        nest under it, and what is left is its self time (the stats of the
+        files, the watermark gate, the loop).  A round that read nothing
+        leaves no span, as a read that found nothing leaves none.  Tracing
+        off: one global read."""
+        tracer = trace.active_tracer()
+        sp = (tracer.start_stage("input.file.round", "input.file.round")
+              if tracer is not None else None)
+        if sp is None:
+            return self._round_body(None)
+        reads0 = self.stats.reads_total
+        discovered: list = []
+        try:
+            return self._round_body(discovered)
+        finally:
+            reads = self.stats.reads_total - reads0
+            if reads:
+                for name, t0, seconds, cpu_s in discovered:
+                    tracer.record_timed(
+                        "input.file.round", "input.file.discover", t0,
+                        seconds, {"config": name}, cpu_s)
+                sp.set_attr("reads", reads)
+                sp.end()
+            else:
+                tracer.pop_current(sp)
+
+    def _round_body(self, discovered: Optional[list]) -> bool:
+        """The round itself.  ``discovered``: None, or the list a traced
+        round's discovery passes go into — (config, start, seconds, CPU
+        seconds) each, recorded by `_round` once it knows the round read
+        something."""
         with self._lock:
             states = list(self._configs.values())
         dirty = self._dirty_paths
@@ -311,12 +345,19 @@ class FileServer:
             if now - st.last_discovery >= DISCOVERY_INTERVAL_S or st.first_round:
                 ran_discovery = True
                 st.last_discovery = now
+                if discovered is not None:
+                    t_disc = time.perf_counter()
+                    c_disc = time.thread_time()
                 st.known = st.poller.poll()
                 for path in st.known:
                     if path not in st.readers:
                         self._open_reader(st, path)
                     else:
                         self._check_rotation(st, path)
+                if discovered is not None:
+                    cpu_s = time.thread_time() - c_disc
+                    discovered.append((st.name, t_disc,
+                                       time.perf_counter() - t_disc, cpu_s))
                 # prune readers whose file left the glob or was deleted —
                 # otherwise open fds pin deleted files' disk space forever
                 known_set = set(st.known)
@@ -527,13 +568,20 @@ class FileServer:
                 break
             stats.reads_total += 1
             stats.read_bytes_total += reader._last_consumed  # SOURCE bytes
+            # the group's one read of the tracer: what the round does for
+            # it beside the read is two spans, input.file.push (tags and
+            # the queue) and input.file.checkpoint
+            tracer = trace.active_tracer()
             if recovery.suppress_duplicate(group):
                 # previous run already delivered this exact span (acked
                 # after the last checkpoint dump): count it, advance the
                 # books, and never let it re-enter the pipeline
                 moved = True
-                self.checkpoints.update(reader.checkpoint())
+                self._note_checkpoint(reader, tracer)
                 continue
+            if tracer is not None:
+                t_push = time.perf_counter()
+                c_push = time.thread_time()
             if st.tag_provider is not None:
                 try:
                     tags = st.tag_provider(reader.path)
@@ -542,14 +590,34 @@ class FileServer:
                 if tags:
                     for k, v in tags.items():
                         group.set_tag(k, v)
-            if pqm is not None:
-                if not pqm.push_queue(st.queue_key, group):
-                    # queue rejected after read: restore offset (SOURCE
-                    # bytes) and the multiline stitch state together
-                    reader.rollback_last()
-                    stats.push_rejected_total += 1
-                    self._register_feedback(st.queue_key)
-                    break
+            rejected = pqm is not None and \
+                not pqm.push_queue(st.queue_key, group)
+            if tracer is not None:
+                cpu_s = time.thread_time() - c_push
+                tracer.record_timed(
+                    "input.file.round", "input.file.push", t_push,
+                    time.perf_counter() - t_push, {"rejected": rejected},
+                    cpu_s)
+            if rejected:
+                # queue rejected after read: restore offset (SOURCE
+                # bytes) and the multiline stitch state together
+                reader.rollback_last()
+                stats.push_rejected_total += 1
+                self._register_feedback(st.queue_key)
+                break
             moved = True
-            self.checkpoints.update(reader.checkpoint())
+            self._note_checkpoint(reader, tracer)
         return moved
+
+    def _note_checkpoint(self, reader: LogFileReader, tracer) -> None:
+        """The reader's offset into the checkpoint table, after a group of
+        its was taken in (``input.file.checkpoint`` when traced)."""
+        if tracer is None:
+            self.checkpoints.update(reader.checkpoint())
+            return
+        t0 = time.perf_counter()
+        c0 = time.thread_time()
+        self.checkpoints.update(reader.checkpoint())
+        cpu_s = time.thread_time() - c0
+        tracer.record_timed("input.file.round", "input.file.checkpoint", t0,
+                            time.perf_counter() - t0, None, cpu_s)

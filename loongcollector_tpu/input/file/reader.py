@@ -204,12 +204,15 @@ class LogFileReader:
         if tracer is None:
             return self._read(force_flush)
         t0 = time.perf_counter()
+        cpu0 = time.thread_time()
         group = self._read(force_flush)
         if group is not None:
+            cpu_s = time.thread_time() - cpu0
             tracer.record_timed(
                 "input", "input.file.read", t0, time.perf_counter() - t0,
-                {"offset": self.offset - self._last_consumed,
-                 "nbytes": self._last_consumed, "rows": len(group)})
+                {"path": self.path,
+                 "offset": self.offset - self._last_consumed,
+                 "nbytes": self._last_consumed, "rows": len(group)}, cpu_s)
         return group
 
     def _read(self, force_flush: bool = False
@@ -364,12 +367,6 @@ class LogFileReader:
         if self._prev_partial:
             group.set_metadata(EventGroupMetaKey.ML_CONTINUE, "1")
         self._prev_partial = partial_tail
-        # span layer head: one timeline event per shipped chunk — the
-        # input-read edge of the trace (offset/bytes are content-stable,
-        # so a replayed soak produces the identical read sequence)
-        if trace.is_active():
-            trace.event("input.read", path=self.path,
-                        offset=read_offset, nbytes=consumed_src)
         return group
 
     def rollback_last(self) -> None:

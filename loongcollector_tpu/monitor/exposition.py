@@ -166,6 +166,91 @@ def process_workers() -> int:
     return runner.thread_count if runner is not None else 0
 
 
+_TASK_DIR = "/proc/self/task"
+
+
+def _read_proc(path: str) -> Optional[str]:
+    try:
+        with open(path) as f:
+            return f.read()
+    except OSError:
+        return None
+
+
+def _task_account(tid: int) -> dict:
+    """The kernel's account of one task of this process, from the three
+    files of ``/proc/self/task/<tid>``.  A field the kernel does not give
+    (no schedstat, or one a sandbox fills with zeros) is absent, never 0;
+    without schedstat ``cpu_s`` is ``stat``'s ``utime + stime`` (10 ms
+    ticks).  Files only: a task that ended meanwhile reads as nothing,
+    where asking a dead thread for its CPU clock (``pthread_getcpuclockid``
+    on a stale ident) takes the process down."""
+    row: dict = {}
+    sched = (_read_proc(f"{_TASK_DIR}/{tid}/schedstat") or "").split()
+    if len(sched) >= 3 and any(x != "0" for x in sched[:3]):
+        # ns on a CPU, ns runnable and waiting for one, timeslices run
+        row["cpu_s"] = int(sched[0]) / 1e9
+        row["runq_wait_s"] = int(sched[1]) / 1e9
+        row["timeslices"] = int(sched[2])
+    for line in (_read_proc(f"{_TASK_DIR}/{tid}/status") or "").splitlines():
+        if line.startswith("voluntary_ctxt_switches:"):
+            row["voluntary_switches"] = int(line.split()[1])
+        elif line.startswith("nonvoluntary_ctxt_switches:"):
+            row["involuntary_switches"] = int(line.split()[1])
+    # the comm field may hold spaces and brackets: count from its end.
+    # utime, stime and processor are fields 14, 15 and 39 of the line
+    stat = (_read_proc(f"{_TASK_DIR}/{tid}/stat") or "").rpartition(")")[2] \
+        .split()
+    if len(stat) >= 37:
+        row["last_cpu"] = int(stat[36])
+        if "cpu_s" not in row:
+            row["cpu_s"] = (int(stat[11]) + int(stat[12])) \
+                / os.sysconf("SC_CLK_TCK")
+    return row
+
+
+def threads_status() -> dict:
+    """/debug/status ``threads``: for each of this process's threads, how
+    much of its life was work on a CPU, how much waiting for one, and how
+    often it let go of it — the kernel's own counters, read when the page
+    is asked for and at no other time (nothing samples between scrapes;
+    two scrapes and a subtraction give the shares of the time between).
+
+    ``by_name``: every thread of ``threading.enumerate()`` under its name
+    (a second thread of one name is ``<name>#<tid>``).  ``other``: the
+    tasks that are no Python thread (the device runtime's and XLA's
+    pools), counted and summed."""
+    doc: dict = {"at_s": time.monotonic() - _process_t0}
+    by_name: dict = {}
+    mine = set()
+    for th in threading.enumerate():
+        tid = th.native_id
+        if tid is None:
+            continue
+        mine.add(tid)
+        row = {"tid": tid}
+        row.update(_task_account(tid))
+        by_name[th.name if th.name not in by_name
+                else f"{th.name}#{tid}"] = row
+    doc["by_name"] = by_name
+    try:
+        tids = [int(t) for t in os.listdir(_TASK_DIR) if t.isdigit()]
+    except OSError:
+        tids = []
+    other: dict = {"threads": 0}
+    for tid in tids:
+        if tid in mine:
+            continue
+        row = _task_account(tid)
+        other["threads"] += 1
+        for key in ("cpu_s", "runq_wait_s"):
+            if key in row:
+                other[key] = other.get(key, 0.0) + row[key]
+    if tids:
+        doc["other"] = other
+    return doc
+
+
 def collect_status() -> dict:
     """The /debug/status document: a one-page answer to "what is this
     agent doing right now", assembled from observe-only handles.  Every
@@ -467,6 +552,13 @@ def collect_status() -> dict:
             doc["startup"] = sdoc
     except Exception:  # noqa: BLE001
         pass
+    try:
+        # the kernel's account of every thread (CPU seconds, run-queue
+        # wait, switches), read now: which thread bounds a pipeline, and
+        # whether it is working or waiting
+        doc["threads"] = threads_status()
+    except Exception:  # noqa: BLE001
+        pass
     return doc
 
 
@@ -479,6 +571,7 @@ STATUS_SECTIONS = (
     "flight", "profiler", "recovery",
     "device_memory", "compile", "xprof",
     "trace", "file_input", "flush", "startup", "multiline", "grok",
+    "threads",
 )
 
 

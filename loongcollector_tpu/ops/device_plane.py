@@ -439,16 +439,23 @@ class DeviceFuture:
                     # submit, so this is what of it the host still has
                     # to wait for.  Without a block_until_ready the
                     # split collapses into d2h.  One pair of readings
-                    # feeds both planes.
+                    # feeds both planes; the tracer's spans take this
+                    # thread's CPU clock at the same three points.
                     t_exec = time.perf_counter()
+                    if tracer is not None:
+                        c_exec = time.thread_time()
                     first = self._outputs[0] if self._outputs else None
                     if hasattr(first, "block_until_ready"):
                         first.block_until_ready()
+                    if tracer is not None:
+                        c_d2h = time.thread_time()
                     t_d2h = time.perf_counter()
                     try:
                         self._materialised = [np.asarray(o)
                                               for o in self._outputs]
                     finally:
+                        if tracer is not None:
+                            c_end = time.thread_time()
                         t_end = time.perf_counter()
                         xprof.leg(xid, "exec", t_exec, t_d2h - t_exec)
                         xprof.leg(xid, "d2h", t_d2h, t_end - t_d2h)
@@ -456,9 +463,10 @@ class DeviceFuture:
                             attrs = xprof.leg_attrs(self._nbytes, xid)
                             tracer.record_timed("device", "device.wait",
                                                 t_exec, t_d2h - t_exec,
-                                                attrs)
+                                                attrs, c_d2h - c_exec)
                             tracer.record_timed("device", "device.d2h",
-                                                t_d2h, t_end - t_d2h, attrs)
+                                                t_d2h, t_end - t_d2h, attrs,
+                                                c_end - c_d2h)
                 else:
                     self._materialised = [np.asarray(o)
                                           for o in self._outputs]
@@ -778,11 +786,13 @@ class DevicePlane:
             # the round trip outlives the stage that submits it: it hangs
             # from the group's root, so `.dispatch` keeps its self time
             root = tracer.root_span()
+            # it is a stopwatch from submit to result — the thread works
+            # on other groups meanwhile — so it takes no CPU reading
             span = (tracer.start_span("device.roundtrip", parent=root,
-                                      attrs={"nbytes": nbytes})
+                                      attrs={"nbytes": nbytes}, cpu=False)
                     if root is not None else
                     tracer.child_or_sampled("device", "device.roundtrip",
-                                            {"nbytes": nbytes}))
+                                            {"nbytes": nbytes}, cpu=False))
         # loongxprof: mint the dispatch id AFTER budget admission, so the
         # submit leg measures the dispatch call, not the back-pressure
         # wait (the tracer's device.acquire span covers that).  0 when off.
@@ -805,6 +815,8 @@ class DevicePlane:
                 xprof.set_current_dispatch(xid)
             if timed:
                 t_submit = time.perf_counter()
+                if tracer is not None:
+                    c_submit = time.thread_time()
             try:
                 outputs = kernel(*args)
                 if not isinstance(outputs, (tuple, list)):
@@ -820,6 +832,8 @@ class DevicePlane:
                     # submit leg / device.submit: the dispatch call and
                     # the start of the copy back — one pair of readings
                     # for both planes
+                    if tracer is not None:
+                        dc_submit = time.thread_time() - c_submit
                     dt_submit = time.perf_counter() - t_submit
                     if xid:
                         xprof.leg(xid, "submit", t_submit, dt_submit)
@@ -827,7 +841,8 @@ class DevicePlane:
                     if tracer is not None:
                         tracer.record_timed("device", "device.submit",
                                             t_submit, dt_submit,
-                                            xprof.leg_attrs(nbytes, xid))
+                                            xprof.leg_attrs(nbytes, xid),
+                                            dc_submit)
                 prof.pop_marker()
             return DeviceFuture(self, nbytes, outputs=outputs, span=span,
                                 tenant=tenant, xid=xid)

@@ -185,7 +185,7 @@ class BatchSlot:
     buffers until then)."""
 
     __slots__ = ("_ring", "B", "L", "packed", "rows", "lengths", "origins",
-                 "_leased", "pack_t0", "pack_dur")
+                 "_leased", "pack_t0", "pack_dur", "pack_cpu")
 
     def __init__(self, ring: "BatchRing", B: int, L: int):
         self._ring = ring
@@ -196,12 +196,14 @@ class BatchSlot:
         self.lengths = self.packed[B:].reshape(-1)[:4 * B].view("<i4")
         self.origins = np.zeros(B, dtype=np.int32)
         self._leased = False
-        # last pack()'s stopwatch (perf_counter start, dur s) — the
-        # dispatch loop hands it to xprof.note_dispatch, which makes it
-        # the timeline's h2d leg and the tracer's device.pack span.  None
-        # while both are off (the pack pays no perf_counter calls then)
+        # last pack()'s stopwatch (perf_counter start, dur s, and the
+        # packing thread's CPU s) — the dispatch loop hands it to
+        # xprof.note_dispatch, which makes it the timeline's h2d leg and
+        # the tracer's device.pack span.  None while both are off (the
+        # pack pays no clock calls then)
         self.pack_t0: Optional[float] = None
         self.pack_dur: Optional[float] = None
+        self.pack_cpu: Optional[float] = None
 
     def pack(self, arena: np.ndarray, offsets: np.ndarray,
              lengths: np.ndarray, lane: Optional[int] = None):
@@ -209,13 +211,16 @@ class BatchSlot:
         feeds the auto-tuner (per chip lane when the dispatching worker is
         lane-bound — loongmesh keys the tuner's floors per chip so one
         sparse chip cannot shrink every lane's geometry)."""
-        if xprof.is_active() or trace.is_active():
+        traced = trace.is_active()
+        if traced or xprof.is_active():
             self.pack_t0 = time.perf_counter()
+            cpu0 = time.thread_time() if traced else None
             batch = pack_rows(arena, offsets, lengths, self.L, self.B,
                               out=(self.rows, self.lengths, self.origins))
+            self.pack_cpu = time.thread_time() - cpu0 if traced else None
             self.pack_dur = time.perf_counter() - self.pack_t0
         else:
-            self.pack_t0 = self.pack_dur = None
+            self.pack_t0 = self.pack_dur = self.pack_cpu = None
             batch = pack_rows(arena, offsets, lengths, self.L, self.B,
                               out=(self.rows, self.lengths, self.origins))
         self._ring.record_pack(self.B, self.L, batch.n_real,
@@ -744,11 +749,11 @@ class DeviceStream:
         chunk = Chunk(tag, batch, slot, fut, bare or kernel, nbytes, unpack)
         self._window.append(chunk)
         if slot is not None:
-            geometry, t0, dur = f"{slot.B}x{slot.L}", slot.pack_t0, \
-                slot.pack_dur
+            geometry, t0, dur, cpu = f"{slot.B}x{slot.L}", slot.pack_t0, \
+                slot.pack_dur, slot.pack_cpu
         else:
-            geometry, t0, dur = "-", None, None
-        xprof.note_dispatch(fut, self.program, geometry, t0, dur)
+            geometry, t0, dur, cpu = "-", None, None, None
+        xprof.note_dispatch(fut, self.program, geometry, t0, dur, cpu)
         lane = self.lane
         if lane is not None:
             if batch is not None:

@@ -287,12 +287,14 @@ def leg_attrs(nbytes: int, xid: int) -> dict:
 
 def note_dispatch(fut, program: str, geometry: str,
                   pack_t0: Optional[float] = None,
-                  pack_dur: Optional[float] = None) -> None:
+                  pack_dur: Optional[float] = None,
+                  pack_cpu: Optional[float] = None) -> None:
     """One-call convenience for the dispatch window
     (device_stream.DeviceStream.submit): attribute the future's dispatch to a
     program + geometry and attach the pack/H2D leg the caller timed —
     to the timeline as ``h2d`` and, the same reading, to the tracer as a
-    ``device.pack`` span under the stage that dispatched.  Both planes
+    ``device.pack`` span under the stage that dispatched (``pack_cpu``:
+    the packing thread's CPU seconds over the same interval).  Both planes
     off: the pack was not timed, two branches."""
     t = _timeline
     if pack_dur is None and t is None:
@@ -302,7 +304,8 @@ def note_dispatch(fut, program: str, geometry: str,
         tracer = trace.active_tracer()
         if tracer is not None:
             tracer.record_timed("device", "device.pack", pack_t0, pack_dur,
-                                leg_attrs(getattr(fut, "_nbytes", 0), xid))
+                                leg_attrs(getattr(fut, "_nbytes", 0), xid),
+                                pack_cpu)
     if t is None or not xid:
         return
     t.annotate(xid, program=program, geometry=geometry)

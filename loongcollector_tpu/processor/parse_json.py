@@ -216,15 +216,18 @@ class ProcessorParseJson(Processor):
         ok = dev_ok
         handled, drift_rows = True, 0
         if len(hrows):
-            t0 = time.perf_counter()
+            tracer = trace.active_tracer()
+            if tracer is not None:
+                t0 = time.perf_counter()
+                cpu0 = time.thread_time()
             handled, drift_rows = self._process_struct(
                 group, sub, raw, [k.encode("utf-8") for k in major_names],
                 ok, field_offs, field_lens, rows=hrows)
-            tracer = trace.active_tracer()
             if tracer is not None:
+                cpu_s = time.thread_time() - cpu0
                 tracer.record_timed("processor", "json.host_emit", t0,
                                     time.perf_counter() - t0,
-                                    {"rows": int(len(hrows))})
+                                    {"rows": int(len(hrows))}, cpu_s)
         by_reason = np.bincount(status[hrows], minlength=len(STATUS_NAMES))
         note_json_rows(int(sub.present.sum()),
                        {STATUS_NAMES[i]: int(by_reason[i])

@@ -828,9 +828,14 @@ class ProcessorRunner:
             # identical group set (docs/observability.md)
             gkey = tracer.next_group_key(pipeline.name or "pipeline")
             if tracer.should_sample(gkey):
+                # a stopwatch: once its device work is in flight (or the
+                # lane ring is drained under it) the thread's CPU goes to
+                # other groups, so it takes no CPU reading of its own; the
+                # stages under it take theirs
                 sp = tracer.start_span(
                     "pipeline.process", trace_id=gkey,
-                    attrs={"pipeline": pipeline.name, "events": n_events})
+                    attrs={"pipeline": pipeline.name, "events": n_events},
+                    cpu=False)
                 tracer.push_current(sp)
         prof.push_marker("pipeline", pipeline.name or "pipeline")
         # loongtenant: device dispatches made inside this chain walk count

@@ -56,15 +56,25 @@ _MAX_EVENTS_PER_SPAN = 256
 class Span:
     """One timed operation.  `end()` is idempotent; the tracer records the
     span at first end.  `add_event` attaches a named point event (kept in
-    arrival order); events recorded after `end()` are dropped."""
+    arrival order); events recorded after `end()` are dropped.
+
+    Beside its wall seconds a span carries ``tid`` (the kernel's id of the
+    thread that started it) and ``cpu_s``: the CPU seconds that thread
+    spent between start and end — work, where ``duration_s`` is work and
+    waiting.  ``cpu_s`` is None where no such reading exists: the span
+    ended on another thread (two threads' CPU clocks do not subtract), its
+    call site made it with ``cpu=False`` (a stopwatch that stays open
+    while its thread does other groups' work), or whoever timed it took
+    none (`close_at` without ``cpu_s``).  Both go into ``attrs`` when the span
+    is recorded, so every exporter carries them."""
 
     __slots__ = ("tracer", "name", "trace_id", "span_id", "parent_id",
                  "start_wall", "_start_perf", "duration_s", "attrs",
-                 "events", "status", "_ended")
+                 "events", "status", "_ended", "tid", "_start_cpu", "cpu_s")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  span_id: int, parent_id: Optional[int],
-                 attrs: Optional[dict] = None):
+                 attrs: Optional[dict] = None, cpu: bool = True):
         self.tracer = tracer
         self.name = name
         self.trace_id = trace_id
@@ -77,21 +87,33 @@ class Span:
         self.events: List[Tuple[str, float, dict]] = []
         self.status = "ok"
         self._ended = False
+        self.tid = tracer.thread_id()
+        self.cpu_s: Optional[float] = None
+        # read last at the start and first at the end: the CPU interval
+        # lies inside the wall interval, so cpu_s <= duration_s.  ``cpu``
+        # False: no reading (a stopwatch, or a span whose caller timed it
+        # and will `close_at` it) — the clock is a system call, and on a
+        # sandboxed kernel a dear one
+        self._start_cpu: Optional[float] = \
+            time.thread_time() if cpu else None
 
     def set_attr(self, key: str, value) -> None:
         if not self._ended:
             self.attrs[key] = value
 
     def close_at(self, start_perf: float, duration_s: float,
-                 store: bool = True) -> None:
+                 store: bool = True, cpu_s: Optional[float] = None) -> None:
         """End with an interval somebody else measured (the device legs:
-        one pair of perf_counter readings feeds this tracer and xprof)."""
+        one pair of perf_counter readings feeds this tracer and xprof) and
+        the CPU seconds that caller read beside it on this thread, None
+        where it read none."""
         if self._ended:
             return
         self._ended = True
         self.start_wall += start_perf - self._start_perf
         self._start_perf = start_perf
         self.duration_s = duration_s
+        self.cpu_s = cpu_s
         self.tracer._record(self, store)
 
     def add_event(self, name: str, **attrs) -> None:
@@ -106,6 +128,9 @@ class Span:
         self._ended = True
         if status is not None:
             self.status = status
+        start_cpu = self._start_cpu
+        if start_cpu is not None and self.tracer.thread_id() == self.tid:
+            self.cpu_s = time.thread_time() - start_cpu
         self.duration_s = time.perf_counter() - self._start_perf
         self.tracer._record(self)
 
@@ -211,18 +236,31 @@ class Tracer:
 
     # -- spans --------------------------------------------------------------
 
+    def thread_id(self) -> int:
+        """The calling thread's kernel id (``threading.get_native_id()``,
+        a system call, asked once a thread): what `/proc/self/task` and
+        /debug/status ``threads`` know it by."""
+        tls = self._tls
+        try:
+            return tls.tid
+        except AttributeError:
+            tls.tid = threading.get_native_id()
+            return tls.tid
+
     def start_span(self, name: str, trace_id: str = "",
                    parent: Optional[Span] = None,
-                   attrs: Optional[dict] = None) -> Span:
+                   attrs: Optional[dict] = None, cpu: bool = True) -> Span:
         if parent is None:
             parent = self.current_span()
         if parent is not None and not trace_id:
             trace_id = parent.trace_id
         return Span(self, name, trace_id, next(self._span_ids),
-                    parent.span_id if parent is not None else None, attrs)
+                    parent.span_id if parent is not None else None, attrs,
+                    cpu)
 
     def child_or_sampled(self, stream: str, name: str,
-                         attrs: Optional[dict] = None) -> Optional[Span]:
+                         attrs: Optional[dict] = None,
+                         cpu: bool = True) -> Optional[Span]:
         """Span-creation policy for instrumented stages: under a live
         (already-sampled) root span the stage always records as its
         child; a rootless stage draws its own deterministic keep/drop
@@ -231,13 +269,13 @@ class Tracer:
         the pipeline root."""
         parent = self.current_span()
         if parent is not None:
-            return self.start_span(name, parent=parent, attrs=attrs)
+            return self.start_span(name, parent=parent, attrs=attrs, cpu=cpu)
         if self.config.sample_rate >= 1.0:       # fast path: no key draw
-            return self.start_span(name, attrs=attrs)
+            return self.start_span(name, attrs=attrs, cpu=cpu)
         key = self.next_group_key(stream)
         if not self.should_sample(key):
             return None
-        return self.start_span(name, trace_id=key, attrs=attrs)
+        return self.start_span(name, trace_id=key, attrs=attrs, cpu=cpu)
 
     def start_stage(self, stream: str, name: str,
                     attrs: Optional[dict] = None) -> Optional[Span]:
@@ -250,13 +288,16 @@ class Tracer:
         return sp
 
     def record_timed(self, stream: str, name: str, start_perf: float,
-                     duration_s: float, attrs: Optional[dict] = None) -> None:
+                     duration_s: float, attrs: Optional[dict] = None,
+                     cpu_s: Optional[float] = None) -> None:
         """A finished span from an interval already measured, under the
         `child_or_sampled` policy: child of the current span, else drawn
-        from ``stream``'s key sequence."""
-        sp = self.child_or_sampled(stream, name, attrs)
+        from ``stream``'s key sequence.  ``cpu_s``: the pair of
+        ``time.thread_time()`` readings the caller took beside its pair of
+        ``perf_counter`` readings, on this thread."""
+        sp = self.child_or_sampled(stream, name, attrs, cpu=False)
         if sp is not None:
-            sp.close_at(start_perf, duration_s)
+            sp.close_at(start_perf, duration_s, cpu_s=cpu_s)
 
     def span_histogram(self, name: str):
         h = self._span_hists.get(name)
@@ -267,12 +308,15 @@ class Tracer:
         return h
 
     def note_gc(self, start_perf: float, duration_s: float,
-                generation: int) -> None:
+                generation: int, cpu_s: Optional[float] = None) -> None:
         """The gc hook's whole work: one lock-free append.  The next
         recorded span folds the list (the periodic spans see to it that
-        one comes within the minute on an idle agent)."""
+        one comes within the minute on an idle agent).  The collector ran
+        on the thread that tripped it: ``cpu_s`` and the thread id are
+        that thread's, whoever folds the list."""
         self._gc_pending.append((start_perf, duration_s, generation,
-                                 self.current_span()))
+                                 self.current_span(), cpu_s,
+                                 self.thread_id()))
 
     def _fold_gc(self) -> None:
         """Pending collections into `runtime.gc` spans: the histogram takes
@@ -281,19 +325,25 @@ class Tracer:
             return                      # another thread is folding them
         try:
             pending, self._gc_pending = self._gc_pending, []
-            for start, dur, gen, parent in pending:
-                Span(self, "runtime.gc",
-                     parent.trace_id if parent is not None else "",
-                     next(self._span_ids),
-                     parent.span_id if parent is not None else None,
-                     {"generation": gen}).close_at(
-                    start, dur, store=gen >= 1 or dur >= 1e-3)
+            for start, dur, gen, parent, cpu_s, tid in pending:
+                sp = Span(self, "runtime.gc",
+                          parent.trace_id if parent is not None else "",
+                          next(self._span_ids),
+                          parent.span_id if parent is not None else None,
+                          {"generation": gen}, cpu=False)
+                sp.tid = tid
+                sp.close_at(start, dur, store=gen >= 1 or dur >= 1e-3,
+                            cpu_s=cpu_s)
         finally:
             self._gc_fold_lock.release()
 
     def _record(self, span: Span, store: bool = True) -> None:
         if self._gc_pending and span.name != "runtime.gc":
             self._fold_gc()
+        # in attrs, where every exporter (and the benchmark's launcher)
+        # already looks; both are volatile, so the structure stays the seed's
+        span.attrs["cpu_s"] = span.cpu_s
+        span.attrs["tid"] = span.tid
         self.span_histogram(span.name).observe(span.duration_s or 0.0)
         if store:
             with self._lock:
@@ -413,15 +463,17 @@ class Tracer:
         with self._lock:
             return {"spans": len(self._spans),
                     "events": len(self._timeline),
-                    "dropped_spans": self._dropped_spans}
+                    "dropped_spans": self._dropped_spans,
+                    "cpu_clock": _cpu_clock}
 
 
 #: span attributes whose values are run-dependent (sizes are stable, ids
 #: and timings are not) — excluded from structure comparison.
 #: dispatch_id is loongxprof's per-run correlation counter: interleaving
-#: under concurrency may renumber dispatches between identical runs
+#: under concurrency may renumber dispatches between identical runs;
+#: cpu_s and tid are every span's CPU seconds and kernel thread id
 _VOLATILE_ATTRS = frozenset({"duration_ms", "wall", "thread",
-                             "dispatch_id"})
+                             "dispatch_id", "cpu_s", "tid"})
 
 
 #: spans whose very presence is run-dependent (a collection, a wait on
@@ -451,6 +503,7 @@ def active_tracer() -> Optional[Tracer]:
 def enable(config: Optional[TraceConfig] = None) -> Tracer:
     global _tracer
     t = Tracer(config)
+    cpu_clock()
     _tracer = t
     if _gc_hook not in gc.callbacks:
         gc.callbacks.append(_gc_hook)
@@ -464,20 +517,58 @@ def disable() -> None:
         gc.callbacks.remove(_gc_hook)
 
 
+_cpu_clock: Optional[dict] = None
+
+
+def cpu_clock() -> dict:
+    """What a reading of this kernel's thread CPU clock costs and how fine
+    it steps, probed once a process (at the first `enable()`):
+    ``cost_us`` (one ``time.thread_time()``, the least of eight) and
+    ``step_us`` (the clock's least step seen while spinning, at most 30
+    ms).  A Linux kernel reads some 0.3 µs in 1 µs steps; a sandboxed one
+    (the chip hosts') 7–15 µs in 10 ms steps — there a span's ``cpu_s`` is
+    a count of 10 ms ticks, good in sums over a slice and void alone, and
+    tracing on costs that reading twice a span.  In /debug/status
+    ``trace``, so that whoever reads a CPU column knows its grain."""
+    global _cpu_clock
+    if _cpu_clock is None:
+        cost = min(_timed(time.thread_time) for _ in range(8))
+        t_end = time.perf_counter() + 0.03
+        last, step = time.thread_time(), None
+        while step is None and time.perf_counter() < t_end:
+            now = time.thread_time()
+            if now != last:
+                step = now - last
+        _cpu_clock = {"cost_us": round(cost * 1e6, 3),
+                      "step_us": None if step is None
+                      else round(step * 1e6, 3)}
+    return _cpu_clock
+
+
+def _timed(fn) -> float:
+    t0 = time.perf_counter()
+    fn()
+    return time.perf_counter() - t0
+
+
 _gc_t0 = 0.0
+_gc_cpu0 = 0.0
 
 
 def _gc_hook(phase: str, info: dict) -> None:
     """``gc.callbacks`` entry, installed only while a tracer is active:
-    times every collection (they never nest) for `runtime.gc`."""
-    global _gc_t0
+    times every collection (they never nest) for `runtime.gc`, on the wall
+    and on the CPU clock of the thread that tripped it."""
+    global _gc_t0, _gc_cpu0
     if phase == "start":
         _gc_t0 = time.perf_counter()
+        _gc_cpu0 = time.thread_time()
     elif _gc_t0:
         t = _tracer
         if t is not None:
+            cpu_s = time.thread_time() - _gc_cpu0
             t.note_gc(_gc_t0, time.perf_counter() - _gc_t0,
-                      info.get("generation", -1))
+                      info.get("generation", -1), cpu_s)
         _gc_t0 = 0.0
 
 

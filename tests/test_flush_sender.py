@@ -583,7 +583,13 @@ class TestOneGroupBatchIsOneNativeCall:
         (wr,) = [s for s in spans if s.name == "flusher.write"]
         want = {"flusher": "flusher_file", "groups": 1, "events": 40,
                 "nbytes": out.stat().st_size}
-        assert ser.attrs == want and wr.attrs == want
+        for sp in (ser, wr):
+            assert {k: v for k, v in sp.attrs.items()
+                    if k not in ("cpu_s", "tid")} == want
+        # one native call, one stretch of the sender's CPU clock: the
+        # pair's CPU seconds are on the serialize span
+        assert ser.attrs["cpu_s"] > 0 and wr.attrs["cpu_s"] is None
+        assert ser.attrs["tid"] == wr.attrs["tid"]
         assert ser.parent_id is None and wr.parent_id is None
         assert ser.duration_s > 0 and wr.duration_s > 0
         assert wr.start_wall >= ser.start_wall + ser.duration_s - 1e-6

@@ -43,6 +43,12 @@ from loongcollector_tpu.runner.processor_runner import ProcessorRunner
 from loongcollector_tpu.trace import TraceConfig
 
 
+def _own(sp) -> dict:
+    """A span's attributes as its call site gave them: without the CPU
+    seconds and the thread id every span gets when it is recorded."""
+    return {k: v for k, v in sp.attrs.items() if k not in ("cpu_s", "tid")}
+
+
 @pytest.fixture(autouse=True)
 def _clean():
     chaos.reset()
@@ -696,7 +702,7 @@ class TestNestedStages:
         (dispatch,) = by["processor.processor_stub_device.dispatch"]
         (submit,) = by["device.submit"]
         assert submit.parent_id == dispatch.span_id
-        assert submit.attrs == {"nbytes": 64}
+        assert _own(submit) == {"nbytes": 64}
 
     def test_roundtrip_keeps_the_root_as_parent(self):
         by, root, _tl = _staged_roundtrip(False)
@@ -751,7 +757,7 @@ class TestDeviceLegs:
     def test_leg_with_xprof_off(self, leg):
         by, _root, _tl = _staged_roundtrip(False)
         (sp,) = by[leg]
-        assert sp.attrs == {"nbytes": 64}    # no dispatch id without xprof
+        assert _own(sp) == {"nbytes": 64}    # no dispatch id without xprof
         assert sp.duration_s >= 0.0
 
     @pytest.mark.parametrize("leg", LEGS)
@@ -759,7 +765,7 @@ class TestDeviceLegs:
         by, _root, timeline = _staged_roundtrip(True)
         (rec,) = timeline.dispatches()
         (sp,) = by[leg]
-        assert sp.attrs == {"nbytes": 64, "dispatch_id": rec.id}
+        assert _own(sp) == {"nbytes": 64, "dispatch_id": rec.id}
         assert by["device.roundtrip"][0].attrs["dispatch_id"] == rec.id
 
     def test_one_measurement_feeds_both_planes(self):
@@ -790,7 +796,7 @@ class TestDeviceLegs:
             fut.result()
         (pack,) = [s for s in t.finished_spans() if s.name == "device.pack"]
         assert pack.parent_id == stage.span_id
-        assert pack.attrs == {"nbytes": 32}
+        assert _own(pack) == {"nbytes": 32}
         assert pack._start_perf == t0 and pack.duration_s == 0.002
 
     def test_acquire_span_only_when_the_budget_blocks(self):
@@ -812,7 +818,7 @@ class TestDeviceLegs:
         acquires = [s for s in t.finished_spans()
                     if s.name == "device.acquire"]
         assert len(acquires) == 1
-        assert acquires[0].attrs == {"nbytes": 80, "on": "budget"}
+        assert _own(acquires[0]) == {"nbytes": 80, "on": "budget"}
         # what it drained while waiting nests under it
         waits = [s for s in t.finished_spans() if s.name == "device.wait"
                  and s.parent_id == acquires[0].span_id]
@@ -848,7 +854,7 @@ class TestSinkSpans:
         (enq,) = [s for s in t.finished_spans()
                   if s.name == "flusher.enqueue"]
         assert enq.parent_id == send.span_id
-        assert enq.attrs == {"flusher": "flusher_file", "groups": 1,
+        assert _own(enq) == {"flusher": "flusher_file", "groups": 1,
                              "events": 1}
         (sp,) = [s for s in t.finished_spans() if s.name == name]
         assert sp.parent_id is None
@@ -881,7 +887,7 @@ class TestSinkSpans:
 
 
 class TestReaderSpan:
-    def test_read_span_and_timeline_event(self, tmp_path):
+    def test_read_span_carries_what_the_timeline_event_did(self, tmp_path):
         p = tmp_path / "a.log"
         p.write_bytes(b"one\ntwo\nthree\n")
         t = trace.enable()
@@ -890,9 +896,10 @@ class TestReaderSpan:
         assert g is not None and r.read() is None     # nothing more: no span
         (sp,) = [s for s in t.finished_spans()
                  if s.name == "input.file.read"]
-        assert sp.attrs == {"offset": 0, "nbytes": 14, "rows": len(g)}
-        (ev,) = t.timeline_by_name()["input.read"]    # stays, as it was
-        assert ev.attrs == {"path": str(p), "offset": 0, "nbytes": 14}
+        # what the `input.read` timeline event said is on the span
+        assert _own(sp) == {"path": str(p), "offset": 0, "nbytes": 14,
+                            "rows": len(g)}
+        assert "input.read" not in t.timeline_by_name()
 
     def test_read_with_tracing_off(self, tmp_path):
         p = tmp_path / "a.log"
@@ -907,7 +914,7 @@ class TestPauses:
         assert len(gc.callbacks) == len(before) + 1
         gc.collect()
         spans = [s for s in t.finished_spans() if s.name == "runtime.gc"]
-        assert spans and spans[-1].attrs == {"generation": 2}
+        assert spans and _own(spans[-1]) == {"generation": 2}
         trace.disable()
         assert gc.callbacks == before
         gc.collect()                               # nothing listens now
@@ -1001,7 +1008,8 @@ class TestSpanHistogramsAndStatus:
             t.start_span(f"s{i}").end()
         assert [s.name for s in t.finished_spans()] == ["s2", "s3", "s4",
                                                         "s5"]
-        assert t.stats() == {"spans": 4, "events": 0, "dropped_spans": 2}
+        assert t.stats() == {"spans": 4, "events": 0, "dropped_spans": 2,
+                             "cpu_clock": tracer_mod.cpu_clock()}
 
     @pytest.mark.parametrize("key", ["spans", "events", "dropped_spans"])
     def test_status_trace_section(self, key):
@@ -1174,3 +1182,172 @@ class TestProgramNames:
         lowered = w._fn.lower(jnp.zeros((4,), jnp.int32))
         assert "jit_loong_unit_family" in lowered.as_text()[:200]
         assert int(w(jnp.zeros((4,), jnp.int32))[0]) == 1
+
+
+# ---------------------------------------------------------------------------
+# work against waiting: cpu_s and tid on every span
+
+
+def _spin(seconds: float) -> None:
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+class TestCpuAndTid:
+    def test_a_busy_span_is_mostly_cpu_and_never_more_than_its_wall(self):
+        t = trace.enable()
+        with trace.span("busy"):
+            _spin(0.02)
+        (sp,) = t.finished_spans()
+        assert 0.018 <= sp.cpu_s <= sp.duration_s
+        assert sp.attrs["cpu_s"] == sp.cpu_s
+        assert sp.attrs["tid"] == sp.tid == threading.get_native_id()
+
+    def test_a_sleeping_span_has_wall_and_next_to_no_cpu(self):
+        t = trace.enable()
+        with trace.span("asleep"):
+            time.sleep(0.05)
+        (sp,) = t.finished_spans()
+        assert sp.duration_s >= 0.05 and sp.cpu_s < 0.01
+
+    def test_a_span_ended_on_another_thread_has_no_cpu(self):
+        t = trace.enable()
+        sp = t.start_span("handed.over")
+        th = threading.Thread(target=sp.end)
+        th.start()
+        th.join()
+        (got,) = t.finished_spans()
+        assert got.cpu_s is None and got.attrs["cpu_s"] is None
+        assert got.tid == threading.get_native_id()     # where it started
+
+    def test_a_stopwatch_says_so_at_its_source(self):
+        t = trace.enable()
+        sp = t.start_span("stopwatch", cpu=False)
+        _spin(0.002)
+        sp.end()
+        assert t.finished_spans()[0].attrs["cpu_s"] is None
+        assert t.child_or_sampled("s", "another", cpu=False)._start_cpu is None
+
+    def test_a_span_its_caller_timed_takes_no_reading_of_its_own(
+            self, monkeypatch):
+        t = trace.enable()
+        reads = []
+        real = time.thread_time
+        monkeypatch.setattr(time, "thread_time",
+                            lambda: reads.append(1) or real())
+        t.record_timed("s", "timed", time.perf_counter(), 0.001, None, 0.0005)
+        assert reads == []                      # the clock is a system call
+        with trace.span("self-timed"):
+            pass
+        assert len(reads) == 2
+
+    def test_the_clocks_cost_and_step_are_on_the_status_page(self):
+        trace.enable()
+        doc = exposition.collect_status()["trace"]["cpu_clock"]
+        assert doc == trace.tracer.cpu_clock()
+        assert 0.0 < doc["cost_us"] < 1000.0
+        assert doc["step_us"] is None or doc["step_us"] > 0.0
+
+    def test_the_round_trip_is_a_stopwatch_and_its_legs_are_not(self):
+        t = trace.enable()
+        plane = DevicePlane(budget_bytes=1 << 20)
+        plane.submit(lambda x: x + 1, (np.arange(8),), nbytes=64).result()
+        by = {s.name: s for s in t.finished_spans()}
+        assert by["device.roundtrip"].attrs["cpu_s"] is None
+        for leg in ("device.submit", "device.wait", "device.d2h"):
+            assert 0.0 <= by[leg].cpu_s <= by[leg].duration_s + 1e-5, leg
+            assert by[leg].tid == by["device.roundtrip"].tid
+
+    @pytest.mark.parametrize("in_flight", [True, False])
+    def test_the_runners_root_span_is_a_stopwatch(self, in_flight):
+        """`pipeline.process` stays open while its group's device work is
+        in flight and the thread turns to other groups (or drains the lane
+        ring under it): it takes no CPU reading, in flight or not."""
+        class Pipeline:
+            name = "p"
+
+            def process_begin(self, groups):
+                _spin(0.002)
+                return (lambda: None) if in_flight else None
+
+            def send(self, groups):
+                pass
+
+        class Manager:
+            def find_pipeline_by_queue_key(self, key):
+                return Pipeline()
+        runner = ProcessorRunner(ProcessQueueManager(), Manager(),
+                                 thread_count=1)
+        t = trace.enable()
+        pending = runner._dispatch_one(1, _one_group())
+        assert (pending is not None) == in_flight
+        while pending is not None:
+            pending = runner._complete(pending)
+        (root,) = [s for s in t.finished_spans()
+                   if s.name == "pipeline.process"]
+        assert root.attrs["cpu_s"] is None and root.duration_s >= 0.002
+        assert root.attrs["tid"] == threading.get_native_id()
+
+    def test_stage_spans_keep_their_cpu_under_a_stopwatch_root(self):
+        by, root, _tl = _staged_roundtrip(False)
+        for name in ("processor.processor_stub_device.dispatch",
+                     "processor.processor_stub_device.complete"):
+            (sp,) = by[name]
+            assert sp.cpu_s is not None and sp.cpu_s <= sp.duration_s + 1e-5
+            assert sp.tid == root.tid
+
+    @pytest.mark.parametrize("cpu_s", [0.003, None])
+    def test_close_at_and_record_timed_carry_the_callers_cpu(self, cpu_s):
+        t = trace.enable()
+        t0 = time.perf_counter()
+        t.start_span("a").close_at(t0, 0.01, cpu_s=cpu_s)
+        t.record_timed("s", "b", t0, 0.01, {"k": 1}, cpu_s)
+        a, b = t.finished_spans()
+        assert a.cpu_s == b.cpu_s == cpu_s
+        assert b.attrs == {"k": 1, "cpu_s": cpu_s,
+                           "tid": threading.get_native_id()}
+
+    def test_a_collection_is_the_cpu_of_the_thread_that_tripped_it(self):
+        t = trace.enable()
+        done = []
+
+        def collect():
+            done.append(threading.get_native_id())
+            gc.collect()
+        th = threading.Thread(target=collect)
+        th.start()
+        th.join()
+        t.start_span("x").end()            # folds it, on this thread
+        g = [s for s in t.finished_spans() if s.name == "runtime.gc"][-1]
+        assert g.tid == done[0] != threading.get_native_id()
+        assert 0.0 < g.cpu_s <= g.duration_s + 1e-5
+
+    def test_cpu_and_tid_do_not_change_the_structure(self):
+        def run(spin):
+            t = trace.enable()
+            with trace.span("a", k=1):
+                _spin(spin)
+            th = threading.Thread(target=lambda: t.start_span("b").end())
+            th.start()
+            th.join()
+            out = t.structure_bytes()
+            trace.disable()
+            return out
+        assert run(0.0) == run(0.003)
+        assert b"cpu_s" not in run(0.0) and b"tid" not in run(0.0)
+
+    def test_the_exporters_carry_both(self):
+        from loongcollector_tpu.trace.export import (chrome_trace,
+                                                     traces_to_group)
+        t = trace.enable()
+        with trace.span("a"):
+            pass
+        (ev,) = [e for e in chrome_trace(t)["traceEvents"]
+                 if e.get("name") == "a"]
+        assert ev["args"]["tid"] == threading.get_native_id()
+        assert ev["args"]["cpu_s"] >= 0.0
+        group = traces_to_group(*t.drain())
+        attrs = json.loads(bytes(group.events[0].get_content(b"attrs")))
+        assert attrs["tid"] == threading.get_native_id() \
+            and attrs["cpu_s"] >= 0.0
