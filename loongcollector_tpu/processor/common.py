@@ -45,23 +45,32 @@ class SourceColumns:
     undecided: Optional[np.ndarray] = None
 
 
+def source_spans(cols: ColumnarLogs, source_key: bytes = DEFAULT_CONTENT_KEY
+                 ) -> Optional[Tuple[np.ndarray, np.ndarray, bool]]:
+    """The source field's (offsets, lengths) columns of a columnar group as
+    it stores them (int32; a length < 0 is a row without the field), and
+    whether they are the raw content's; None where the group has no such
+    source."""
+    skey = source_key.decode() if isinstance(source_key, bytes) else source_key
+    if skey in cols.fields:
+        return cols.fields[skey] + (False,)
+    if (skey == "content" and not cols.content_consumed) or not cols.fields:
+        return cols.offsets, cols.lengths, True
+    return None
+
+
 def extract_source(group: PipelineEventGroup,
                    source_key: bytes = DEFAULT_CONTENT_KEY
                    ) -> Optional[SourceColumns]:
     """Returns the source field of every event as span columns."""
     cols = group.columns
     if cols is not None and not group._events:
-        skey = source_key.decode() if isinstance(source_key, bytes) else source_key
-        from_content = False
-        if skey in cols.fields:
-            offs, lens = cols.fields[skey]
-            present = lens >= 0
-        elif (skey == "content" and not cols.content_consumed) or not cols.fields:
-            offs, lens = cols.offsets, cols.lengths
-            present = np.ones(len(cols), dtype=bool)
-            from_content = True
-        else:
+        spans = source_spans(cols, source_key)
+        if spans is None:
             return None
+        offs, lens, from_content = spans
+        present = np.ones(len(cols), dtype=bool) if from_content \
+            else lens >= 0
         arena = group.source_buffer.as_array()
         return SourceColumns(arena, offs.astype(np.int64), lens, True, present,
                              from_content)

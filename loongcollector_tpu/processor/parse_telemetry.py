@@ -30,6 +30,8 @@ _lock = threading.Lock()
 _rows: Dict[Tuple[str, str], int] = {}
 _fallback: Dict[Tuple[str, str], int] = {}
 _drift: Dict[Tuple[str, str], int] = {}
+#: (rows a native column call stored, such calls), only where one was made
+_native: Dict[Tuple[str, str], Tuple[int, int]] = {}
 _alarmed: set = set()
 _records: Dict[str, object] = {}
 
@@ -52,10 +54,12 @@ def _metrics(processor: str):
 
 
 def note_rows(processor: str, pipeline: str, total: int,
-              fallback: int, drift: int = 0) -> None:
+              fallback: int, drift: int = 0, native_rows: int = 0,
+              native_calls: int = 0) -> None:
     """Account one group's parse outcome.  `fallback` = rows that left the
     structural plane for per-row Python; `drift` = rows parsed on-plane
-    with schema drift (extras columns)."""
+    with schema drift (extras columns); `native_rows` = rows the group's
+    `native_calls` native column calls stored (the timestamp processor's)."""
     if total <= 0:
         return
     try:
@@ -74,6 +78,9 @@ def note_rows(processor: str, pipeline: str, total: int,
         _fallback[key] = _fallback.get(key, 0) + fallback
         if drift:
             _drift[key] = _drift.get(key, 0) + drift
+        if native_calls:
+            rows, calls = _native.get(key, (0, 0))
+            _native[key] = (rows + native_rows, calls + native_calls)
         seen, fb = _rows[key], _fallback[key]
         if key not in _alarmed and seen >= MIN_ROWS \
                 and fb >= seen * RATE_THRESHOLD:
@@ -96,11 +103,14 @@ def note_rows(processor: str, pipeline: str, total: int,
 
 def status() -> Dict[str, object]:
     """The /debug/status `parse` section: per-(processor, pipeline) row /
-    fallback / drift totals plus which pairs have alarmed."""
+    fallback / drift totals plus which pairs have alarmed; `native_rows` /
+    `native_calls` where a native column call ran (absent, never 0, where
+    none did: a process without the library)."""
     with _lock:
         rows = dict(_rows)
         fallback = dict(_fallback)
         drift = dict(_drift)
+        native = dict(_native)
         alarmed = set(_alarmed)
     out = {}
     for key, seen in rows.items():
@@ -111,6 +121,8 @@ def status() -> Dict[str, object]:
             "drift_rows": drift.get(key, 0),
             "degraded": key in alarmed,
         }
+        if key in native:
+            out[label]["native_rows"], out[label]["native_calls"] = native[key]
     return out
 
 
@@ -121,4 +133,5 @@ def reset_for_testing() -> None:
         _rows.clear()
         _fallback.clear()
         _drift.clear()
+        _native.clear()
         _alarmed.clear()

@@ -14,9 +14,13 @@ it can prove `strptime` would give and hands every other row to the row
 path, so values and `PARSE_TIME_FAIL` alarms are the row path's by
 construction.
 
-The column path is written in few numpy calls on purpose: each call on more
-than some 500 elements lets go of the interpreter lock, and a worker that
-lets go of it forty times a group waits forty times for whoever took it.
+The column path's work for a group is ONE native call where the library is
+loaded (`native.timestamp_column`: the walk over the stamps, the sums, the
+minute memo's lookup and the stores, under the interpreter lock it never
+lets go of), and the same plan in few numpy calls where it is not.  Few on
+purpose: each numpy call on more than some 500 elements lets go of the
+lock, and a worker that lets go of it thirty times a group waits thirty
+times for whoever took it.
 
 Without `SourceTimezone` the local zone's offset comes from `time.mktime`
 itself, once per distinct minute.  In a local hour that occurs twice (the
@@ -36,12 +40,13 @@ from typing import Any, Dict, Optional, Tuple
 import numpy as np
 from numpy.lib.stride_tricks import as_strided
 
+from .. import native
 from ..monitor.alarms import (AlarmLevel, AlarmManager,
                               AlarmType)
 from ..models import PipelineEventGroup
 from ..pipeline.plugin.interface import PluginContext, Processor
 from . import parse_telemetry
-from .common import SourceColumns, extract_source
+from .common import SourceColumns, extract_source, source_spans
 
 #: A group with fewer present rows keeps the row loop.  Measured in this
 #: repo's CPU sandbox on the Apache format (my run, PR 28): the column path
@@ -60,6 +65,9 @@ _NUMERIC = {"Y": (4, 9, _DATE, 100), "m": (2, 1, _DATE, 1_000_000),
 #: what the table holds for a byte outside its column's class; a proven
 #: row's `_CHECK` sum stays far below it, and every sum stays an exact float
 _NOT_IN_CLASS = float(1 << 20)
+#: the same mark in the native call's byte table: a worth is a digit or an
+#: ASCII letter's code, so the top bit is free
+_NATIVE_NOT_IN_CLASS = 0xFF
 #: seconds of a minute that has none: the date does not exist, the zone has
 #: no one offset in it, or the time is before the epoch (the row path stores
 #: no negative time)
@@ -93,8 +101,8 @@ class _ColumnPlan:
     `_NOT_IN_CLASS`; `weights` ([width, 4]) adds the worths up into the
     date, hour * 100 + minute, the second, and the check sum."""
 
-    __slots__ = ("width", "column_base", "table", "weights", "months",
-                 "month_default", "day_default")
+    __slots__ = ("width", "column_base", "table", "weights", "native_table",
+                 "native_weights", "months", "month_default", "day_default")
 
     @classmethod
     def compile(cls, fmt: str) -> Optional["_ColumnPlan"]:
@@ -150,6 +158,15 @@ class _ColumnPlan:
         plan.weights[:, _CHECK] = 1
         for col, number, weight in terms:
             plan.weights[col, number] = weight
+        # the same plan as integers, for `native.timestamp_column` (int64
+        # is exact where float64 is: the same sums)
+        outside = plan.table == _NOT_IN_CLASS
+        plan.native_table = np.where(outside, _NATIVE_NOT_IN_CLASS,
+                                     plan.table).astype(np.uint8)
+        weights = plan.weights.astype(np.int64)
+        plan.native_weights = np.stack(
+            [weights[:, _DATE] * 10000 + weights[:, _HOUR_MINUTE],
+             weights[:, _SECOND]], axis=1)
         plan.months = None
         if "b" in seen:
             plan.months = _month_keys()
@@ -175,7 +192,38 @@ class _ColumnPlan:
         key, second, ok = self.parse(raw, np.zeros(1, dtype=np.int64))
         fields = self.fields(int(key[0]))
         return bool(ok[0]) and fields is not None \
-            and fields + (int(second[0]),) == tuple(st[:6])
+            and fields + (int(second[0]),) == tuple(st[:6]) \
+            and self._native_agrees(raw, int(key[0]), int(second[0]))
+
+    def _native_agrees(self, raw: np.ndarray, key: int, second: int) -> bool:
+        """The same stamp through the native walk, where the library is
+        loaded: the minute's key with an empty memo, then the second beside
+        a memo that holds the minute."""
+        offsets = np.zeros(1, dtype=np.int32)
+        lengths = np.full(1, self.width, dtype=np.int32)
+        stored = np.full(1, -1, dtype=np.int64)
+        none = np.zeros(0, dtype=np.int64)
+        asked = self.native_column(raw, offsets, lengths, None, 0,
+                                   (none, none), stored)
+        if asked is None:
+            return True             # no library: the numpy path is the path
+        if asked.missing.tolist() != [key] or asked.stored:
+            return False
+        memo = (asked.missing.copy(), np.zeros(1, dtype=np.int64))
+        found = self.native_column(raw, offsets, lengths, asked.pending, 0,
+                                   memo, stored)
+        return found.stored == 1 and stored.tolist() == [second]
+
+    def native_column(self, arena: np.ndarray, offsets: np.ndarray,
+                      lengths: np.ndarray, rows: Optional[np.ndarray],
+                      min_present: int, minutes: Tuple[np.ndarray, np.ndarray],
+                      timestamps: np.ndarray
+                      ) -> Optional[native.TimestampColumn]:
+        """`native.timestamp_column` under this plan; `minutes`: the memo as
+        (keys ascending, epoch seconds of each minute's second 0)."""
+        return native.timestamp_column(
+            arena, offsets, lengths, rows, min_present, self.width,
+            self.native_table, self.native_weights, *minutes, timestamps)
 
     def parse(self, arena: np.ndarray, offsets: np.ndarray
               ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -220,6 +268,9 @@ class ProcessorParseTimestamp(Processor):
         #: the column path's: the plan's key of a minute → epoch seconds of
         #: its second 0 (`_NO_SUCH_MINUTE`: none)
         self._minute_memo: Dict[int, int] = {}
+        #: the same for the native call: (keys ascending, their seconds),
+        #: replaced as a pair whenever it grows
+        self._minutes = (np.zeros(0, dtype=np.int64),) * 2
 
     def init(self, config: Dict[str, Any], context: PluginContext) -> bool:
         super().init(config, context)
@@ -266,13 +317,12 @@ class ProcessorParseTimestamp(Processor):
         self._memo[data] = ts
         return ts
 
-    def _parse_rows(self, src: SourceColumns, tss: np.ndarray,
+    def _parse_rows(self, arena: np.ndarray, offsets: np.ndarray,
+                    lengths: np.ndarray, tss: np.ndarray,
                     rows: np.ndarray) -> None:
-        raw = src.arena
-        offsets, lengths = src.offsets, src.lengths
         for i in rows.tolist():
             o = int(offsets[i])
-            ts = self._parse_one(raw[o : o + int(lengths[i])].tobytes())
+            ts = self._parse_one(arena[o : o + int(lengths[i])].tobytes())
             if ts >= 0:
                 tss[i] = ts
 
@@ -334,11 +384,65 @@ class ProcessorParseTimestamp(Processor):
                 tss[proven] = ts[ok]
                 rest[proven] = False
         rest = np.flatnonzero(rest)
-        self._parse_rows(src, tss, rest)
+        self._parse_rows(src.arena, src.offsets, src.lengths, tss, rest)
         parse_telemetry.note_rows(self.name, self._pipeline,
                                   len(present), len(rest))
 
+    def _parse_column_native(self, group: PipelineEventGroup) -> bool:
+        """The column path as one native call a group, two for a group that
+        brings a minute the memo lacks: the call walks the field's columns as
+        the group stores them, stores what the plan proves and names the rows
+        for the row path.  False, and nothing done, where the call does not
+        apply: no library, a group under `COLUMN_MIN_ROWS` present rows."""
+        cols = group.columns
+        spans = source_spans(cols, self.source_key)
+        if spans is None:
+            return False
+        offsets, lengths, _ = spans
+        arena = group.source_buffer.as_array()
+        tss = cols.timestamps
+        found = self._plan.native_column(arena, offsets, lengths, None,
+                                         COLUMN_MIN_ROWS, self._minutes, tss)
+        if found is None:
+            return False
+        present, stored, rest, calls = found.present, found.stored, \
+            found.rest, 1
+        while len(found.pending):
+            # the standard library once a minute, as on the numpy path
+            seconds = np.array([self._minute_seconds(k)
+                                for k in found.missing.tolist()])
+            found = self._plan.native_column(
+                arena, offsets, lengths, found.pending, 0,
+                self._grow_minutes(found.missing, seconds), tss)
+            stored += found.stored
+            rest = np.sort(np.concatenate([rest, found.rest]))
+            calls += 1
+        self._parse_rows(arena, offsets, lengths, tss, rest)
+        parse_telemetry.note_rows(self.name, self._pipeline, present,
+                                  len(rest), native_rows=stored,
+                                  native_calls=calls)
+        return True
+
+    def _grow_minutes(self, keys: np.ndarray, seconds: np.ndarray
+                      ) -> Tuple[np.ndarray, np.ndarray]:
+        """The native call's memo with these minutes in it; past `_MEMO_MAX`
+        it starts over from them, as `_minute_memo` does."""
+        known = self._minutes
+        if len(known[0]) + len(keys) > _MEMO_MAX:
+            known = (keys.copy(), seconds)
+        else:
+            keys = np.concatenate([known[0], keys])
+            order = np.argsort(keys, kind="stable")
+            known = (keys[order], np.concatenate([known[1], seconds])[order])
+        self._minutes = known
+        return known
+
     def process(self, group: PipelineEventGroup) -> None:
+        if self._plan is not None and group.columns is not None \
+                and not group._events \
+                and len(group.columns) >= COLUMN_MIN_ROWS \
+                and self._parse_column_native(group):
+            return
         src = extract_source(group, self.source_key)
         if src is None:
             return
@@ -346,7 +450,8 @@ class ProcessorParseTimestamp(Processor):
             tss = group.columns.timestamps
             present = np.flatnonzero(src.present)
             if self._plan is None or len(present) < COLUMN_MIN_ROWS:
-                self._parse_rows(src, tss, present)
+                self._parse_rows(src.arena, src.offsets, src.lengths, tss,
+                                 present)
             else:
                 self._parse_column(src, tss, present)
             return

@@ -11,6 +11,7 @@
 //
 // Build: make -C native   (g++ -O3 -shared -fPIC)
 
+#include <algorithm>
 #include <cerrno>
 #include <cstdint>
 #include <cstring>
@@ -70,6 +71,119 @@ void lct_pack_rows(const uint8_t* arena, int64_t arena_len,
         if (len > 0) memcpy(dst, arena + off, static_cast<size_t>(len));
         if (len < L) memset(dst + len, 0, static_cast<size_t>(L - len));
     }
+}
+
+// ---------------------------------------------------------------------------
+// The timestamp column of one group in one call: the per-group work of
+// processor/parse_timestamp.py's column path (the plan, the semantics and
+// the numpy twin live there).  For each row with the field (length >= 0;
+// offsets and lengths are int32 at a byte stride, as ColumnarLogs stores a
+// column of a span matrix): the `width` bytes at its offset are looked up
+// in `table[column * 256 + byte]` (the byte's worth, top bit set: not in the
+// column's class) and summed by `weights[column * 2 + {minute's key,
+// second}]`; the key is looked up in the sorted memo (keys → epoch seconds
+// of the minute's second 0) and seconds + second is stored into
+// `timestamps` in place when it is >= 0.
+//   rest_rows     rows the plan cannot prove (width, class, a span that
+//                 leaves the arena, a minute whose seconds are negative):
+//                 the caller's per-row path has them
+//   pending_rows  rows whose minute is not in the memo: nothing stored;
+//   missing_keys  their distinct keys, ascending.  The caller asks the
+//                 standard library, grows the memo and calls again with
+//                 pending_rows as `rows_in`
+//   counts        present rows seen, rows stored, and the lengths of the
+//                 three outputs (each output holds `n` entries)
+// `rows_in` NULL: every row of the group, and a group with fewer than
+// `min_present` present rows is declined untouched (-1).  Returns 0.
+// ---------------------------------------------------------------------------
+int64_t lct_timestamp_column(
+        const uint8_t* arena, int64_t arena_len,
+        const uint8_t* offs, int64_t offs_stride,
+        const uint8_t* lens, int64_t lens_stride, int64_t n,
+        const int64_t* rows_in, int64_t n_rows_in, int64_t min_present,
+        int64_t width, const uint8_t* table, const int64_t* weights,
+        const int64_t* memo_keys, const int64_t* memo_seconds,
+        int64_t memo_len, int64_t* timestamps,
+        int64_t* rest_rows, int64_t* pending_rows, int64_t* missing_keys,
+        int64_t* counts) {
+    auto load32 = [](const uint8_t* base, int64_t stride, int64_t i) {
+        int32_t v;
+        memcpy(&v, base + i * stride, sizeof v);
+        return v;
+    };
+    const int64_t todo = rows_in ? n_rows_in : n;
+    if (!rows_in && min_present > 0) {
+        int64_t present = 0;
+        for (int64_t i = 0; i < n && present < min_present; ++i)
+            present += load32(lens, lens_stride, i) >= 0;
+        if (present < min_present) return -1;
+    }
+    int64_t present = 0, stored = 0, n_rest = 0, n_pending = 0;
+    int64_t last = 0;       // the memo entry the row before hit
+    // a group's arena is cold by the time its stamps are read (512 KiB a
+    // group, a few groups in flight): ask for the stamp of the row
+    // `kAhead` on, two cache lines at most
+    constexpr int64_t kAhead = 16;
+    for (int64_t k = 0; k < todo; ++k) {
+        if (k + kAhead < todo) {
+            const int64_t a = rows_in ? rows_in[k + kAhead] : k + kAhead;
+            if (a >= 0 && a < n) {
+                const int64_t ahead = load32(offs, offs_stride, a);
+                if (ahead >= 0 && ahead + width <= arena_len) {
+                    __builtin_prefetch(arena + ahead);
+                    __builtin_prefetch(arena + ahead + width - 1);
+                }
+            }
+        }
+        const int64_t r = rows_in ? rows_in[k] : k;
+        if (r < 0 || r >= n) continue;
+        const int32_t len = load32(lens, lens_stride, r);
+        if (len < 0) continue;
+        ++present;
+        const int64_t off = load32(offs, offs_stride, r);
+        if (len != width || off < 0 || off + width > arena_len) {
+            rest_rows[n_rest++] = r;
+            continue;
+        }
+        const uint8_t* p = arena + off;
+        int64_t key = 0, second = 0;
+        uint8_t outside = 0;
+        for (int64_t c = 0; c < width; ++c) {
+            const uint8_t worth = table[c * 256 + p[c]];
+            outside |= worth;
+            key += worth * weights[c * 2];
+            second += worth * weights[c * 2 + 1];
+        }
+        if (outside & 0x80) {
+            rest_rows[n_rest++] = r;
+            continue;
+        }
+        if (last >= memo_len || memo_keys[last] != key) {
+            const int64_t* hit =
+                std::lower_bound(memo_keys, memo_keys + memo_len, key);
+            if (hit == memo_keys + memo_len || *hit != key) {
+                missing_keys[n_pending] = key;
+                pending_rows[n_pending++] = r;
+                continue;
+            }
+            last = hit - memo_keys;
+        }
+        const int64_t ts = memo_seconds[last] + second;
+        if (ts < 0) {
+            rest_rows[n_rest++] = r;
+            continue;
+        }
+        timestamps[r] = ts;
+        ++stored;
+    }
+    std::sort(missing_keys, missing_keys + n_pending);
+    counts[0] = present;
+    counts[1] = stored;
+    counts[2] = n_rest;
+    counts[3] = n_pending;
+    counts[4] = std::unique(missing_keys, missing_keys + n_pending)
+        - missing_keys;
+    return 0;
 }
 
 // ---------------------------------------------------------------------------

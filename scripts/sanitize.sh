@@ -71,7 +71,7 @@ export LD_PRELOAD="$RUNTIMES"
 
 echo "== sanitize: native test corpus ($VARIANT) =="
 python -m pytest tests/test_native.py tests/test_native_t1.py \
-    -q -p no:cacheprovider
+    tests/test_parse_timestamp_columns.py -q -p no:cacheprovider
 
 if [ "$VARIANT" = tsan ]; then
     echo "sanitize OK (tsan)"

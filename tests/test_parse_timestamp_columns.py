@@ -2,11 +2,15 @@
 
 `ProcessorParseTimestamp` parses a columnar group's time column with a plan
 compiled from `SourceFormat` and hands every row the plan cannot prove to
-`_parse_one`.  The guard of that change is differential: the same groups
-through a processor with its plan and through one forced onto the row loop
-(`_plan = None`) must give the same `cols.timestamps`, row for row, and the
-same number of PARSE_TIME_FAIL alarms — on valid stamps and on every
-mutation of them this file can think of."""
+`_parse_one`.  The plan runs as one native call a group
+(`lct_timestamp_column`) or, in a process without the library, as a few
+numpy calls.  The guard is differential, three ways: the same groups through
+the native call, through the numpy column path (the library withheld) and
+through a processor forced onto the row loop (`_plan = None`) must give the
+same `cols.timestamps`, row for row, and the same number of PARSE_TIME_FAIL
+alarms — on valid stamps and on every mutation of them this file can think
+of.  Under `LOONG_DISABLE_NATIVE` the "native" cases run the numpy path too:
+the same tests, without the counts only the native call makes."""
 
 import calendar
 import os
@@ -19,6 +23,7 @@ import time
 import numpy as np
 import pytest
 
+from loongcollector_tpu import native
 from loongcollector_tpu.models import PipelineEventGroup, SourceBuffer
 from loongcollector_tpu.monitor.alarms import AlarmManager
 from loongcollector_tpu.pipeline.plugin.interface import PluginContext
@@ -73,6 +78,29 @@ def group_of(values):
             offs[i], lens[i] = view.offset + 1, len(v)
     g.columns.set_field("time", offs, lens)
     return g
+
+
+@pytest.fixture(params=["native", "numpy"])
+def path(request, monkeypatch):
+    """The column path under test: the native call, or the numpy calls of a
+    process without the library."""
+    return withhold_library(monkeypatch.setattr, request.param)
+
+
+def withhold_library(setattr_, path):
+    if path == "numpy":
+        setattr_(native, "timestamp_column", lambda *a, **k: None)
+    return path if native.get_lib() is not None else "numpy"
+
+
+def column_status(path, rows, fallback, calls, degraded=False):
+    """The processor's line of /debug/status `parse` after `calls` groups
+    on the column path."""
+    want = {"rows": rows, "fallback_rows": fallback, "drift_rows": 0,
+            "degraded": degraded}
+    if path == "native":
+        want.update(native_rows=rows - fallback, native_calls=calls)
+    return want
 
 
 def processor(fmt, tz=None, columns=True):
@@ -155,7 +183,7 @@ def mutations(fmt, rng):
 @pytest.mark.parametrize("tz", [None, "GMT+08:00", "GMT-03:30"],
                          ids=["local", "plus8", "minus330"])
 @pytest.mark.parametrize("name", sorted(FORMATS))
-def test_column_path_equals_row_path(name, tz, seed):
+def test_column_path_equals_row_path(name, tz, seed, path):
     fmt = FORMATS[name]
     rng = random.Random(seed * 1000 + len(name))
     values = valid_stamps(fmt, rng, 300) + mutations(fmt, rng) \
@@ -176,19 +204,22 @@ def test_column_path_equals_row_path(name, tz, seed):
     st = parse_telemetry.status()[LABEL]
     # the plan engaged: it proved rows, and it did not prove the mutants
     assert 0 < st["fallback_rows"] < st["rows"]
+    if path == "native":
+        assert st["native_rows"] + st["fallback_rows"] == st["rows"]
+        assert st["native_calls"] >= len(groups)
 
 
 @pytest.mark.parametrize("tz", [None, "GMT+08:00"], ids=["local", "plus8"])
 @pytest.mark.parametrize("name", sorted(FORMATS))
-def test_valid_stamps_never_leave_the_column_path(name, tz):
+def test_valid_stamps_never_leave_the_column_path(name, tz, path):
     fmt = FORMATS[name]
     values = valid_stamps(fmt, random.Random(7), 1024)
     want, _ = run(processor(fmt, tz, columns=False), values)
     got, alarms = run(processor(fmt, tz), values)
     np.testing.assert_array_equal(got, want)
     assert (got != 100).all() and not alarms
-    assert parse_telemetry.status()[LABEL] == {
-        "rows": 1024, "fallback_rows": 0, "drift_rows": 0, "degraded": False}
+    # a cold memo: one call names the group's minutes, the next stores
+    assert parse_telemetry.status()[LABEL] == column_status(path, 1024, 0, 2)
 
 
 @pytest.mark.parametrize("fmt", [
@@ -211,6 +242,7 @@ def test_format_the_plan_cannot_prove_compiles_to_no_plan(fmt):
 def test_processor_without_a_plan_is_unchanged(fmt, stamp, monkeypatch):
     p = processor(fmt, "GMT+00:00")
     monkeypatch.setattr(pt._ColumnPlan, "parse", None)     # never called
+    monkeypatch.setattr(pt._ColumnPlan, "native_column", None)
     values = [stamp] * 200 + [b"junk"] * 100 + [None] * 10
     got, alarms = run(p, values)
     want = calendar.timegm(time.strptime(stamp.decode(), fmt))
@@ -220,10 +252,11 @@ def test_processor_without_a_plan_is_unchanged(fmt, stamp, monkeypatch):
 
 
 @pytest.mark.parametrize("n", [1, 6, COLUMN_MIN_ROWS - 1])
-def test_group_under_the_crossover_keeps_the_row_loop(n, monkeypatch):
+def test_group_under_the_crossover_keeps_the_row_loop(n, monkeypatch, path):
     p = processor(APACHE)
     assert p._plan is not None
     monkeypatch.setattr(pt._ColumnPlan, "parse", None)     # never called
+    monkeypatch.setattr(pt.ProcessorParseTimestamp, "_minute_seconds", None)
     # absent rows do not count towards the crossover
     values = valid_stamps(APACHE, random.Random(n), n) + [None] * 500
     got, alarms = run(p, values)
@@ -233,7 +266,7 @@ def test_group_under_the_crossover_keeps_the_row_loop(n, monkeypatch):
     assert parse_telemetry.status() == {}
 
 
-def test_group_on_the_crossover_reports_rows_that_add_up(monkeypatch):
+def test_group_on_the_crossover_reports_rows_that_add_up(monkeypatch, path):
     rng = random.Random(11)
     bad = [b"10/Oct/2000:13:55:36 -07x0", b"31/Feb/2000:13:55:36 -0700",
            b"short"]
@@ -246,13 +279,13 @@ def test_group_on_the_crossover_reports_rows_that_add_up(monkeypatch):
     monkeypatch.setattr(pt._ColumnPlan, "parse",
                         lambda *a: calls.append(1) or parse(*a))
     _, alarms = run(p, values)
-    assert calls == [1]
-    st = parse_telemetry.status()[LABEL]
-    assert st["rows"] == COLUMN_MIN_ROWS and st["fallback_rows"] == len(bad)
+    assert calls == ([] if path == "native" else [1])
+    assert parse_telemetry.status()[LABEL] == column_status(
+        path, COLUMN_MIN_ROWS, len(bad), 2)
     assert alarms == {"PARSE_TIME_FAIL_ALARM": len(bad)}
 
 
-def test_stream_of_malformed_stamps_degrades_once_and_alarms_per_row():
+def test_stream_of_malformed_stamps_degrades_once_and_alarms_per_row(path):
     p = processor(APACHE)
     rng = random.Random(5)
     total = 0
@@ -268,9 +301,10 @@ def test_stream_of_malformed_stamps_degrades_once_and_alarms_per_row():
     alarms = alarm_counts()
     assert alarms == {"PARSE_TIME_FAIL_ALARM": total,
                       "PARSE_FALLBACK_DEGRADED_ALARM": 1}
-    st = parse_telemetry.status()[LABEL]
-    assert st == {"rows": total, "fallback_rows": total, "drift_rows": 0,
-                  "degraded": True}
+    # no minute is asked for: the month that is none is a minute's key
+    # like another, and `_minute_seconds` has no answer for it, once
+    assert parse_telemetry.status()[LABEL] == column_status(
+        path, total, total, 4, degraded=True)
 
 
 def test_event_branch_is_the_row_path():
@@ -308,7 +342,7 @@ def test_plan_reads_the_fields_the_writer_wrote(name):
         assert plan.fields(k) + (s,) == w
 
 
-def test_offsets_that_leave_the_arena_are_not_gathered():
+def test_offsets_that_leave_the_arena_are_not_gathered(path):
     g = group_of(valid_stamps(APACHE, random.Random(3), 200))
     offs, lens = g.columns.fields["time"]
     offs = offs.copy()
@@ -319,8 +353,138 @@ def test_offsets_that_leave_the_arena_are_not_gathered():
     processor(APACHE).process(g)       # no IndexError; the row path has them
     assert (g.columns.timestamps[2:] != 100).all()
     assert (g.columns.timestamps[:2] == 100).all()
+    # the native call checks every span; one gather of the numpy path's
+    # either takes all the spans or none
+    assert parse_telemetry.status()[LABEL] == column_status(
+        path, 200, 2 if path == "native" else 200, 2)
+
+
+def minutes_apart(fmt, n, step_minutes, start=(2021, 3, 4, 5, 6)):
+    """`n` stamps `step_minutes` apart, no two in one minute."""
+    t0 = calendar.timegm(start + (0,))
+    return [render(fmt, *time.gmtime(t0 + i * 60 * step_minutes + i % 60)[:6])
+            for i in range(n)]
+
+
+@pytest.mark.parametrize("tz", [None, "GMT+08:00"], ids=["local", "plus8"])
+@pytest.mark.parametrize("sizes", [[5000], [1500] * 4, [4096, 300, 4097]],
+                         ids=["one_group", "across_groups", "on_the_cap"])
+def test_memo_overflow_past_its_cap_changes_no_answer(sizes, tz, path):
+    fmt = FORMATS["iso_space"]
+    values = minutes_apart(fmt, sum(sizes), 7)
+    assert sum(sizes) > pt._MEMO_MAX
+    col, row = processor(fmt, tz), processor(fmt, tz, columns=False)
+    for size in sizes:
+        part, values = values[:size], values[size:]
+        want, want_alarms = run(row, part)
+        got, got_alarms = run(col, part)
+        np.testing.assert_array_equal(got, want)
+        assert (got != 100).all() and not got_alarms and not want_alarms
+    assert len(col._minute_memo) <= pt._MEMO_MAX + 1
+    assert len(col._minutes[0]) <= max(pt._MEMO_MAX, max(sizes))
+    # a second pass over the last group: every minute is in the memo the
+    # path under test kept, and the answers are the same
+    got, _ = run(col, part)
+    np.testing.assert_array_equal(got, want)
+    assert parse_telemetry.status()[LABEL] == column_status(
+        path, sum(sizes) + sizes[-1], 0, 2 * len(sizes) + 1)
+
+
+def test_warm_memo_makes_one_native_call_a_group_and_a_new_minute_two(
+        monkeypatch):
+    if native.get_lib() is None:
+        pytest.skip("no native library in this process")
+    calls = []
+    real = native.timestamp_column
+    monkeypatch.setattr(native, "timestamp_column",
+                        lambda *a: calls.append(a[3]) or real(*a))
+    p = processor(APACHE, "GMT+00:00")
+    del calls[:]                    # the plan's own check at init
+    monkeypatch.setattr(pt._ColumnPlan, "parse", None)     # never called
+    rng = random.Random(17)
+
+    def stamps(minute, n=300):
+        return [render(APACHE, 2024, 5, 6, 7, minute, rng.randrange(60))
+                for _ in range(n)]
+
+    asked = []
+    minute_seconds = p._minute_seconds
+    monkeypatch.setattr(p, "_minute_seconds",
+                        lambda k: asked.append(k) or minute_seconds(k))
+    for values, want_calls, want_asked in (
+            (stamps(8) + stamps(9), 2, 2),          # cold: two minutes
+            (stamps(8) + stamps(9), 1, 0),          # warm
+            (stamps(9) + [None] * 50, 1, 0),
+            (stamps(9) + stamps(10), 2, 1),         # one new minute
+            (stamps(10) + [b"junk"] * 5, 1, 0)):
+        del calls[:], asked[:]
+        got, _ = run(p, values)
+        assert len(calls) == want_calls and len(asked) == want_asked
+        # the first call walks the whole group, the second the rows the
+        # first left pending: those of the new minute alone
+        assert calls[0] is None
+        if want_calls == 2 and want_asked == 1:
+            assert len(calls[1]) == 300
+        base = calendar.timegm((2024, 5, 6, 7, 0, 0))
+        for v, ts in zip(values, got.tolist()):
+            if v is None or v == b"junk":
+                assert ts == 100
+            else:
+                assert ts == base + int(v[15:17]) * 60 + int(v[18:20])
     st = parse_telemetry.status()[LABEL]
-    assert st["rows"] == st["fallback_rows"] == 200
+    assert st == {"rows": 600 * 3 + 300 + 305, "fallback_rows": 5,
+                  "drift_rows": 0, "degraded": False,
+                  "native_rows": 600 * 3 + 300 + 300, "native_calls": 7}
+
+
+def test_native_call_reads_the_columns_of_a_span_matrix_as_stored(
+        path, monkeypatch):
+    # a regex's captures arrive as columns of one [rows, keys] matrix:
+    # strided int32 views, which the native call takes without a copy
+    values = valid_stamps(APACHE, random.Random(23), 400) + [None] * 30
+    random.Random(24).shuffle(values)
+    flat, strided = group_of(values), group_of(values)
+    offs, lens = flat.columns.fields["time"]
+    mats = [np.stack([np.zeros_like(col), col, col + 1], axis=1)
+            for col in (offs, lens)]
+    del strided.columns.fields["time"]
+    strided.columns.set_fields_matrix(["a", "time", "b"], *mats)
+    assert not strided.columns.fields["time"][0].flags.c_contiguous
+    strides = []
+    if path == "native":
+        real = native.timestamp_column
+        monkeypatch.setattr(native, "timestamp_column",
+                            lambda *a: strides.append(a[1].strides)
+                            or real(*a))
+    p = processor(APACHE)
+    p.process(flat)
+    p.process(strided)
+    np.testing.assert_array_equal(strided.columns.timestamps,
+                                  flat.columns.timestamps)
+    assert (flat.columns.timestamps != 100).sum() == 400
+    if path == "native":
+        assert (12,) in strides and (4,) in strides
+
+
+def test_format_the_native_walk_misreads_leaves_the_row_path(monkeypatch):
+    if native.get_lib() is None:
+        pytest.skip("no native library in this process")
+    assert pt._ColumnPlan.compile(APACHE) is not None
+    real = native.timestamp_column
+
+    def misread(*a):
+        found = real(*a)
+        return found._replace(missing=found.missing + 1)
+
+    monkeypatch.setattr(native, "timestamp_column", misread)
+    assert pt._ColumnPlan.compile(APACHE) is None
+    assert processor(APACHE)._plan is None
+
+
+def test_native_source_builds_without_a_warning():
+    r = subprocess.run(["make", "-C", os.path.join(REPO, "native"), "lint"],
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-3000:]
 
 
 # -- the local zone across its transitions, in a process of that zone ---------
@@ -331,6 +495,7 @@ _ZONE_SCRIPT = textwrap.dedent('''
     sys.path.insert(0, {repo!r}); sys.path.insert(0, {tests!r})
     import test_parse_timestamp_columns as t
 
+    t.withhold_library(setattr, {path!r})
     FMT = "%Y-%m-%d %H:%M:%S"
     checked = 0
     for y, mo, d, h in {transitions!r}:
@@ -379,11 +544,12 @@ _ZONE_SCRIPT = textwrap.dedent('''
     # parsed just before, and the two processors parse them at other moments
     ("Africa/Monrovia", [(1972, 1, 7, 0)], False, 30),
 ])
+@pytest.mark.parametrize("path", ["native", "numpy"])
 def test_local_zone_across_its_transitions(zone, transitions, skipped_too,
-                                           fallback):
+                                           fallback, path):
     script = _ZONE_SCRIPT.format(repo=REPO, tests=os.path.join(REPO, "tests"),
                                  transitions=transitions,
-                                 skipped_too=skipped_too)
+                                 skipped_too=skipped_too, path=path)
     r = subprocess.run([sys.executable, "-c", script],
                        env=dict(os.environ, TZ=zone, JAX_PLATFORMS="cpu"),
                        capture_output=True, text=True, timeout=300)
