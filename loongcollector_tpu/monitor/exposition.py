@@ -471,6 +471,19 @@ def collect_status() -> dict:
     except Exception:  # noqa: BLE001
         pass
     try:
+        # processor_classify_url_tpu (processor/classify_url.py): rows
+        # through the rule list, where they were labelled (the fused
+        # program's label stage or the host), rows each rule took, by
+        # pipeline — absent until such a pipeline has seen a group
+        import sys as _sys
+        _cu = _sys.modules.get("loongcollector_tpu.processor.classify_url")
+        if _cu is not None:
+            cu_doc = _cu.status()
+            if cu_doc:
+                doc["classify_url"] = cu_doc
+    except Exception:  # noqa: BLE001
+        pass
+    try:
         from ..prof import flight as _flight
         rec = _flight.recorder()
         doc["flight"] = {"events": len(rec),
@@ -571,7 +584,7 @@ STATUS_SECTIONS = (
     "flight", "profiler", "recovery",
     "device_memory", "compile", "xprof",
     "trace", "file_input", "flush", "startup", "multiline", "grok",
-    "threads",
+    "classify_url", "threads",
 )
 
 

@@ -10,19 +10,20 @@ device-capable stages:
 
 * **StageSpec / StageCond** — the declarative resident form of one stage
   (Tier-1 extract, JSON field spans, fused multi-accept scan, structural
-  index, filter keep mask).  A filter condition over a field an earlier
-  stage of the program publishes binds to that stage's DEVICE-RESIDENT
-  span columns (``("capture", producer, cap)``) — no host bounce, no
-  re-pack between stages.  The contract is the columns, not the kind: any
-  stage in ``SPAN_STAGES`` publishes ``(ok[B], off[B, C], len[B, C])`` as
+  index, filter keep mask, rule-list label).  A filter condition or a
+  rule list over a field an earlier stage of the program publishes binds
+  to that stage's DEVICE-RESIDENT span columns (``("capture", producer,
+  cap)``) — no host bounce, no re-pack between stages.  The contract is
+  the columns, not the kind: any stage in ``SPAN_STAGES`` publishes ``(ok[B], off[B, C], len[B, C])`` as
   its first three outputs (row-relative offsets, length −1 where the
-  capture is absent), and that is all a ``span_match`` reads.
+  capture is absent), and that is all a ``span_match`` or a ``label``
+  reads.
 
 * **FusedProgramKernel** — ONE jitted program per (stage list, B, L)
   geometry composed from the existing kernel cores
   (``build_extract_fn`` / ``build_fused_scan_fn`` / ``build_index_fn`` /
   ``build_dfa_match_fn`` / ``build_dfa_span_match_fn`` /
-  ``build_json_fields_fn``): inputs packed
+  ``build_span_label_fn`` / ``build_json_fields_fn``): inputs packed
   once, inter-stage columns stay in HBM, every stage's outputs
   materialise together in one D2H.  ``packed_call`` is the streaming
   path's entry (ops/packed_io.py): one array in, one array out.
@@ -78,7 +79,7 @@ ENV_CACHE = "LOONG_FUSED_CACHE"
 
 #: flat-output width per stage kind
 _STAGE_WIDTH = {"extract": 3, "scan": 1, "struct_index": 4, "keep": 1,
-                "json_fields": 6}
+                "json_fields": 6, "label": 1}
 
 
 def _stage_columns(spec) -> Optional[Tuple]:
@@ -95,6 +96,8 @@ def _stage_columns(spec) -> Optional[Tuple]:
         return (("u32", None),)
     if spec.kind == "keep":
         return (("bool", None),)
+    if spec.kind == "label":
+        return (("i32", None),)
     return None
 
 
@@ -157,7 +160,12 @@ class StageSpec:
     ``json_fields`` (top-level JSON members → ok + value spans, status,
     member count, key signature), ``scan`` (fused multi-accept automaton →
     tag bitmask), ``struct_index`` (structural bitmaps), ``keep`` (filter
-    mask over StageConds).
+    mask over StageConds), ``label`` (the fused multi-accept automaton of
+    an ordered rule list walked once over a span column → index of the
+    first rule that matches, −1 for none or an absent span;
+    ``binding=(producer_stage_idx, cap_idx)`` names the column, a stage of
+    ``SPAN_STAGES``'s, and None the packed rows themselves; ``staged``
+    takes ``(rows, lengths, starts, spanlens)``).
 
     ``ident`` is the canonical content identity (pattern strings, mode)
     the program cache hashes; ``staged`` is the stage's OWN kernel (the
@@ -165,20 +173,34 @@ class StageSpec:
     ``terminal`` marks stages that rebuild the row population (multiline
     classify) and therefore must end a fused run."""
 
-    __slots__ = ("kind", "payload", "ident", "staged", "terminal", "label")
+    __slots__ = ("kind", "payload", "ident", "staged", "terminal", "label",
+                 "binding")
 
     def __init__(self, kind: str, payload, ident, staged=None,
-                 terminal: bool = False, label: str = ""):
+                 terminal: bool = False, label: str = "",
+                 binding: Optional[Tuple[int, int]] = None):
         self.kind = kind
         self.payload = payload
         self.ident = ident
         self.staged = staged
         self.terminal = terminal
         self.label = label or kind
+        self.binding = binding
 
     @property
     def width(self) -> int:
         return _STAGE_WIDTH[self.kind]
+
+
+def _bound_span(spec: StageSpec, stage_outs, lengths):
+    """``(starts, spanlens)`` of the column a ``label`` stage walks: the
+    capture it binds, as the producer left it (device-resident in the
+    program, materialised on the per-stage path), or the whole row."""
+    if spec.binding is None:
+        return lengths * 0, lengths
+    prod, cap = spec.binding
+    p_off, p_len = stage_outs[prod][1:3]
+    return p_off[:, cap], p_len[:, cap]
 
 
 def build_fused_fn(specs: Sequence[StageSpec]):
@@ -189,7 +211,7 @@ def build_fused_fn(specs: Sequence[StageSpec]):
     until the caller materialises the flat tuple once."""
     from .kernels.dfa_scan import (build_dfa_match_fn,
                                    build_dfa_span_match_fn,
-                                   build_fused_scan_fn)
+                                   build_fused_scan_fn, build_span_label_fn)
     from .kernels.field_extract import build_extract_fn
     from .kernels.json_fields import build_json_fields_fn
     from .kernels.struct_index import build_index_fn
@@ -206,6 +228,8 @@ def build_fused_fn(specs: Sequence[StageSpec]):
         elif spec.kind == "struct_index":
             mode, sep = spec.payload
             stage_fns.append(build_index_fn(mode, sep))
+        elif spec.kind == "label":
+            stage_fns.append(build_span_label_fn(spec.payload))
         elif spec.kind == "keep":
             fns = []
             for cond in spec.payload:
@@ -229,6 +253,9 @@ def build_fused_fn(specs: Sequence[StageSpec]):
                 outs = tuple(fn(rows, lengths))
             elif spec.kind == "scan":
                 outs = (fn(rows, lengths),)
+            elif spec.kind == "label":
+                outs = (fn(rows, lengths,
+                           *_bound_span(spec, stage_outs, lengths)),)
             else:  # keep
                 keep = None
                 for cond, cfn in zip(spec.payload, fn):
@@ -296,6 +323,8 @@ class FusedProgramKernel:
         self.roundtrip_ms_total = 0.0
         self.idle_attr_ms = 0.0
         self.geometries: set = set()
+        #: dispatches by (B, L): which shape the calls of a window had
+        self.geometry_dispatches: Dict[Tuple[int, int], int] = {}
         self._geom_dirty = False
         self.layout: List[Tuple[int, int]] = []
         i = 0
@@ -345,7 +374,11 @@ class FusedProgramKernel:
         outs: List[Tuple[np.ndarray, ...]] = []
         lens_np = np.asarray(lengths)
         for spec in self.specs:
-            if spec.kind != "keep":
+            if spec.kind == "label":
+                # loonglint: disable=host-bounce
+                outs.append((np.asarray(spec.staged(
+                    rows, lengths, *_bound_span(spec, outs, lens_np))),))
+            elif spec.kind != "keep":
                 raw = spec.staged(rows, lengths)
                 if not isinstance(raw, (tuple, list)):
                     raw = (raw,)
@@ -379,6 +412,8 @@ class FusedProgramKernel:
     # -- geometry ledger ----------------------------------------------------
 
     def note_geometry(self, B: int, L: int) -> None:
+        self.geometry_dispatches[(B, L)] = \
+            self.geometry_dispatches.get((B, L), 0) + 1
         if (B, L) not in self.geometries:
             self.geometries.add((B, L))
             self._geom_dirty = True
@@ -414,6 +449,9 @@ class FusedProgramKernel:
             "demotions": self.demotions,
             "lane_respills": self.lane_respills,
             "geometries": sorted(f"{b}x{l}" for b, l in self.geometries),
+            "geometry_dispatches": {
+                f"{b}x{l}": n
+                for (b, l), n in sorted(self.geometry_dispatches.items())},
             "roundtrip_ms_total": round(self.roundtrip_ms_total, 3),
             "idle_while_backlogged_attr_ms": round(self.idle_attr_ms, 3),
         }
@@ -672,7 +710,7 @@ class FusedBatchResult:
     ``stages[i]`` for stage kind: extract → (ok bool [n], cap_off i32
     [n, C] ARENA-ABSOLUTE, cap_len i32 [n, C]); json_fields → the same
     three, then (status i32 [n], members i32 [n], signature i32 [n, 2]);
-    scan → (tags uint32 [n],);
+    scan → (tags uint32 [n],); label → (label int32 [n],);
     keep → (keep bool [n],); struct_index → (in_string, structural,
     escaped, quote) bool [n, Lmax]."""
 
@@ -743,6 +781,8 @@ class FusedDispatch:
                 bufs.append((np.zeros(n, dtype=np.uint32),))
             elif spec.kind == "keep":
                 bufs.append((np.zeros(n, dtype=bool),))
+            elif spec.kind == "label":
+                bufs.append((np.full(n, -1, dtype=np.int32),))
             else:  # struct_index: ragged per-chunk widths, assembled late
                 bufs.append(None)
         return bufs
@@ -861,6 +901,8 @@ class FusedDispatch:
             elif spec.kind == "keep":
                 self._stage_bufs[si][0][chunk] = \
                     np.asarray(outs[0][:n_real], dtype=bool)
+            elif spec.kind == "label":
+                self._stage_bufs[si][0][chunk] = outs[0][:n_real]
             else:  # struct_index: keep packed words per chunk, unpack late
                 self._struct_parts.setdefault(si, []).append(
                     (chunk, [o[:n_real] for o in outs], batch.rows.shape[1]))

@@ -35,10 +35,12 @@ def _lockstep_core(automaton):
 
     Returns (K, byte_classes, run): ``byte_classes`` classifies a [B, L]
     byte tensor via interval compares (no LUT gather); ``run(cls)``
-    advances all rows in lockstep — state carried ONE-HOT [B, S] in
-    bfloat16, each step contracting (state ⊗ class one-hot) with the
-    dense [(K+1)·S, S] transition tensor on the MXU, class K being the
-    identity freeze class — and returns the final one-hot states.  The
+    advances all rows in lockstep (``run(cls, lo, hi)`` over the columns
+    ``[lo, hi)`` alone, for a caller whose other columns are all frozen)
+    — state carried ONE-HOT [B, S] in bfloat16, each step contracting
+    (state ⊗ class one-hot) with the dense [(K+1)·S, S] transition tensor
+    on the MXU, class K being the identity freeze class — and returns the
+    final one-hot states.  The
     builders below differ only in how they VALIDITY-mask the class ids
     (whole row vs span) and what they read off the final states (accept
     bit vs tag bitmask)."""
@@ -67,7 +69,7 @@ def _lockstep_core(automaton):
             cls = jnp.where(m, k, cls)
         return cls
 
-    def run(cls: jnp.ndarray) -> jnp.ndarray:
+    def run(cls: jnp.ndarray, lo=None, hi=None) -> jnp.ndarray:
         B = cls.shape[0]
         state0 = jax.nn.one_hot(automaton.start, S, dtype=jnp.bfloat16)
         state0 = jnp.broadcast_to(state0, (B, S))
@@ -79,8 +81,17 @@ def _lockstep_core(automaton):
             nxt = jnp.dot(z, T_ext, preferred_element_type=jnp.bfloat16)
             return nxt, None
 
-        final, _ = jax.lax.scan(step, state0, cls.T)       # scan over L
-        return final
+        cols = cls.T                                       # [L, B]
+        if lo is None:
+            final, _ = jax.lax.scan(step, state0, cols)    # scan over L
+            return final
+        # the columns [lo, hi) alone (traced bounds): every column outside
+        # carries the freeze class in every row, so stepping it is a no-op
+        return jax.lax.fori_loop(
+            lo, hi,
+            lambda t, state: step(state, jax.lax.dynamic_index_in_dim(
+                cols, t, 0, keepdims=False))[0],
+            state0)
 
     return K, byte_classes, run
 
@@ -133,6 +144,55 @@ def build_dfa_span_match_fn(dfa: DFA):
         return jnp.take(accepting, final_state) & (spanlens >= 0)
 
     return match
+
+
+def first_pattern(tags: np.ndarray) -> np.ndarray:
+    """Host: accept-tag bitmasks -> int32 index of the lowest set bit (the
+    first pattern of the set that matches), -1 for a mask of 0."""
+    tags = np.asarray(tags).astype(np.int64)
+    low = tags & -tags
+    return np.where(low != 0, np.log2(np.maximum(low, 1)), -1).astype(np.int32)
+
+
+def build_span_label_fn(fdfa):
+    """jit-able f(rows u8 [B,L], lengths i32 [B], starts i32 [B],
+    spanlens i32 [B]) -> label i32 [B]: ONE walk of the fused multi-accept
+    automaton over each row's row-relative SPAN [starts, starts+spanlens),
+    read off as the index of the LOWEST pattern of the set that fully
+    matches the span (first match wins), -1 where none does and -1 where
+    the span is absent (spanlen < 0: the producer did not parse the row).
+
+    The rule list of a classifier in the fused program: the span validity
+    mask is ``build_dfa_span_match_fn``'s, the automaton
+    ``build_fused_scan_fn``'s, and the walk steps only the columns some
+    row's span covers — ``[min start, max end)`` of the batch — because
+    every other column is the freeze class in every row.  Its cost is the
+    automaton's states times classes a step, whatever the number of
+    patterns: the choice among them is a [S] table read off the final
+    state."""
+    K, byte_classes, run = _lockstep_core(fdfa)
+    # per state, 1 + the lowest accepting pattern (0: none): small whole
+    # numbers, exact in bfloat16, so the read-off is one contraction with
+    # the one-hot final state and no gather
+    first = first_pattern(fdfa.accept_tags) + 1
+    first_dev = jnp.asarray(first, dtype=jnp.bfloat16)
+
+    def label(rows: jnp.ndarray, lengths: jnp.ndarray,
+              starts: jnp.ndarray, spanlens: jnp.ndarray) -> jnp.ndarray:
+        L = rows.shape[1]
+        cls = byte_classes(rows)
+        pos = jnp.arange(L, dtype=jnp.int32)[None, :]
+        present = spanlens >= 0
+        span_end = jnp.minimum(starts + jnp.maximum(spanlens, 0), lengths)
+        inside = (pos >= starts[:, None]) & (pos < span_end[:, None])
+        cls = jnp.where(inside, cls, K)    # freeze outside the span
+        lo = jnp.clip(jnp.min(jnp.where(present, starts, L)), 0, L)
+        hi = jnp.clip(jnp.max(jnp.where(present, span_end, 0)), 0, L)
+        final = run(cls, lo, hi)
+        got = jnp.dot(final, first_dev, preferred_element_type=jnp.float32)
+        return jnp.where(present, got.astype(jnp.int32) - 1, -1)
+
+    return label
 
 
 class DFASpanMatchKernel:

@@ -246,26 +246,28 @@ class FusedRun:
 
 
 def _unbound_span(spec, members) -> Optional[str]:
-    """Why a ``keep`` stage cannot join the run: one of its ``span_match``
-    conditions binds a member that publishes no ``(ok, off, len)`` columns,
-    or a capture that member does not have.  None when every binding
-    holds."""
-    if spec.kind != "keep":
+    """Why a ``keep`` or a ``label`` stage cannot join the run: one of its
+    span bindings (a ``span_match`` condition's, the rule list's) names a
+    member that publishes no ``(ok, off, len)`` columns, or a capture that
+    member does not have.  None when every binding holds."""
+    if spec.kind == "keep":
+        bindings = [("a condition", c.binding) for c in spec.payload
+                    if c.kind == "span_match"]
+    elif spec.kind == "label" and spec.binding is not None:
+        bindings = [("the rule list", spec.binding)]
+    else:
         return None
-    for cond in spec.payload:
-        if cond.kind != "span_match":
-            continue
-        prod, cap = cond.binding
+    for what, (prod, cap) in bindings:
         if not 0 <= prod < len(members):
-            return f"a condition binds stage {prod}, which is not a " \
+            return f"{what} binds stage {prod}, which is not a " \
                    f"prior member of the run"
         producer = members[prod].spec
         if producer.kind not in SPAN_STAGES:
-            return f"a condition binds capture {cap} of stage {prod} " \
+            return f"{what} binds capture {cap} of stage {prod} " \
                    f"({producer.label}), which publishes no span columns " \
                    f"(only {', '.join(SPAN_STAGES)} stages do)"
         if not 0 <= cap < producer.payload.num_caps:
-            return f"a condition binds capture {cap} of stage {prod} " \
+            return f"{what} binds capture {cap} of stage {prod} " \
                    f"({producer.label}), which publishes " \
                    f"{producer.payload.num_caps}"
     return None
