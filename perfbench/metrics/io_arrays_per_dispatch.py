@@ -1,9 +1,14 @@
 """io_arrays_per_dispatch — dispatch: arrays that crossed per dispatch, both ways: those the
 dispatches handed to their calls (/debug/status device h2d_arrays_total; each numpy argument of a
 jitted call is a host→device transfer of its own) plus the outputs whose copy back they started
-(d2h_arrays_total; each is a copy start and an np.asarray of its own), over the dispatches
-(dispatched_total), between the two scrapes.  Nothing where the program has no such counters, or
-no dispatch fell in the window."""
+(d2h_arrays_total; each is a copy start and an np.asarray of its own), over the dispatches whose
+arrays those counts hold (arrays_dispatched_total where the program publishes it, else every
+admitted dispatch, dispatched_total), between the two scrapes.  Nothing where the program has no
+such counters, or no dispatch fell in the window."""
+
+#: counted in the same step as the arrays; dispatched_total is counted at admission, before the
+#: call, so a dispatch in between at a scrape moves the ratio by a ten-thousandth
+COUNTED = "arrays_dispatched_total"
 
 
 def read(obs):
@@ -11,10 +16,9 @@ def read(obs):
     dev1 = (obs["status1"] or {}).get("device") or {}
     if "h2d_arrays_total" not in dev1 or "d2h_arrays_total" not in dev1:
         return None
-    dispatched = dev1.get("dispatched_total", 0) - dev0.get("dispatched_total", 0)
+    key = COUNTED if COUNTED in dev1 else "dispatched_total"
+    dispatched = dev1.get(key, 0) - dev0.get(key, 0)
     if dispatched <= 0:
         return None
-    # read under one lock in the program, but a dispatch is counted as dispatched before its call
-    # and its arrays after it: one in between at a scrape moves the ratio by a ten-thousandth
     return ((dev1["h2d_arrays_total"] - dev0.get("h2d_arrays_total", 0))
             + (dev1["d2h_arrays_total"] - dev0.get("d2h_arrays_total", 0))) / dispatched

@@ -20,6 +20,7 @@ import subprocess
 import numpy as np
 import pytest
 
+import bm_entries
 from benchlib import check, spec
 
 BM = spec.load_benchmark()
@@ -146,16 +147,15 @@ def test_the_cell_and_its_metrics_are_entries_alone():
     assert cell == dict(cell, config="file_multiline_java_2k",
                         traffic="backlog", chips=1)
     e2e = {m["name"] for m in spec.metrics_of_cell(BM, CELL, "end_to_end")}
-    assert e2e == {"delivered_MBps", "setup_s"}
+    assert {"delivered_MBps", "setup_s"} <= e2e
     mine = {m["name"] for m in spec.metrics_of_cell(BM, CELL, "per_layer")}
-    theirs = {m["name"] for m in
-              spec.metrics_of_cell(BM, "regex512.backlog", "per_layer")}
-    # flush_offload_share: PR 30's test pins that entry's cells to three
-    # (ROADMAP D14's disease), so the cell stays off its list
-    assert mine == (theirs - {"gen_lead_min_MiB", "ts_column_row_share",
-                              "flush_offload_share"}) | {
-        "ml_classify_s_per_GB", "ml_merge_s_per_GB", "ml_device_line_share",
-        "ml_classify_roofline"}
+    # its own entries and what every other saturated cell reports, but
+    # gen_lead_min_MiB, whose reader multiplies the ledger's ingest events
+    # (physical lines here) by line_bytes, and flush_offload_share, which
+    # does not list the cell yet (PERF.md, Open questions)
+    common = bm_entries.common_of_the_other_backlog_cells(BM, CELL)
+    assert set(READERS) | (common - {"gen_lead_min_MiB",
+                                     "flush_offload_share"}) <= mine
     assert CFG["reduced"] == [] and len(CFG["guarantees"]) == 7
     assert "needs_of_program" not in CFG["source"]
     entry = [c for c in BM["configs"] if c["name"] == CFG["name"]][0]
@@ -188,7 +188,8 @@ def test_a_cpu_rehearsal_of_the_cell_is_correct():
     doc, stderr = _run("none")
     assert doc["correct"] is True, stderr[-3000:]
     assert doc["failed"] == 0 and doc["attempted"] > 0
-    assert set(doc["metrics"]) == {"delivered_MBps", "setup_s"}
+    assert set(doc["metrics"]) == {
+        m["name"] for m in spec.metrics_of_cell(BM, CELL, "end_to_end")}
     assert all(c["value"] == 0 for c in doc["checks"].values())
 
 
@@ -323,15 +324,16 @@ def test_every_entry_of_this_pr_has_the_reader_and_the_cell():
     by_name = {m["name"]: m for m in BM["per_layer"]}
     for name in READERS:
         m = by_name[name]
-        assert m["workloads"] == [CELL] and m["moves"] == "delivered_MBps"
+        assert CELL in m["workloads"] and m["moves"] == "delivered_MBps"
         assert os.path.exists(os.path.join(spec.BENCH_DIR, "metrics",
                                            name + ".py"))
     assert by_name["ml_classify_roofline"] == dict(
         by_name["ml_classify_roofline"], unit="%", better="higher",
         source="device_trace", layer="kernels")
     for name in ("extract_us_per_MiB", "extract_roofline"):
-        assert by_name[name]["workloads"] == ["regex512.backlog", CELL]
+        assert {"regex512.backlog", CELL} <= set(by_name[name]["workloads"])
+    # facts of the program, not pins: the ingest events are physical lines,
+    # and the cell runs no timestamp stage, no fused chain and no JSON stage
     for name in ("gen_lead_min_MiB", "ts_column_row_share",
-                 "fused_dispatch_share", "json_program_roofline",
-                 "flush_offload_share"):
+                 "fused_dispatch_share", "json_program_roofline"):
         assert CELL not in by_name[name]["workloads"]

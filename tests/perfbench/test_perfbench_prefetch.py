@@ -63,14 +63,18 @@ def test_share_is_the_window_difference_or_nothing(dev0, dev1, want):
 
 
 def test_the_entry_is_the_one_the_reader_expects():
-    entry = BM["per_layer"][-1]     # appended, nothing before it changed
-    assert entry == {
+    # by name, wherever later PRs put it; its cells a superset of this PR's
+    (entry,) = [m for m in BM["per_layer"] if m["name"] == NAME]
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
         "name": NAME, "unit": "share", "better": "higher",
         "source": "program_counter", "layer": "dispatch",
-        "moves": "delivered_MBps", "workloads": CELLS}
+        "moves": "delivered_MBps"}
+    assert set(CELLS) <= set(entry["workloads"])
     for cell in CELLS:
         assert NAME in {m["name"] for m in
                         spec.metrics_of_cell(BM, cell, "per_layer")}
+    # a fact of the cell, not a pin: the burst reports no delivered_MBps,
+    # the end-to-end metric this one moves
     assert NAME not in {m["name"] for m in spec.metrics_of_cell(
         BM, "regex512.burst40", "per_layer")}
 

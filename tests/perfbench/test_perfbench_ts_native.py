@@ -19,6 +19,7 @@ import subprocess
 
 import pytest
 
+import bm_entries
 from benchlib import spec
 
 REPO = spec.ROOT
@@ -91,16 +92,19 @@ def test_share_is_the_window_difference_or_nothing(status0, status1, want):
 
 def test_the_entry_is_found_by_name_and_is_the_one_the_reader_expects():
     # by name, wherever later PRs put it: not by its place in `per_layer`
-    entries = [m for m in BM["per_layer"] if m["name"] == NAME]
-    assert entries == [{
+    (entry,) = [m for m in BM["per_layer"] if m["name"] == NAME]
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
         "name": NAME, "unit": "share", "better": "higher",
         "source": "program_counter", "layer": "processors",
-        "moves": "delivered_MBps", "workloads": [CELL]}]
+        "moves": "delivered_MBps"}
+    assert CELL in entry["workloads"]
     assert os.path.isfile(os.path.join(REPO, "perfbench", "metrics",
                                        NAME + ".py"))
-    for cell in (w["name"] for w in BM["workloads"]):
-        names = {m["name"] for m in spec.metrics_of_cell(BM, cell, "per_layer")}
-        assert (NAME in names) == (cell == CELL)
+    # every cell that lists it has the reader's source: the timestamp
+    # processor, whose rows the counters are
+    for cell in entry["workloads"]:
+        assert "processor_parse_timestamp_native" \
+            in bm_entries.plugins(BM, cell), cell
 
 
 def test_program_writes_the_fields_the_reader_reads():
