@@ -484,6 +484,20 @@ def collect_status() -> dict:
     except Exception:  # noqa: BLE001
         pass
     try:
+        # processor_desensitize_native (processor/desensitize.py): rows
+        # through the masking pair, where they were decided (the fused
+        # program's mask stage or the host, by reason), rows changed and
+        # matches replaced, by pipeline — absent until such a pipeline has
+        # seen a group
+        import sys as _sys
+        _ds = _sys.modules.get("loongcollector_tpu.processor.desensitize")
+        if _ds is not None:
+            ds_doc = _ds.status()
+            if ds_doc:
+                doc["desensitize"] = ds_doc
+    except Exception:  # noqa: BLE001
+        pass
+    try:
         from ..prof import flight as _flight
         rec = _flight.recorder()
         doc["flight"] = {"events": len(rec),
@@ -584,7 +598,7 @@ STATUS_SECTIONS = (
     "flight", "profiler", "recovery",
     "device_memory", "compile", "xprof",
     "trace", "file_input", "flush", "startup", "multiline", "grok",
-    "classify_url", "threads",
+    "classify_url", "threads", "desensitize",
 )
 
 

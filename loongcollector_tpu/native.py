@@ -108,7 +108,8 @@ def _load() -> Optional[ctypes.CDLL]:
             or not hasattr(lib, "lct_struct_index")
             or not hasattr(lib, "lct_group_reduce")
             or not hasattr(lib, "lct_ndjson_serialize_append")
-            or not hasattr(lib, "lct_timestamp_column")):
+            or not hasattr(lib, "lct_timestamp_column")
+            or not hasattr(lib, "lct_mask_spans")):
         # stale build predating the newest entry point: rebuild + reload
         _build()
         lib = _cdll(so_path)
@@ -140,6 +141,18 @@ def _load() -> Optional[ctypes.CDLL]:
              ctypes.c_int64, i64p, ctypes.c_int64, ctypes.c_int64,
              ctypes.c_int64, u8p, i64p, i64p, i64p, ctypes.c_int64, i64p]
             + [i64p] * 4)
+    if hasattr(lib, "lct_mask_spans"):
+        # through the same PyDLL handle, for the same reason: a group's
+        # rebuild is a short copy loop, cheaper than a hand-over of the lock
+        if not hasattr(lib, "keeps_lock"):
+            lib.keeps_lock = _cdll(so_path, ctypes.PyDLL)
+        lib.lct_mask_spans = lib.keeps_lock.lct_mask_spans
+        lib.lct_mask_spans.restype = ctypes.c_int64
+        lib.lct_mask_spans.argtypes = (
+            [u8p, ctypes.c_int64, i32p, ctypes.c_int64, i32p, ctypes.c_int64,
+             ctypes.c_int64, i32p, i32p, i32p, ctypes.c_int64, u8p,
+             ctypes.c_int64, u8p, ctypes.c_int64, ctypes.c_int64, i32p,
+             i32p])
     lib.lct_json_extract.restype = None
     lib.lct_json_extract.argtypes = [u8p, ctypes.c_int64, i64p, i32p,
                                      ctypes.c_int64, u8p, i32p,
@@ -340,6 +353,46 @@ def timestamp_column(arena: np.ndarray, offsets: np.ndarray,
     return TimestampColumn(present, stored, out[:n_rest],
                            out[n:n + n_pending],
                            out[2 * n:2 * n + n_missing])
+
+
+def mask_spans(arena: np.ndarray, offsets: np.ndarray, lengths: np.ndarray,
+               count: np.ndarray, first: np.ndarray, last: np.ndarray,
+               repl: bytes, base: int, new_offsets: np.ndarray,
+               new_lengths: np.ndarray) -> Optional[int]:
+    """The masked values of one group in ONE native call (`lct_mask_spans`;
+    processor/desensitize.py's install of the ``mask`` stage): every row
+    whose ``count`` is 1..S gets its value — the field's int32 columns as
+    `ColumnarLogs` stores them, strided or not — with the content spans
+    ``first[r, :count]`` .. ``last[r, :count]`` (int32 [n, S], arena
+    offsets) replaced by ``repl``, written into ``arena`` from ``base`` on
+    (a block the caller allocated, ``arena`` its buffer after that), and its
+    new offset and length stored into ``new_offsets`` / ``new_lengths``
+    (int32, contiguous) in place.  Returns the bytes written; None when the
+    library is unavailable, the arrays are not what the call reads, or a
+    row's spans do not lie inside its value — then nothing is to be relied
+    on and the caller takes its other path."""
+    lib = get_lib()
+    if lib is None or not hasattr(lib, "lct_mask_spans"):
+        return None
+    n = len(count)
+    S = first.shape[1] if first.ndim == 2 else -1
+    if offsets.dtype != np.int32 or lengths.dtype != np.int32 \
+            or offsets.shape != (n,) or lengths.shape != (n,) \
+            or arena.dtype != np.uint8 or not arena.flags.c_contiguous \
+            or not arena.flags.writeable or not 0 <= base <= len(arena) \
+            or any(a.dtype != np.int32 or not a.flags.c_contiguous
+                   for a in (count, first, last, new_offsets, new_lengths)) \
+            or first.shape != (n, S) or last.shape != (n, S) \
+            or new_offsets.shape != (n,) or new_lengths.shape != (n,):
+        return None
+    data = arena.ctypes.data
+    rc = lib.lct_mask_spans(
+        data, base, offsets.ctypes.data, offsets.strides[0],
+        lengths.ctypes.data, lengths.strides[0], n, count.ctypes.data,
+        first.ctypes.data, last.ctypes.data, S, repl, len(repl),
+        data + base, len(arena) - base, base, new_offsets.ctypes.data,
+        new_lengths.ctypes.data)
+    return None if rc < 0 else int(rc)
 
 
 def json_extract(arena: np.ndarray, offsets: np.ndarray,

@@ -538,6 +538,8 @@ class DevicePlane:
         self._prefetched = 0   # dispatches whose copy back began at submit
         self._h2d_arrays = 0   # arrays handed to the dispatched calls
         self._d2h_arrays = 0   # outputs whose copy back was started
+        # dispatches those two counts hold, counted in the same step
+        self._arrays_dispatched = 0
         self._lock = threading.Lock()
         self._freed = threading.Condition(self._lock)
         self._closed = False
@@ -654,6 +656,10 @@ class DevicePlane:
                 # transfer (or a copy start and an np.asarray) of its own
                 "h2d_arrays_total": self._h2d_arrays,
                 "d2h_arrays_total": self._d2h_arrays,
+                # the dispatches whose arrays the two counts hold: counted
+                # under the same lock hold as they are (dispatched_total is
+                # counted at admission, before the call)
+                "arrays_dispatched_total": self._arrays_dispatched,
                 "elapsed_s": elapsed,
             }
 
@@ -825,6 +831,7 @@ class DevicePlane:
                 with self._lock:
                     self._h2d_arrays += len(args)
                     self._d2h_arrays += started
+                    self._arrays_dispatched += 1
                     if outputs and started == len(outputs):
                         self._prefetched += 1
             finally:

@@ -10,20 +10,22 @@ device-capable stages:
 
 * **StageSpec / StageCond** — the declarative resident form of one stage
   (Tier-1 extract, JSON field spans, fused multi-accept scan, structural
-  index, filter keep mask, rule-list label).  A filter condition or a
-  rule list over a field an earlier stage of the program publishes binds
-  to that stage's DEVICE-RESIDENT span columns (``("capture", producer,
-  cap)``) — no host bounce, no re-pack between stages.  The contract is
+  index, filter keep mask, rule-list label, find-all mask).  A filter
+  condition, a rule list or a mask over a field an earlier stage of the
+  program publishes binds to that stage's DEVICE-RESIDENT span columns
+  (``("capture", producer, cap)``) — no host bounce, no re-pack between
+  stages.  The contract is
   the columns, not the kind: any stage in ``SPAN_STAGES`` publishes ``(ok[B], off[B, C], len[B, C])`` as
   its first three outputs (row-relative offsets, length −1 where the
-  capture is absent), and that is all a ``span_match`` or a ``label``
-  reads.
+  capture is absent), and that is all a ``span_match``, a ``label`` or a
+  ``mask`` reads.
 
 * **FusedProgramKernel** — ONE jitted program per (stage list, B, L)
   geometry composed from the existing kernel cores
   (``build_extract_fn`` / ``build_fused_scan_fn`` / ``build_index_fn`` /
   ``build_dfa_match_fn`` / ``build_dfa_span_match_fn`` /
-  ``build_span_label_fn`` / ``build_json_fields_fn``): inputs packed
+  ``build_span_label_fn`` / ``build_span_mask_fn`` /
+  ``build_json_fields_fn``): inputs packed
   once, inter-stage columns stay in HBM, every stage's outputs
   materialise together in one D2H.  ``packed_call`` is the streaming
   path's entry (ops/packed_io.py): one array in, one array out.
@@ -79,7 +81,7 @@ ENV_CACHE = "LOONG_FUSED_CACHE"
 
 #: flat-output width per stage kind
 _STAGE_WIDTH = {"extract": 3, "scan": 1, "struct_index": 4, "keep": 1,
-                "json_fields": 6, "label": 1}
+                "json_fields": 6, "label": 1, "mask": 3}
 
 
 def _stage_columns(spec) -> Optional[Tuple]:
@@ -98,6 +100,9 @@ def _stage_columns(spec) -> Optional[Tuple]:
         return (("bool", None),)
     if spec.kind == "label":
         return (("i32", None),)
+    if spec.kind == "mask":
+        return (("i32", None), ("i32", spec.payload.slots),
+                ("i32", spec.payload.slots))
     return None
 
 
@@ -105,6 +110,11 @@ def _stage_columns(spec) -> Optional[Tuple]:
 #: ``(ok[B], off[B, C], len[B, C])`` with C = ``payload.num_caps``, and a
 #: ``span_match`` condition may bind any of them
 SPAN_STAGES = ("extract", "json_fields")
+
+#: stage kinds that walk a span column a prior stage publishes (their
+#: ``binding``), or the packed rows themselves where it is None; their
+#: ``staged`` takes ``(rows, lengths, starts, spanlens)``
+BOUND_STAGES = ("label", "mask")
 
 
 def fusion_enabled() -> bool:
@@ -162,10 +172,13 @@ class StageSpec:
     tag bitmask), ``struct_index`` (structural bitmaps), ``keep`` (filter
     mask over StageConds), ``label`` (the fused multi-accept automaton of
     an ordered rule list walked once over a span column → index of the
-    first rule that matches, −1 for none or an absent span;
+    first rule that matches, −1 for none or an absent span), ``mask`` (a
+    masking pair's find-all walk over a span column → a match count and
+    the content spans of up to ``payload.slots`` matches a row, which the
+    member's apply rewrites; `build_span_mask_fn`).  For the last two
     ``binding=(producer_stage_idx, cap_idx)`` names the column, a stage of
     ``SPAN_STAGES``'s, and None the packed rows themselves; ``staged``
-    takes ``(rows, lengths, starts, spanlens)``).
+    takes ``(rows, lengths, starts, spanlens)``.
 
     ``ident`` is the canonical content identity (pattern strings, mode)
     the program cache hashes; ``staged`` is the stage's OWN kernel (the
@@ -193,8 +206,8 @@ class StageSpec:
 
 
 def _bound_span(spec: StageSpec, stage_outs, lengths):
-    """``(starts, spanlens)`` of the column a ``label`` stage walks: the
-    capture it binds, as the producer left it (device-resident in the
+    """``(starts, spanlens)`` of the column a `BOUND_STAGES` stage walks:
+    the capture it binds, as the producer left it (device-resident in the
     program, materialised on the per-stage path), or the whole row."""
     if spec.binding is None:
         return lengths * 0, lengths
@@ -211,7 +224,8 @@ def build_fused_fn(specs: Sequence[StageSpec]):
     until the caller materialises the flat tuple once."""
     from .kernels.dfa_scan import (build_dfa_match_fn,
                                    build_dfa_span_match_fn,
-                                   build_fused_scan_fn, build_span_label_fn)
+                                   build_fused_scan_fn, build_span_label_fn,
+                                   build_span_mask_fn)
     from .kernels.field_extract import build_extract_fn
     from .kernels.json_fields import build_json_fields_fn
     from .kernels.struct_index import build_index_fn
@@ -230,6 +244,8 @@ def build_fused_fn(specs: Sequence[StageSpec]):
             stage_fns.append(build_index_fn(mode, sep))
         elif spec.kind == "label":
             stage_fns.append(build_span_label_fn(spec.payload))
+        elif spec.kind == "mask":
+            stage_fns.append(build_span_mask_fn(spec.payload))
         elif spec.kind == "keep":
             fns = []
             for cond in spec.payload:
@@ -256,6 +272,9 @@ def build_fused_fn(specs: Sequence[StageSpec]):
             elif spec.kind == "label":
                 outs = (fn(rows, lengths,
                            *_bound_span(spec, stage_outs, lengths)),)
+            elif spec.kind == "mask":
+                outs = tuple(fn(rows, lengths,
+                                *_bound_span(spec, stage_outs, lengths)))
             else:  # keep
                 keep = None
                 for cond, cfn in zip(spec.payload, fn):
@@ -374,12 +393,10 @@ class FusedProgramKernel:
         outs: List[Tuple[np.ndarray, ...]] = []
         lens_np = np.asarray(lengths)
         for spec in self.specs:
-            if spec.kind == "label":
-                # loonglint: disable=host-bounce
-                outs.append((np.asarray(spec.staged(
-                    rows, lengths, *_bound_span(spec, outs, lens_np))),))
-            elif spec.kind != "keep":
-                raw = spec.staged(rows, lengths)
+            if spec.kind != "keep":
+                bound = _bound_span(spec, outs, lens_np) \
+                    if spec.kind in BOUND_STAGES else ()
+                raw = spec.staged(rows, lengths, *bound)
                 if not isinstance(raw, (tuple, list)):
                     raw = (raw,)
                 # demotion tier by design: per-stage dispatches with
@@ -445,6 +462,9 @@ class FusedProgramKernel:
             # geometries, the shapes of a call's outputs
             "captures": [s.payload.num_caps if s.kind in SPAN_STAGES else 0
                          for s in self.specs],
+            # match slots each mask stage hands back a row (0: none)
+            "slots": [s.payload.slots if s.kind == "mask" else 0
+                      for s in self.specs],
             "dispatches": self.dispatch_count,
             "demotions": self.demotions,
             "lane_respills": self.lane_respills,
@@ -710,9 +730,10 @@ class FusedBatchResult:
     ``stages[i]`` for stage kind: extract → (ok bool [n], cap_off i32
     [n, C] ARENA-ABSOLUTE, cap_len i32 [n, C]); json_fields → the same
     three, then (status i32 [n], members i32 [n], signature i32 [n, 2]);
-    scan → (tags uint32 [n],); label → (label int32 [n],);
-    keep → (keep bool [n],); struct_index → (in_string, structural,
-    escaped, quote) bool [n, Lmax]."""
+    scan → (tags uint32 [n],); label → (label int32 [n],); mask → (count
+    int32 [n], content_start int32 [n, S], content_end int32 [n, S], both
+    ARENA-ABSOLUTE); keep → (keep bool [n],); struct_index → (in_string,
+    structural, escaped, quote) bool [n, Lmax]."""
 
     __slots__ = ("stages", "n")
 
@@ -783,6 +804,11 @@ class FusedDispatch:
                 bufs.append((np.zeros(n, dtype=bool),))
             elif spec.kind == "label":
                 bufs.append((np.full(n, -1, dtype=np.int32),))
+            elif spec.kind == "mask":
+                S = spec.payload.slots
+                bufs.append((np.full(n, -1, dtype=np.int32),
+                             np.zeros((n, S), dtype=np.int32),
+                             np.zeros((n, S), dtype=np.int32)))
             else:  # struct_index: ragged per-chunk widths, assembled late
                 bufs.append(None)
         return bufs
@@ -903,6 +929,12 @@ class FusedDispatch:
                     np.asarray(outs[0][:n_real], dtype=bool)
             elif spec.kind == "label":
                 self._stage_bufs[si][0][chunk] = outs[0][:n_real]
+            elif spec.kind == "mask":
+                count_b, first_b, last_b = self._stage_bufs[si]
+                count_b[chunk] = outs[0][:n_real]
+                origin = batch.origins[:n_real, None]
+                first_b[chunk] = outs[1][:n_real] + origin
+                last_b[chunk] = outs[2][:n_real] + origin
             else:  # struct_index: keep packed words per chunk, unpack late
                 self._struct_parts.setdefault(si, []).append(
                     (chunk, [o[:n_real] for o in outs], batch.rows.shape[1]))

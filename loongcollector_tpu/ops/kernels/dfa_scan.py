@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..regex.charclass import CharClass
 from ..regex.dfa import DFA
 
 
@@ -323,3 +324,103 @@ class DFAMatchKernel:
                                            "dfa_match",
                                            donate_argnums=(0, 1))
         return self._fn_donated(rows, lengths)
+
+
+#: a ``mask`` stage's count for a row it does not decide: the span is absent,
+#: it holds a byte >= 0x80, or (the demoted path's) the host decides it;
+#: ``slots + 1`` is a row with more matches than the stage hands back
+MASK_ABSENT = -1
+MASK_NON_ASCII = -2
+MASK_DEMOTED = -3
+
+
+def _in_class(rows: jnp.ndarray, mask: np.ndarray) -> jnp.ndarray:
+    """Byte-class membership by interval compares (no LUT gather): the
+    class's own intervals or its complement's, whichever is fewer."""
+    mask = np.asarray(mask, dtype=bool)
+    negate = len(CharClass(~mask).intervals()) < len(
+        CharClass(mask).intervals())
+    hit = jnp.zeros(rows.shape, dtype=bool)
+    for lo, hi in CharClass(~mask if negate else mask).intervals():
+        hit = hit | ((rows == lo) if lo == hi
+                     else (rows >= lo) & (rows <= hi))
+    return ~hit if negate else hit
+
+
+def build_span_mask_fn(plan):
+    """jit-able f(rows u8 [B,L], lengths i32 [B], starts i32 [B],
+    spanlens i32 [B]) -> (count i32 [B], content_start i32 [B, S],
+    content_end i32 [B, S]): the find-all walk of a masking pair (a
+    `MaskPlan`, ``ops/regex/fuse.py``) over each row's row-relative SPAN,
+    leftmost-first and non-overlapping, as ``re.sub`` takes matches.
+
+    Every column is decided at once, with no sequential walk over the
+    columns: for each of the prefix's fixed-length alternatives, shifted
+    class compares say where it matches wholly inside the span; a reverse
+    running minimum of "the next byte outside the content class, or the
+    span's end" gives each content's greedy extent, so a match never
+    reaches past the field; at one start the lowest alternative whose
+    content count reaches ``lo`` wins.  Then ``S`` sequential picks, each
+    the leftmost start at or after the cursor, the cursor moving to the
+    match's end.  ``count`` is the matches found (their content spans in
+    the first ``count`` slots), ``S + 1`` where a row has more than the
+    stage hands back (never where only the first match is replaced),
+    `MASK_ABSENT` for an absent span and `MASK_NON_ASCII` for a span with a
+    byte >= 0x80: those rows are the host's."""
+    alts = [[np.asarray(m, dtype=bool) for m in alt]
+            for alt in plan.alternatives]
+    wide = max(len(a) for a in alts)
+    S = plan.slots
+    lo_count, hi_count = plan.lo, plan.hi
+
+    def mask(rows: jnp.ndarray, lengths: jnp.ndarray, starts: jnp.ndarray,
+             spanlens: jnp.ndarray):
+        B, L = rows.shape
+        pos = jnp.arange(L, dtype=jnp.int32)[None, :]
+        present = spanlens >= 0
+        end = jnp.minimum(starts + jnp.maximum(spanlens, 0), lengths)
+        padded = jnp.pad(rows, ((0, 0), (0, wide + 1)))
+        P = L + wide + 1
+        pos_p = jnp.arange(P, dtype=jnp.int32)[None, :]
+        stop = (pos_p >= end[:, None]) | ~_in_class(padded, plan.content)
+        # the first position at or after each column where the content stops
+        nxt = jax.lax.cummin(jnp.where(stop, pos_p, P), axis=1, reverse=True)
+        valid = jnp.zeros((B, L), dtype=bool)
+        c_start = jnp.zeros((B, L), dtype=jnp.int32)
+        c_end = jnp.zeros((B, L), dtype=jnp.int32)
+        for alt in reversed(alts):           # the lowest alternative wins
+            k = len(alt)
+            hit = (pos >= starts[:, None]) & (pos + k <= end[:, None])
+            for j, cls in enumerate(alt):
+                hit = hit & _in_class(padded[:, j:j + L], cls)
+            run = nxt[:, k:k + L] - (pos + k)
+            if hi_count is not None:
+                run = jnp.minimum(run, hi_count)
+            ok = hit & (run >= lo_count)
+            valid = valid | ok
+            c_start = jnp.where(ok, pos + k, c_start)
+            c_end = jnp.where(ok, pos + k + run, c_end)
+        cursor = starts
+        count = jnp.zeros(B, dtype=jnp.int32)
+        firsts, lasts = [], []
+        for _ in range(S):
+            at = jnp.min(jnp.where(valid & (pos >= cursor[:, None]), pos, L),
+                         axis=1)
+            found = at < L
+            here = pos == at[:, None]          # no column where none found
+            cs = jnp.sum(jnp.where(here, c_start, 0), axis=1)
+            ce = jnp.sum(jnp.where(here, c_end, 0), axis=1)
+            firsts.append(cs)
+            lasts.append(ce)
+            count = count + found.astype(jnp.int32)
+            cursor = jnp.where(found, ce, cursor)
+        if plan.replace_all:
+            more = jnp.any(valid & (pos >= cursor[:, None]), axis=1)
+            count = jnp.where(more, S + 1, count)
+        inside = (pos >= starts[:, None]) & (pos < end[:, None])
+        wide_byte = jnp.any(inside & (rows >= 0x80), axis=1)
+        count = jnp.where(wide_byte, MASK_NON_ASCII, count)
+        count = jnp.where(present, count, MASK_ABSENT)
+        return count, jnp.stack(firsts, axis=1), jnp.stack(lasts, axis=1)
+
+    return mask

@@ -1500,3 +1500,138 @@ def try_build_set(patterns: Sequence[str],
         return FusedSetExec(patterns, names=names)
     except Exception:  # noqa: BLE001 — any compile failure means "no fusion"
         return None
+
+
+# ---------------------------------------------------------------------------
+# find-all masking: the pattern pairs the device's ``mask`` stage takes
+# ---------------------------------------------------------------------------
+
+#: a prefix expands to at most this many fixed-length alternatives, each at
+#: most this long — one shifted compare per byte a walk step
+MASK_MAX_ALTERNATIVES = 16
+MASK_MAX_PREFIX = 64
+
+
+@dataclass
+class MaskPlan:
+    """A masking pair ``(B)C`` in the form the device's ``mask`` stage runs
+    (``ops/kernels/dfa_scan.py`` `build_span_mask_fn`): ``alternatives``,
+    the fixed-length byte-class sequences B matches, in the order ``re``
+    tries them; ``content``, C's one byte class, and its count bounds
+    ``lo`` .. ``hi`` (None: unbounded), taken greedily; ``slots``, the
+    matches a row hands back (1 where only the first is replaced,
+    ``replace_all`` False)."""
+
+    alternatives: List[List[np.ndarray]]
+    content: np.ndarray
+    lo: int
+    hi: Optional[int]
+    slots: int
+    replace_all: bool = True
+
+    def ident(self) -> list:
+        """The plan's content identity, for the program cache."""
+        return [[[CharClass(m).to_regex_fragment() for m in alt]
+                 for alt in self.alternatives],
+                CharClass(self.content).to_regex_fragment(),
+                self.lo, self.hi, self.slots, self.replace_all]
+
+
+def _fixed_alternatives(items, dotall: bool) -> Optional[List[list]]:
+    """The byte-class sequences a parsed sre item list matches, each of a
+    fixed length, in ``re``'s order of trying (a sequence tries its first
+    part's alternatives outermost); None where some part has no fixed
+    length, sets a flag, or the expansion passes the caps."""
+    alts: List[list] = [[]]
+    for op, av in items:
+        if op is sre_c.LITERAL:
+            if av > 255:
+                return None
+            piece = [[CharClass.single(av).mask]]
+        elif op is sre_c.NOT_LITERAL:
+            if av > 255:
+                return None
+            piece = [[~CharClass.single(av).mask]]
+        elif op is sre_c.IN:
+            try:
+                piece = [[CharClass.from_sre_in(av).mask]]
+            except ValueError:
+                return None
+        elif op is sre_c.ANY:
+            piece = [[CharClass.dot(dotall).mask]]
+        elif op is sre_c.SUBPATTERN:
+            _group, add_flags, del_flags, sub = av
+            if add_flags or del_flags:
+                return None
+            piece = _fixed_alternatives(sub, dotall)
+        elif op is sre_c.BRANCH:
+            piece = []
+            for branch in av[1]:
+                got = _fixed_alternatives(branch, dotall)
+                if got is None:
+                    return None
+                piece += got
+        elif op in (sre_c.MAX_REPEAT, sre_c.MIN_REPEAT) and av[0] == av[1]:
+            body = _fixed_alternatives(av[2], dotall)
+            if body is None:
+                return None
+            piece = [[]]
+            for _ in range(av[0]):
+                piece = [a + b for a in piece for b in body]
+                if len(piece) > MASK_MAX_ALTERNATIVES:
+                    return None
+        else:
+            return None
+        if piece is None:
+            return None
+        alts = [a + p for a in alts for p in piece]
+        if len(alts) > MASK_MAX_ALTERNATIVES \
+                or any(len(a) > MASK_MAX_PREFIX for a in alts):
+            return None
+    return alts
+
+
+def try_build_mask(before: bytes, content: bytes, slots: int,
+                   replace_all: bool = True) -> Optional[MaskPlan]:
+    """The device form of upstream's masking pair — ``(before)content``,
+    each match's ``content`` part replaced — or None where the device tier
+    does not take it: every match of ``before`` must have one length for
+    each of its alternatives (literals, alternations of literals, byte
+    classes, fixed counts; no flags), none empty, and ``content`` must be
+    ONE byte class under a greedy ``+``, ``*``, ``?`` or ``{m,n}`` (or
+    alone).  Then leftmost-first, non-overlapping matching is: at the
+    leftmost start at or after the cursor, the first alternative whose
+    content count reaches ``lo``, content as long as the class runs (at most
+    ``hi``) — what ``re`` and RE2 give for such a pair."""
+    try:
+        b_parsed = sre_parse.parse(before)
+        c_parsed = sre_parse.parse(content)
+    except (re.error, TypeError, ValueError):
+        return None
+    flags = b_parsed.state.flags | c_parsed.state.flags
+    if flags & (sre_c.SRE_FLAG_IGNORECASE | sre_c.SRE_FLAG_LOCALE):
+        return None
+    dotall = bool(flags & sre_c.SRE_FLAG_DOTALL)
+    alts = _fixed_alternatives(list(b_parsed), dotall)
+    if not alts or any(len(a) == 0 for a in alts):
+        return None
+    items = list(c_parsed)
+    if len(items) != 1:
+        return None
+    op, av = items[0]
+    if op is sre_c.MAX_REPEAT:
+        lo, hi, body = av
+        body = list(body)
+        if len(body) != 1:
+            return None
+        op, av = body[0]
+    elif op in (sre_c.LITERAL, sre_c.NOT_LITERAL, sre_c.IN, sre_c.ANY):
+        lo = hi = 1
+    else:
+        return None
+    one = _fixed_alternatives([(op, av)], dotall)
+    if one is None or len(one) != 1 or len(one[0]) != 1:
+        return None
+    return MaskPlan(alts, one[0][0], int(lo),
+                    None if hi is sre_c.MAXREPEAT else int(hi),
+                    int(slots) if replace_all else 1, bool(replace_all))

@@ -43,9 +43,9 @@ import numpy as np
 from .. import trace
 from ..monitor import ledger
 from ..ops.device_batch import LENGTH_BUCKETS
-from ..ops.fused_pipeline import (SPAN_STAGES, FusedDispatch,
-                                  fusion_enabled, get_fused_program,
-                                  note_json_rows)
+from ..ops.fused_pipeline import (BOUND_STAGES, SPAN_STAGES,
+                                  FusedDispatch, fusion_enabled,
+                                  get_fused_program, note_json_rows)
 from ..utils.logger import get_logger
 
 log = get_logger("fused_chain")
@@ -56,12 +56,14 @@ class FusionPlanContext:
     column, span columns published by prior members (name →
     (stage_idx, cap_idx)), a prior member whose column names are the
     data's own (it mints a capture for a name on request), and which keys
-    a member consumed — the information that decides whether the NEXT
-    stage's inputs are statically resident."""
+    a member consumed or rewrites — the information that decides whether
+    the NEXT stage's inputs are statically resident."""
 
     def __init__(self) -> None:
         self.source_key: Optional[bytes] = None
         self.consumed: set = set()
+        #: fields a member rewrites at apply time (`note_rewritten`)
+        self.rewritten: set = set()
         self.fields: Dict[str, Tuple[int, int]] = {}
         self.n_stages = 0
         #: the configuration's processors ahead of the run's first member
@@ -85,6 +87,8 @@ class FusionPlanContext:
         """'source', ("capture", stage_idx, cap_idx), or None (not
         statically resident — the stage must not fuse)."""
         skey = key.decode("latin-1") if isinstance(key, bytes) else key
+        if skey in self.rewritten:
+            return None
         got = self.fields.get(skey)
         if got is not None:
             return ("capture", got[0], got[1])
@@ -117,6 +121,14 @@ class FusionPlanContext:
     def note_consumed(self, key) -> None:
         skey = key.decode("latin-1") if isinstance(key, bytes) else key
         self.consumed.add(skey)
+
+    def note_rewritten(self, key) -> None:
+        """A member rewrites this field's bytes at apply time (a mask): the
+        device's column no longer holds what a later member would read, so
+        no later member of the run may bind it."""
+        skey = key.decode("latin-1") if isinstance(key, bytes) else key
+        self.fields.pop(skey, None)
+        self.rewritten.add(skey)
 
 
 class FusedMemberStage:
@@ -246,15 +258,17 @@ class FusedRun:
 
 
 def _unbound_span(spec, members) -> Optional[str]:
-    """Why a ``keep`` or a ``label`` stage cannot join the run: one of its
-    span bindings (a ``span_match`` condition's, the rule list's) names a
-    member that publishes no ``(ok, off, len)`` columns, or a capture that
-    member does not have.  None when every binding holds."""
+    """Why a ``keep``, a ``label`` or a ``mask`` stage cannot join the
+    run: one of its span bindings (a ``span_match`` condition's, the rule
+    list's, the masked field's) names a member that publishes no ``(ok,
+    off, len)`` columns, or a capture that member does not have.  None when
+    every binding holds."""
     if spec.kind == "keep":
         bindings = [("a condition", c.binding) for c in spec.payload
                     if c.kind == "span_match"]
-    elif spec.kind == "label" and spec.binding is not None:
-        bindings = [("the rule list", spec.binding)]
+    elif spec.kind in BOUND_STAGES and spec.binding is not None:
+        what = "the rule list" if spec.kind == "label" else "the mask"
+        bindings = [(what, spec.binding)]
     else:
         return None
     for what, (prod, cap) in bindings:

@@ -187,6 +187,66 @@ int64_t lct_timestamp_column(
 }
 
 // ---------------------------------------------------------------------------
+// The masked values of one group in one call: the install of
+// processor/desensitize.py's `mask` stage (the semantics, the sizing and
+// the Python twin live there).  For each row r whose count is 1..slots (a
+// row the device decided, with at least one match) the field's value
+// [off, off + len) — int32 columns at a byte stride, as ColumnarLogs stores
+// a column of a span matrix — is copied to `out` with the content of each
+// match k < count, [first[r * slots + k], last[r * slots + k]) (arena
+// offsets, ascending, apart, inside the value), replaced by `repl`.  The
+// row's new offset (`base` + where its value starts in `out`) and length
+// go to new_offs / new_lens; no other row is touched.  `out` may lie in
+// the arena's own buffer, past every value read.  Returns the bytes
+// written, or -1 — where some row's spans do not lie inside its value or
+// `out_cap` is too small — with nothing to rely on.
+// ---------------------------------------------------------------------------
+int64_t lct_mask_spans(
+        const uint8_t* arena, int64_t arena_len,
+        const uint8_t* offs, int64_t offs_stride,
+        const uint8_t* lens, int64_t lens_stride, int64_t n,
+        const int32_t* count, const int32_t* first, const int32_t* last,
+        int64_t slots, const uint8_t* repl, int64_t repl_len,
+        uint8_t* out, int64_t out_cap, int64_t base,
+        int32_t* new_offs, int32_t* new_lens) {
+    auto load32 = [](const uint8_t* col, int64_t stride, int64_t i) {
+        int32_t v;
+        memcpy(&v, col + i * stride, sizeof v);
+        return v;
+    };
+    int64_t at = 0;
+    for (int64_t r = 0; r < n; ++r) {
+        const int32_t k_n = count[r];
+        if (k_n <= 0 || k_n > slots) continue;
+        const int64_t off = load32(offs, offs_stride, r);
+        const int64_t len = load32(lens, lens_stride, r);
+        const int64_t end = off + len;
+        if (len < 0 || off < 0 || end > arena_len) return -1;
+        const int32_t* f = first + r * slots;
+        const int32_t* l = last + r * slots;
+        const int64_t start = at;
+        int64_t cur = off;
+        for (int32_t k = 0; k < k_n; ++k) {
+            if (f[k] < cur || l[k] < f[k] || l[k] > end) return -1;
+            const int64_t keep = f[k] - cur;
+            if (at + keep + repl_len > out_cap) return -1;
+            memcpy(out + at, arena + cur, static_cast<size_t>(keep));
+            at += keep;
+            memcpy(out + at, repl, static_cast<size_t>(repl_len));
+            at += repl_len;
+            cur = l[k];
+        }
+        if (at + (end - cur) > out_cap) return -1;
+        memcpy(out + at, arena + cur, static_cast<size_t>(end - cur));
+        at += end - cur;
+        new_offs[r] = static_cast<int32_t>(base + start);
+        new_lens[r] = static_cast<int32_t>(at - start);
+    }
+    return at;
+}
+
+
+// ---------------------------------------------------------------------------
 // SLS LogGroup wire serialization from columnar spans.
 //
 // Wire schema (public sls_logs.proto):
